@@ -5,136 +5,55 @@ The reference's backends amortize startup by caching *engines* on disk
 ``ext/nnstreamer/tensor_filter/tensor_filter_tensorrt.cc``).  The XLA
 analog is jax's persistent compilation cache: compiled executables keyed
 by (HLO, flags, platform) survive process restarts, so a production
-pipeline's first frame costs milliseconds instead of the 20-40 s TPU
-compile.
+pipeline's first frame costs a cache read instead of a TPU compile.
 
-Config (``core/config.py`` ini + env overrides):
+Where the cache lives is decided OUTSIDE the program:
 
-    [xla]
-    cache_dir = ~/.cache/nnstreamer_tpu/xla   ; "" disables
-    cache_min_compile_secs = 0.0
+* ``JAX_COMPILATION_CACHE_DIR`` set — jax reads the variable itself and
+  this module writes no directory into ``jax.config``;
+* not set — ``<checkout>/.jax_cache`` (git-ignored).  The path is part of
+  the cache key's neighbourhood: a directory that moves never hits, so it
+  is fixed, not derived from the host or the user.
 
-Env: ``NNS_TPU_XLA_CACHE_DIR`` / ``NNS_TPU_XLA_CACHE_MIN_COMPILE_SECS``.
-Enabled automatically by the jax-xla backend on open(); idempotent.
+Without the variable, XLA:CPU programs are not cached: a CPU run is a
+test or dry run in a fresh checkout, where a cache can only cost writes.
+
+:func:`enable` is the one call; the jax-xla filter backend, the
+generator and the trainer all make it before their first compile.
 """
 
 from __future__ import annotations
 
 import os
-import threading
 from typing import Optional
 
-from . import config as nns_config
 from .log import get_logger
 
 log = get_logger("compile_cache")
 
-_DEFAULT_DIR = "~/.cache/nnstreamer_tpu/xla"
-_lock = threading.Lock()
-_enabled: Optional[str] = None
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def host_fingerprint() -> str:
-    """Short tag identifying this host's compilation compatibility class.
-
-    XLA's CPU backend AOT-compiles for the host's exact CPU features; an
-    entry produced on another machine can load but SIGILL at run time
-    (cpu_aot_loader machine-feature-mismatch warnings).  Keying the cache
-    directory by platform + CPU-feature hash keeps each compatibility
-    class in its own subtree, so cross-host cache reuse can't happen.
-    """
-    import hashlib
-    import platform
-
-    feats = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    feats = " ".join(sorted(line.split(":", 1)[1].split()))
-                    break
-    except OSError:
-        feats = platform.processor()
-    tag = hashlib.sha1(
-        f"{platform.system()}-{platform.machine()}-{feats}".encode()
-    ).hexdigest()[:12]
-    return f"{platform.machine()}-{tag}"
-
-
-def enable(cache_dir: Optional[str] = None,
-           platform: Optional[str] = None) -> Optional[str]:
+def enable() -> Optional[str]:
     """Turn on the persistent cache (idempotent); returns the directory
-    in use, or None when disabled by config/error.
+    in use, or None for an XLA:CPU process without the variable."""
+    import jax
 
-    ``platform`` is the caller's actual device platform when known.  With
-    no explicit directory (arg/env/ini), the cache auto-enables only for
-    accelerator platforms: TPU compiles are the 20-40 s ones worth
-    persisting, while XLA:CPU persists AOT machine code whose embedded
-    compile "features" include tuning prefs (+prefer-no-gather, ...) the
-    host feature probe never reports — so every warm-start load logs a
-    spurious cpu_aot_loader feature-mismatch error.  An explicit
-    directory overrides (tests, CPU farms that accept the noise).
-    """
-    global _enabled
-    with _lock:
-        if _enabled is not None and not (cache_dir and not _enabled):
-            # sticky result — except that an explicit cache_dir may retry
-            # after an earlier failure/disable
-            if cache_dir and _enabled:
-                want = os.path.expanduser(cache_dir)
-                # _enabled is <dir>/<host-fingerprint>; same request iff
-                # want is that dir (or the full fingerprinted path)
-                if want not in (_enabled, os.path.dirname(_enabled)):
-                    log.warning(
-                        "compile cache already enabled at %s; ignoring "
-                        "request for %s (call reset_for_tests() first to "
-                        "re-point)", _enabled, want,
-                    )
-            return _enabled or None
-        explicit = (
-            cache_dir
-            if cache_dir is not None
-            else nns_config.get_value("xla", "cache_dir", None)
-        )
-        if explicit is None and platform == "cpu":
-            # auto mode on CPU: skip (see docstring); stays retryable so a
-            # later accelerator-backend open() can still enable it
-            log.debug("persistent cache auto-disabled on cpu platform")
+    path = os.environ.get(ENV_VAR)
+    if not path:
+        if jax.default_backend() == "cpu":
             return None
-        raw = _DEFAULT_DIR if explicit is None else explicit
-        if not raw:
-            _enabled = ""
-            return None
-        # per-host subtree: AOT entries are only valid on hosts with the
-        # same CPU feature set (see host_fingerprint)
-        path = os.path.join(os.path.expanduser(raw), host_fingerprint())
-        try:
-            # parse every knob BEFORE mutating jax.config so a bad ini
-            # value cannot leave the cache half-enabled.  min 0: streaming
-            # pipelines recompile per shape bucket, and those sub-second
-            # compiles are exactly the ones worth persisting.
-            min_secs = float(
-                nns_config.get_value(
-                    "xla", "cache_min_compile_secs", "0.0"
-                )
-            )
+        path = CHECKOUT_CACHE
+        if jax.config.jax_compilation_cache_dir != path:
             os.makedirs(path, exist_ok=True)
-            import jax
-
             jax.config.update("jax_compilation_cache_dir", path)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", min_secs
-            )
-        except Exception as e:  # config knob drift must never kill serving
-            log.warning("persistent compilation cache unavailable: %s", e)
-            _enabled = ""
-            return None
-        _enabled = path
-        log.info("XLA persistent compilation cache at %s", path)
-        return path
-
-
-def reset_for_tests() -> None:
-    global _enabled
-    with _lock:
-        _enabled = None
+            log.info("XLA persistent compilation cache at %s", path)
+    # min 0: streaming pipelines recompile per shape bucket, and those
+    # sub-second compiles are exactly the ones worth persisting
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

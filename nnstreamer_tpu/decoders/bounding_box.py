@@ -302,7 +302,7 @@ class BoundingBoxes:
     # -- device-fused half (pipeline fusion pass) ---------------------------
     # Max surviving candidates shipped to host per frame.  128 × 6 floats =
     # 3 KB vs e.g. yolov5's 25200×85 float head = 8.5 MB — a ~2800×
-    # reduction in link traffic, which is exactly where a PCIe/tunnel-bound
+    # reduction in link traffic, which is exactly where a PCIe-bound
     # deployment loses throughput.
     FUSED_TOPK = 128
 
@@ -314,7 +314,7 @@ class BoundingBoxes:
             return self._priors is not None
         return self.mode in ("yolov5", "yolov8")
 
-    def device_fn(self, outs, platform=None):
+    def device_fn(self, outs):
         """jit-traceable half, folded into the upstream filter's XLA
         program: box decode -> score threshold -> top-k preselect ->
         batched per-class NMS (``ops/nms.py``), all on device.  Returns

@@ -50,13 +50,13 @@ class ImageLabeling:
         return out
 
     # -- device-fused half (pipeline fusion pass) ---------------------------
-    def device_fn(self, outs, platform=None):
+    def device_fn(self, outs, single_device=True):
         """jit-traceable half, folded into the upstream filter's XLA
-        program: fused argmax+max (Pallas row-reduction on TPU,
-        ``ops/labeling.py``) so only (index, score) — 8 bytes/frame —
-        ever crosses PCIe instead of the full score tensor.  ``platform``
-        comes from the backend that compiles this (its actual device, not
-        the process default).
+        program: fused argmax+max (Pallas row-reduction when lowered for
+        a TPU, ``ops/labeling.py``) so only (index, score) — 8
+        bytes/frame — ever crosses PCIe instead of the full score tensor.
+        ``single_device`` comes from the backend that compiles this: a
+        program partitioned over a mesh cannot hold the Mosaic kernel.
 
         The pair is packed into ONE float32 (B, 2) tensor so the host
         boundary pays a single transfer per micro-batch instead of two —
@@ -67,7 +67,7 @@ class ImageLabeling:
 
         from ..ops.labeling import top1
 
-        idx, score = top1(outs[0], platform=platform)
+        idx, score = top1(outs[0], use_pallas=single_device)
         return [
             jnp.stack(
                 [idx.astype(jnp.float32), score.astype(jnp.float32)],

@@ -119,7 +119,7 @@ class PoseEstimation:
     def supports_device_fn(self) -> bool:
         return True  # both heatmap modes are static-shape traceable
 
-    def device_fn(self, outs, platform=None):
+    def device_fn(self, outs):
         """jit-traceable half, folded into the upstream filter's XLA
         program: per-keypoint argmax + offset gather on device, so one
         (B, K, 3) [x_in, y_in, score] tensor — ~200 bytes/frame — crosses

@@ -99,7 +99,7 @@ class ImageSegment:
         # caps the class space at 255 (Pascal VOC default is 20).
         return self.mode == "tflite-deeplab" and self.max_labels <= 255
 
-    def device_fn(self, outs, platform=None):
+    def device_fn(self, outs):
         """jit-traceable half: per-pixel argmax + clip on device, so a
         (H, W) uint8 class grid (~66 KB at deeplab 257) crosses the link
         instead of the (H, W, C) float score volume (~5.5 MB at C=21).

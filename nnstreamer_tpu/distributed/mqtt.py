@@ -679,7 +679,7 @@ class MqttClient:
         retained state into a restarted (amnesiac) or failed-over broker."""
         self._on_connect.append(cb)
 
-    def _connect(self) -> None:
+    def _connect(self, reconnect: bool = False) -> None:
         self._host, self._port = self._brokers[self._broker_i]
         sock = socket.create_connection(
             (self._host, self._port), timeout=self._timeout
@@ -703,6 +703,10 @@ class MqttClient:
         sock.settimeout(max(1.0, self._keepalive * 1.5))
         with self._wlock:
             self._sock = sock
+        if reconnect:
+            # counted BEFORE the event: whoever `connected` wakes reads an
+            # exact count
+            self.reconnects += 1
         self.connected.set()
 
     def _resume_session(self) -> None:
@@ -730,10 +734,9 @@ class MqttClient:
         self._stop.wait(self._reconnect_delay_s)
         while not self._stop.is_set():
             try:
-                self._connect()
+                self._connect(reconnect=True)
                 log.info("mqtt client reconnected to %s:%d",
                          self._host, self._port)
-                self.reconnects += 1
                 self._resume_session()
                 for cb in list(self._on_connect):
                     try:

@@ -194,8 +194,10 @@ class TensorGenerator(Element):
     def start(self):
         import jax
 
+        from ..core.compile_cache import enable as enable_compile_cache
         from ..models.transformer import build_stream
 
+        enable_compile_cache()  # before this element's first compile
         props = {}
         for part in self.props["custom"].split(","):
             if ":" in part:
@@ -300,11 +302,8 @@ class TensorGenerator(Element):
                 params = None
                 self._max_seq = int(props.get("seq", str(1 << 30)))
             else:
-                from ..models.transformer import build_slot_stream
-
-                model, params, self._max_seq = build_slot_stream(
-                    props, slots, mesh=mesh)
-                params = self._place_on_survivor(params, mesh)
+                model, params, self._max_seq = self._build_zoo_slot_model(
+                    props, slots, mesh)
             self._params = params
             self._zoo_props = dict(props)
             self._slots = slots
@@ -335,7 +334,8 @@ class TensorGenerator(Element):
                 f"{self.name}: custom sim: needs slots >= 1 (the sim "
                 "proxy drives the slot engine; slots=1 is the "
                 "request-serial baseline)")
-        prefill, decode_chunk, params, self._max_seq = build_stream(props)
+        prefill, decode_chunk, params, self._max_seq = build_stream(
+            props, device=self._serving_device())
         self._prefill = jax.jit(prefill)
         self._decode = decode_chunk
         self._params = params
@@ -568,11 +568,7 @@ class TensorGenerator(Element):
                     props.get("sim_prefill_ms", "0.02")),
             )
             return model, None, self._max_seq
-        from ..models.transformer import build_slot_stream
-
-        model, params, max_seq = build_slot_stream(
-            props, slots, mesh=self._mesh)
-        return model, self._place_on_survivor(params, self._mesh), max_seq
+        return self._build_zoo_slot_model(props, slots, self._mesh)
 
     def _apply_resize(self) -> None:
         """Runs on the DISPATCH thread with the engine idle: build the
@@ -642,28 +638,25 @@ class TensorGenerator(Element):
             self._resize_target = 0
 
     # -- device-loss resilience (degrade, don't die) -------------------------
-    def _place_on_survivor(self, params, mesh):
-        """Commit an UNSHARDED build's params to a surviving device when
-        past losses excluded ordinals — the default placement would hand
-        the dead chip back (``host_init`` pins builds to cpu:0 by
-        design, so the exclusion must be applied post-build; the jitted
-        steps then follow the committed params).  Identity with a mesh
-        (the claim already excludes the dead) or with no exclusions."""
-        if mesh is not None or not self._mesh_exclude or params is None:
-            return params
-        import jax
+    def _serving_device(self):
+        """The ONE device an unsharded model of this element lives on:
+        the process default device — or, once past losses excluded
+        ordinals, a survivor (the default pick would hand the dead chip
+        back)."""
+        from ..backends.jax_xla import pick_device, surviving_device
 
-        from ..core.resilience import DeviceLostError
+        return surviving_device(pick_device(["auto"]), self._mesh_exclude)
 
-        dead = {int(i) for i in self._mesh_exclude}
-        for d in jax.devices():
-            if int(d.id) not in dead:
-                params = jax.device_put(params, d)
-                jax.block_until_ready(params)
-                return params
-        raise DeviceLostError(
-            "no surviving device to place on",
-            device_ids=tuple(sorted(dead)))
+    def _build_zoo_slot_model(self, props, slots: int, mesh):
+        """(model, params, max_seq) for the real transformer — the one
+        build every path shares (start, resize, device-loss rebuild):
+        params AND KV cache land on the mesh, or unsharded on
+        :meth:`_serving_device`, before the first step."""
+        from ..models.transformer import build_slot_stream
+
+        return build_slot_stream(
+            props, slots, mesh=mesh,
+            device=None if mesh is not None else self._serving_device())
 
     def _rebuild_on_device_loss(self, err):
         """SlotEngine ``on_device_lost`` hook (runs on the PUMP thread,
@@ -688,7 +681,6 @@ class TensorGenerator(Element):
         detail = "sim"
         if not self._sim:
             from ..backends.jax_xla import probe_device_ids
-            from ..models.transformer import build_slot_stream
             from ..parallel.mesh import (
                 claim_devices,
                 make_mesh,
@@ -724,9 +716,8 @@ class TensorGenerator(Element):
             self.log.error(
                 "device lost (%s): rebuilding slot model on survivors "
                 "as mesh=%s", err, detail)
-            model, params, self._max_seq = build_slot_stream(
-                self._zoo_props, self._slots, mesh=mesh)
-            params = self._place_on_survivor(params, mesh)
+            model, params, self._max_seq = self._build_zoo_slot_model(
+                self._zoo_props, self._slots, mesh)
             self._mesh = mesh
             self._mesh_axes = axes if mesh is not None else {}
             self._params = params

@@ -391,17 +391,24 @@ def make_stream_generate(
     return prefill, decode_chunk
 
 
-def build_stream(props: Dict[str, str]):
+def build_stream(props: Dict[str, str], device=None):
     """Factory for the streaming-generation element: same ``custom``
     dialect (and seed semantics: ``seed`` = params, ``gen_seed`` =
     sampling) as the zoo transformer, so the streamed tokens are
-    bit-equal to ``generate:<N>`` one-shot serving.  Returns
+    bit-equal to ``generate:<N>`` one-shot serving.  Params are committed
+    to ``device`` (default: the process default device); the cache is
+    created inside the jitted prefill and follows them.  Returns
     (prefill, decode_chunk, params, max_seq)."""
+    from ..core.hw import default_device
+
     cfg = _cfg_from_props(props)
-    params = host_init(
-        TransformerLM(cfg).init,
-        int(props.get("seed", "0")),
-        np.zeros((1, min(8, cfg.max_seq)), np.int32),
+    params = jax.device_put(
+        host_init(
+            TransformerLM(cfg).init,
+            int(props.get("seed", "0")),
+            np.zeros((1, min(8, cfg.max_seq)), np.int32),
+        ),
+        device if device is not None else default_device(),
     )
     prefill, decode_chunk = make_stream_generate(
         cfg,
@@ -441,16 +448,21 @@ class SlotModel:
       pick ``k = min(chunk, min remaining)`` so streams complete exactly
       at scan boundaries).  Compiled once per (slot width, k) — the
       idle-slot mask keeps each bucket shape-stable as streams churn.
-      The cache argument is DONATED off-CPU (the engine's cache is
-      caller-private — PR-6 donation discipline; XLA ignores donation on
-      CPU and warns, so it is gated exactly like
+      The cache argument is DONATED when the model's devices are not CPU
+      (the engine's cache is caller-private — PR-6 donation discipline;
+      XLA ignores donation on CPU and warns, so it is gated exactly like
       ``backends/jax_xla._donation_ok``).
+
+    Placement: with a ``mesh`` params and pages shard over it; without
+    one BOTH are committed to ``device`` (default: the process default
+    device), so every prefill and decode step runs there — nothing is
+    left for jit to place by default.
     """
 
     def __init__(self, cfg: TransformerConfig, slots: int,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
                  donate: Optional[bool] = None,
-                 mesh: Optional[Mesh] = None):
+                 mesh: Optional[Mesh] = None, device=None):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
         self.cfg = cfg
@@ -467,9 +479,17 @@ class SlotModel:
         # are tiny).  GSPMD propagates the placements through the jitted
         # step, so the shape-stable bucket contract is unchanged.
         self.mesh = mesh
+        self.device = None
         self._page_sharding = None
-        if mesh is not None:
+        if mesh is None:
+            from ..core.hw import default_device
+
+            self.device = device if device is not None else default_device()
+            platform = self.device.platform
+        else:
             from jax.sharding import NamedSharding, PartitionSpec as P
+
+            platform = next(iter(mesh.devices.flat)).platform
 
             tp = mesh.shape.get("tp", 1)
 
@@ -482,7 +502,7 @@ class SlotModel:
 
             self._page_sharding = page_spec
         if donate is None:
-            donate = jax.default_backend() != "cpu"
+            donate = platform != "cpu"
         self._donate = (1,) if donate else ()
         #: compile counters — the shape-stability contract is observable
         #: (tests pin decode_compiles staying at the bucket count across
@@ -492,14 +512,16 @@ class SlotModel:
         self.reset_slot = jax.jit(self._reset_slot)
         self.pick_first = jax.jit(self._pick_first)
 
-    def shard_params(self, params):
-        """Place a host param pytree for this model's mesh (tp rules;
-        fully staged before return) — identity when unsharded."""
+    def place_params(self, params):
+        """Place a param pytree where this model runs, fully staged
+        before return: tp-sharded over the mesh, else committed to the
+        model's device."""
         if self.mesh is None:
-            return params
-        from ..parallel.sharding import shard_params, transformer_rules
+            params = jax.device_put(params, self.device)
+        else:
+            from ..parallel.sharding import shard_params, transformer_rules
 
-        params = shard_params(params, self.mesh, transformer_rules())
+            params = shard_params(params, self.mesh, transformer_rules())
         jax.block_until_ready(params)
         return params
 
@@ -519,7 +541,8 @@ class SlotModel:
                 shapes,
             )
         return jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), shapes
+            lambda s: jnp.zeros(s.shape, s.dtype, device=self.device),
+            shapes,
         )
 
     @staticmethod
@@ -670,16 +693,17 @@ class SlotModel:
 
 def build_slot_stream(props: Dict[str, str], slots: int,
                       donate: Optional[bool] = None,
-                      mesh: Optional[Mesh] = None):
+                      mesh: Optional[Mesh] = None, device=None):
     """Factory for the CONTINUOUS-BATCHING generator path: same
     ``custom`` dialect and seed semantics as :func:`build_stream`
     (``seed`` = params, ``gen_seed`` = sampling), so a single occupant's
     stream is bit-equal to ``generate:<N>`` one-shot serving.  With a
     ``mesh`` the params tensor-shard on tp and the per-slot KV pages
-    shard on heads along tp (params fully staged across the mesh before
-    return) — the token SEQUENCE is unchanged, only its placement, so
-    the stream-continuity resume signature deliberately excludes the
-    mesh.  Returns ``(SlotModel, params, max_seq)``."""
+    shard on heads along tp; without one both are committed to
+    ``device`` (default: the process default device).  Params are fully
+    staged before return.  The token SEQUENCE is unchanged, only its
+    placement, so the stream-continuity resume signature deliberately
+    excludes mesh and device.  Returns ``(SlotModel, params, max_seq)``."""
     cfg = _cfg_from_props(props)
     params = host_init(
         TransformerLM(cfg).init,
@@ -693,9 +717,9 @@ def build_slot_stream(props: Dict[str, str], slots: int,
         seed=int(props.get("gen_seed", "0")),
         donate=donate,
         mesh=mesh,
+        device=device,
     )
-    params = model.shard_params(params)
-    return model, params, cfg.max_seq
+    return model, model.place_params(params), cfg.max_seq
 
 
 def build(custom_props=None):

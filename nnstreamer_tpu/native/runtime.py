@@ -1,9 +1,12 @@
 """ctypes bindings for the native core (libnns_tpu_core.so).
 
-Builds the library on demand with g++ (no pybind11 in this image; the C
-ABI + ctypes keeps the boundary simple).  Everything degrades gracefully:
-if the toolchain or build is unavailable, ``available()`` returns False and
-the pipeline runtime falls back to ``queue.Queue``.
+Builds the library on first use with g++, blocking (about a second; no
+pybind11 in this image — the C ABI + ctypes keeps the boundary simple), so
+every pipeline of a process, the first included, runs the same dataplane.
+If the toolchain or build is unavailable, ``available()`` returns False and
+the pipeline runtime runs on ``queue.Queue``; :func:`mailbox_impl` says
+which one a process got, and anything that measures treats "not native"
+as a failure.
 
 :class:`NativeMailbox` is API-compatible with the ``queue.Queue`` subset
 the scheduler uses (put/put_nowait/get/get_nowait raising queue.Full/Empty)
@@ -59,42 +62,25 @@ def _build() -> Optional[str]:
     return _SO
 
 
-_bg_build: Optional[threading.Thread] = None
-
-
 def _so_fresh() -> bool:
     return os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
 
 
-def _load(block: bool = False) -> Optional[ctypes.CDLL]:
-    """dlopen the core library.  When the .so is not built yet, `block=False`
-    (the pipeline-start path) kicks off a background compile and returns
-    None — the FIRST pipeline falls back to queue.Queue instead of stalling
-    behind a 2-minute g++ run; later pipelines pick the library up."""
-    global _lib, _build_failed, _bg_build
+def _load() -> Optional[ctypes.CDLL]:
+    """dlopen the core library, compiling it first when the .so is missing
+    or older than its source.  A failed build is remembered: the process
+    stays on queue.Queue rather than re-running g++ per mailbox."""
+    global _lib, _build_failed
     if _lib is not None:
         return _lib
     if _build_failed:
         return None
     if os.environ.get("NNS_TPU_NO_NATIVE"):
         return None
-    if not _so_fresh() and not block:
-        with _build_lock:
-            if _bg_build is None or not _bg_build.is_alive():
-                def _bg():
-                    global _build_failed
-                    if _build() is None:
-                        _build_failed = True  # fail once, fall back forever
-
-                _bg_build = threading.Thread(
-                    target=_bg, name="nns-native-build", daemon=True
-                )
-                _bg_build.start()
-        return None
     with _build_lock:
         if _lib is not None or _build_failed:
             return _lib
-        so = _SO if _so_fresh() else _build()
+        so = _build()
         if so is None:
             _build_failed = True
             return None
@@ -152,10 +138,15 @@ def _load(block: bool = False) -> Optional[ctypes.CDLL]:
         return _lib
 
 
-def available(block: bool = False) -> bool:
-    """True when the native core is loadable now.  ``block=True`` waits for
-    (or performs) the compile — tests use it; the runtime path does not."""
-    return _load(block=block) is not None
+def available() -> bool:
+    """True when the native core is loaded (built first if need be)."""
+    return _load() is not None
+
+
+def mailbox_impl() -> str:
+    """Which mailbox the pipelines of this process run on: ``"native"``
+    (the C++ condvar queue) or ``"queue.Queue"``."""
+    return "native" if available() else "queue.Queue"
 
 
 class NativeMailbox:

@@ -11,7 +11,10 @@ This is the intra-device complement of the sequence-parallel layers:
 each device's local block product is exactly what this kernel computes.
 
 ``flash_attention(q, k, v)`` takes (B, T, H, D) like the rest of the
-stack.  Off-TPU it falls back to the fused-XLA reference implementation;
+stack.  A program lowered for a TPU runs the kernel; every other
+platform lowers the fused-XLA reference (``lax.platform_dependent``: the
+choice follows the device the program is compiled for, and nothing on a
+TPU declines the kernel quietly — a shape it cannot take raises).
 ``interpret=True`` (tests only) runs the kernel in the Pallas interpreter
 instead.
 """
@@ -19,87 +22,88 @@ instead.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # large-negative instead of -inf: avoids NaN in exp-diff
+_LANES = 128      # running max / sum live lane-replicated in VMEM scratch
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
-                  causal: bool, scale: float, seq_len: int, block_q: int,
-                  valid_len: int):
-    """One (batch*head, q-block) program: stream K/V blocks, online softmax.
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
+                  block_k: int, causal: bool, scale: float, seq_len: int,
+                  valid_len: int, with_lse: bool):
+    """One (batch*head, q-block, kv-block) program of the online softmax.
 
-    q_ref (block_q, D); k_ref/v_ref (T, D) — the whole K/V for this head
-    (the wrapper budget-checks VMEM and falls back to the XLA reference
-    path when a head's K/V would not fit); o_ref (block_q, D).
-    ``valid_len`` < seq_len marks wrapper padding: K columns at or past it
-    are masked out (static python int — the mask compiles to constants).
+    The kv-block axis is the innermost, sequential grid axis: the running
+    max ``m``, sum ``l`` (both (block_q, 128), lane-replicated) and the
+    f32 accumulator persist in VMEM scratch across it; the first kv step
+    initializes them, the last one normalizes into ``o_ref``.  q_ref
+    (block_q, D); k_ref/v_ref (block_k, D) — only one K/V block is ever
+    resident, so T is bounded by HBM, not VMEM.  ``valid_len`` < seq_len
+    marks wrapper padding: K columns at or past it are masked out (static
+    python int — the mask compiles to constants).
     """
-    qi = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32) * scale
-    D = q.shape[-1]
-    n_kv = seq_len // block_k
-    padded = valid_len < seq_len
+    if with_lse:
+        lse_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        m_scr, l_scr, acc_scr = rest
+    qi, ki = pl.program_id(1), pl.program_id(2)
 
-    def body(j, carry):
-        m_prev, l_prev, acc = carry
-        k = lax.dynamic_slice_in_dim(
-            k_ref[:], j * block_k, block_k, axis=0
-        ).astype(jnp.float32)
-        v = lax.dynamic_slice_in_dim(
-            v_ref[:], j * block_k, block_k, axis=0
-        ).astype(jnp.float32)
-        s = q @ k.T  # (block_q, block_k) on the MXU
-        if causal or padded:
-            k_pos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def _step():
+        k = k_ref[...]
+        v = v_ref[...]
+        # q . k^T on the MXU in the input dtype, f32 accumulation
+        s = lax.dot_general(
+            q_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (block_q, block_k)
+        if causal or valid_len < seq_len:
+            k_pos = ki * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
         if causal:
             q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
+                jnp.int32, (block_q, block_k), 0)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        if padded:
+        if valid_len < seq_len:
             s = jnp.where(k_pos < valid_len, s, _NEG_INF)
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new[:, :1])
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + p @ v
-        return m_new, l_new, acc
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
 
-    m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, D), jnp.float32)
     if causal:
-        # blocks strictly above the diagonal contribute nothing; bound the
-        # loop at the q-block's last row (traced upper bound via while)
-        n_kv_eff = lax.min(
-            n_kv, (qi * block_q + block_q + block_k - 1) // block_k
-        )
+        # kv blocks strictly above the diagonal contribute nothing
+        pl.when(ki * block_k <= qi * block_q + (block_q - 1))(_step)
     else:
-        n_kv_eff = n_kv
-    m, l, acc = lax.fori_loop(0, n_kv_eff, body, (m0, l0, acc0))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    if lse_ref:
-        # per-row log-sum-exp of the (masked) scores: the cross-block
-        # merge statistic for ring attention (sequence parallelism);
-        # fully-masked rows keep a large-negative lse (l == 0)
-        lse = jnp.where(
-            l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), _NEG_INF
-        )
-        lse_ref[0][:] = lse[:, None].astype(jnp.float32)
+        _step()
 
-
-try:  # imported lazily below for environments without pallas
-    from jax.experimental import pallas as pl
-except ImportError:  # pragma: no cover
-    pl = None
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finish():
+        l = l_scr[:, :1]
+        o_ref[...] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(
+            o_ref.dtype)
+        if with_lse:
+            # per-row log-sum-exp of the (masked) scores: the cross-block
+            # merge statistic for ring attention (sequence parallelism);
+            # fully-masked rows keep a large-negative lse (l == 0)
+            lse_ref[...] = jnp.where(
+                l > 0.0, m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30)),
+                _NEG_INF)
 
 
 @functools.partial(
@@ -110,8 +114,9 @@ except ImportError:  # pragma: no cover
 def _flash_bh(qf, kf, vf, causal: bool, block_q: int, block_k: int,
               interpret: bool, valid_len: int, with_lse: bool = False):
     """(BH, Tq, D) + (BH, Tk, D) K/V -> (BH, Tq, D) [+ (BH, Tq, 1) f32
-    lse]; grid over (BH, Tq/block_q).  Tk may differ from Tq (ring hops /
-    partial-key calls) — causal requires Tq == Tk (aligned positions)."""
+    lse]; grid over (BH, Tq/block_q, Tk/block_k).  Tk may differ from Tq
+    (ring hops / partial-key calls) — causal requires Tq == Tk (aligned
+    positions)."""
     BH, Tq, D = qf.shape
     Tk = kf.shape[1]
     if causal and Tq != Tk:
@@ -120,124 +125,105 @@ def _flash_bh(qf, kf, vf, causal: bool, block_q: int, block_k: int,
         raise ValueError(
             f"causal flash needs aligned q/k positions (Tq={Tq}, Tk={Tk})"
         )
-    scale = 1.0 / (D**0.5)
+    if Tq % block_q or Tk % block_k:
+        raise ValueError(
+            f"flash blocks ({block_q}, {block_k}) do not tile (Tq={Tq}, "
+            f"Tk={Tk})")
     kern = functools.partial(
-        _flash_kernel, block_k=block_k, causal=causal, scale=scale,
-        seq_len=Tk, block_q=block_q, valid_len=valid_len,
+        _flash_kernel, block_q=block_q, block_k=block_k, causal=causal,
+        scale=1.0 / (D**0.5), seq_len=Tk, valid_len=valid_len,
+        with_lse=with_lse,
     )
     # under shard_map (ring hops) outputs must declare their varying
-    # mesh axes (jax >= 0.9 vma typing); inherit from the traced input
-    vma = getattr(qf.aval, "vma", None)
+    # mesh axes (vma typing); inherit from the traced input
+    vma = getattr(qf.aval, "vma", None) or frozenset()
 
-    def _sds(shape, dtype):
-        if vma:
-            try:
-                return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-            except TypeError:  # pragma: no cover — older jax
-                pass
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-    out_shape = [_sds((BH, Tq, D), qf.dtype)]
-    out_specs = [pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype, vma=vma)]
+    # None squeezes the batch*head dim out of the kernel refs
+    out_specs = [pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0))]
     if with_lse:
         # trailing length-1 lane dim keeps the ref 2-D for Mosaic tiling
-        out_shape.append(_sds((BH, Tq, 1), jnp.float32))
+        out_shape.append(
+            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32, vma=vma))
         out_specs.append(
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0))
-        )
+            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)))
     res = pl.pallas_call(
         kern,
         out_shape=out_shape,
-        grid=(BH, Tq // block_q),
+        grid=(BH, Tq // block_q, Tk // block_k),
         in_specs=[
-            # None squeezes the batch*head dim out of the kernel refs
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, block_k, D), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
+            pltpu.VMEM((block_q, D), jnp.float32),       # accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
     return res if with_lse else res[0]
 
 
-def _kernel_usable(Tq, Tk, D, dtype, bq, bk, interpret, causal=False,
-                   aligned=True):
-    """Shared gate for both entry points: can the Pallas kernel run here,
-    or must the call fall back to the fused-XLA reference path?  One
-    predicate so the two entry points can never drift to different
-    fallback shapes."""
-    if pl is None:
-        return False
-    if jax.default_backend() != "tpu" and not interpret:
-        return False
-    itemsize = jnp.dtype(dtype).itemsize
-    # VMEM: one head's full K/V + the q block + f32 accumulators; past
-    # ~3/4 of the ~16 MB VMEM fall back instead of an opaque Mosaic
-    # overflow.  Constrains only the compiled kernel, not the interpreter.
-    vmem_est = (2 * Tk * D) * itemsize + bq * D * (itemsize + 4) \
-        + bq * bk * 4
-    if vmem_est > 12 * 1024 * 1024 and not interpret:
-        return False
-    if interpret and max(Tq, Tk) > 4096:
-        return False
-    if causal and not aligned:
-        return False
-    return True
+def _blocks(T: int, block_q: int, block_k: int):
+    """(T_pad, bq, bk): the padded length and the blocks that tile it.  A
+    sequence shorter than a block becomes ONE block, rounded up to the
+    sublane tile; a longer one pads to a multiple of both blocks."""
+    if T <= min(block_q, block_k):
+        T_pad = -(-T // 16) * 16
+        return T_pad, T_pad, T_pad
+    blk = math.lcm(block_q, block_k)
+    T_pad = -(-T // blk) * blk
+    return T_pad, block_q, block_k
+
+
+def _to_heads(x):
+    # (B, T, H, D) -> (B*H, T, D): each (batch, head) is one independent
+    # attention problem
+    B, T, H, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None):
+                    block_k: int = 128, interpret: bool = False):
     """Exact attention, (B, T, H, D) -> (B, T, H, D).
 
-    TPU: real Pallas kernel.  Elsewhere: interpret mode when requested
-    (tests), else the fused-XLA reference path (same numerics contract).
+    Lowered for a TPU: the Pallas kernel.  Lowered for anything else: the
+    fused-XLA reference path (same numerics contract), unless
+    ``interpret=True`` asks for the kernel in the Pallas interpreter —
+    TESTS only, orders of magnitude slower than XLA.
     """
     B, T, H, D = q.shape
-    # interpret mode is for TESTS only (explicitly requested): it executes
-    # the kernel block-by-block in the interpreter, orders of magnitude
-    # slower than XLA.  Off-TPU without an explicit request -> reference.
-    if interpret is None:
-        interpret = False
     # non-divisible T (e.g. ViT's (S/p)^2 + 1 tokens): pad K/V/Q up to a
     # multiple of BOTH block sizes; padded K columns are masked inside the
     # kernel via the static valid_len, padded Q rows are sliced off below
-    bq, bk = min(block_q, T), min(block_k, T)
-    T_pad = T
-    if T % bq or T % bk:
-        import math
+    T_pad, bq, bk = _blocks(T, block_q, block_k)
 
-        blk = max(block_q, block_k)
-        if blk % min(block_q, block_k):
-            blk = math.lcm(block_q, block_k)
-        T_pad = -(-T // blk) * blk
-        # T_pad >= blk >= both requested blocks, and divides both
-        bq, bk = min(block_q, T_pad), min(block_k, T_pad)
-    if not _kernel_usable(T_pad, T_pad, D, q.dtype, bq, bk, interpret):
+    def kernel(q, k, v):
+        qf, kf, vf = _to_heads(q), _to_heads(k), _to_heads(v)
+        if T_pad != T:
+            pad = ((0, 0), (0, T_pad - T), (0, 0))
+            qf, kf, vf = jnp.pad(qf, pad), jnp.pad(kf, pad), jnp.pad(vf, pad)
+        out = _flash_bh(
+            qf, kf, vf, causal, bq, bk, bool(interpret), valid_len=T)
+        return out[:, :T].reshape(B, H, T, D).transpose(0, 2, 1, 3)
+
+    def reference(q, k, v):
         from ..parallel.ring_attention import reference_attention
 
         return reference_attention(q, k, v, causal=causal).astype(q.dtype)
-    # (B, T, H, D) -> (B*H, T, D): each (batch, head) is one independent
-    # attention problem; kernel VMEM holds one head's K/V
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-    if T_pad != T:
-        pad = ((0, 0), (0, T_pad - T), (0, 0))
-        qf = jnp.pad(qf, pad)
-        kf = jnp.pad(kf, pad)
-        vf = jnp.pad(vf, pad)
-    out = _flash_bh(
-        qf, kf, vf, causal, bq, bk, bool(interpret), valid_len=T
-    )
-    if T_pad != T:
-        out = out[:, :T]
-    return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+
+    if interpret:
+        return kernel(q, k, v)
+    return lax.platform_dependent(q, k, v, tpu=kernel, default=reference)
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 128,
-                        block_k: int = 128,
-                        interpret: Optional[bool] = None):
+                        block_k: int = 128, interpret: bool = False):
     """Exact attention + per-row log-sum-exp: (B, T, H, D) ->
     ((B, T, H, D), (B, H, T) f32).
 
@@ -249,31 +235,29 @@ def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 128,
 
     which is how ``parallel/ring_attention.py`` composes this kernel
     across the ``sp`` ring (each hop's K/V block -> one kernel call).
-    Falls back to the fused-XLA reference (same contract) off-TPU unless
-    ``interpret=True``.
+    Ring blocks are uniform, so there is no padding path: blocks that do
+    not tile the sequence raise.  Platform choice as in
+    :func:`flash_attention`.
     """
     B, T, H, D = q.shape
     Tk = k.shape[1]
-    if interpret is None:
-        interpret = False
     bq, bk = min(block_q, T), min(block_k, Tk)
-    if (
-        not _kernel_usable(T, Tk, D, q.dtype, bq, bk, interpret,
-                           causal=causal, aligned=(T == Tk))
-        or T % bq or Tk % bk  # ring blocks are uniform; no padding path
-    ):
-        return reference_attention_lse(q, k, v, causal=causal)
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    out, lse = _flash_bh(
-        qf, kf, vf, causal, bq, bk, bool(interpret), valid_len=Tk,
-        with_lse=True,
-    )
-    return (
-        out.reshape(B, H, T, D).transpose(0, 2, 1, 3),
-        lse.reshape(B, H, T),
-    )
+
+    def kernel(q, k, v):
+        out, lse = _flash_bh(
+            _to_heads(q), _to_heads(k), _to_heads(v), causal, bq, bk,
+            bool(interpret), valid_len=Tk, with_lse=True,
+        )
+        return (
+            out.reshape(B, H, T, D).transpose(0, 2, 1, 3),
+            lse.reshape(B, H, T),
+        )
+
+    if interpret:
+        return kernel(q, k, v)
+    return lax.platform_dependent(
+        q, k, v, tpu=kernel,
+        default=functools.partial(reference_attention_lse, causal=causal))
 
 
 def reference_attention_lse(q, k, v, causal: bool = True):
@@ -313,8 +297,7 @@ def reference_attention_lse(q, k, v, causal: bool = True):
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_grad(q, k, v, causal: bool = True, block_q: int = 128,
-                         block_k: int = 128,
-                         interpret: Optional[bool] = None):
+                         block_k: int = 128, interpret: bool = False):
     """Differentiable flash attention: (B, T, H, D) -> (B, T, H, D).
 
     Forward runs the Pallas kernel (or its documented fallbacks);

@@ -2,9 +2,11 @@
 
 The canonical image-ingest hot path (``tensor_transform mode=arithmetic``
 chains + typecast in the reference, ORC-accelerated there): one VMEM-tiled
-pass computing ``x * scale + bias`` in the target dtype.  On TPU this runs
-as a real Pallas kernel (VPU elementwise, lane-aligned tiles); elsewhere it
-runs the identical jnp expression (XLA fuses it anyway) — same numerics.
+pass computing ``x * scale + bias`` in the target dtype.  A program
+lowered for a TPU runs the Pallas kernel (VPU elementwise, lane-aligned
+tiles); every other platform lowers the identical jnp expression
+(``lax.platform_dependent``: the choice follows the device the program
+is compiled for).
 """
 
 from __future__ import annotations
@@ -14,33 +16,42 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
 
 _LANES = 128
-_ROWS = 256  # block rows: multiple of every dtype's sublane minimum
+_ROWS = 2048  # block rows: multiple of every dtype's sublane minimum
 
 
-def _kernel(x_ref, o_ref, *, scale: float, bias: float, out_dtype):
-    x = x_ref[:].astype(jnp.float32)
-    o_ref[:] = (x * scale + bias).astype(out_dtype)
+def _kernel(x_ref, o_ref, *, scale: float, bias: float):
+    x = x_ref[...]
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        # widen through int32: the one integer->float path every Mosaic
+        # generation lowers for 8-bit inputs
+        x = x.astype(jnp.int32)
+    o_ref[...] = (x.astype(jnp.float32) * scale + bias).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bias", "out_dtype"))
-def _pallas_normalize(flat, *, scale: float, bias: float, out_dtype):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    rows = flat.shape[0] // _LANES
-    x2 = flat.reshape(rows, _LANES)
-    grid = (max(1, rows // _ROWS),)
-    blk = min(_ROWS, rows)
+@functools.partial(
+    jax.jit, static_argnames=("scale", "bias", "out_dtype", "interpret"))
+def _pallas_normalize(x, *, scale: float, bias: float, out_dtype,
+                      interpret: bool = False):
+    n = x.size
+    tile = _ROWS * _LANES
+    padded = (n + tile - 1) // tile * tile
+    flat = x.reshape(-1)
+    if padded != n:
+        flat = jnp.pad(flat, (0, padded - n))
+    rows = padded // _LANES
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, bias=bias, out_dtype=out_dtype),
+        functools.partial(_kernel, scale=scale, bias=bias),
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), out_dtype),
-        grid=grid,
-        in_specs=[pl.BlockSpec((blk, _LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((blk, _LANES), lambda i: (i, 0)),
-    )(x2)
-    return out.reshape(flat.shape)
+        grid=(rows // _ROWS,),
+        in_specs=[pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
+        interpret=interpret,
+    )(flat.reshape(rows, _LANES))
+    return out.reshape(-1)[:n].reshape(x.shape)
 
 
 def normalize_u8(
@@ -49,20 +60,22 @@ def normalize_u8(
     bias: float = -1.0,
     dtype: Any = jnp.bfloat16,
     use_pallas: bool = True,
+    interpret: bool = False,
 ):
     """``x * scale + bias`` cast to `dtype` (default: uint8 [0,255] ->
-    [-1, 1] bf16, the MobileNet ingest transform).  Accepts any shape."""
+    [-1, 1] bf16, the MobileNet ingest transform).  Accepts any shape.
+    ``interpret`` runs the kernel in the Pallas interpreter (tests)."""
     x = jnp.asarray(x)
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not (use_pallas and on_tpu):
-        return (x.astype(jnp.float32) * scale + bias).astype(dtype)
-    n = x.size
-    tile = _ROWS * _LANES
-    padded = (n + tile - 1) // tile * tile
-    flat = x.reshape(-1)
-    if padded != n:
-        flat = jnp.pad(flat, (0, padded - n))
-    out = _pallas_normalize(
-        flat, scale=float(scale), bias=float(bias), out_dtype=jnp.dtype(dtype)
-    )
-    return out[:n].reshape(x.shape)
+    out_dtype = jnp.dtype(dtype)
+
+    def plain(a):
+        return (a.astype(jnp.float32) * scale + bias).astype(out_dtype)
+
+    if not use_pallas:
+        return plain(x)
+    kernel = functools.partial(
+        _pallas_normalize, scale=float(scale), bias=float(bias),
+        out_dtype=out_dtype)
+    if interpret:
+        return kernel(x, interpret=True)
+    return lax.platform_dependent(x, tpu=kernel, default=plain)

@@ -99,31 +99,7 @@ def initialize(
             log.warning("cpu collectives unavailable (%s): cross-process "
                         "computations may fail", e)
     if local_device_count:
-        if hasattr(jax.config, "jax_num_cpu_devices"):
-            jax.config.update("jax_num_cpu_devices", local_device_count)
-        else:
-            # older jax (0.4.x) has no post-import device-count config;
-            # XLA reads XLA_FLAGS at backend init, which this contract
-            # already requires to be in the future ("call before any
-            # other jax API touches devices") — fresh worker processes
-            # always satisfy it.  An INHERITED device-count flag (the
-            # test conftest exports one) is rewritten, not silently
-            # kept: the caller's count wins, loudly.
-            import re
-
-            flags = os.environ.get("XLA_FLAGS", "")
-            want = (f"--xla_force_host_platform_device_count="
-                    f"{local_device_count}")
-            pat = r"--xla_force_host_platform_device_count=\d+"
-            if re.search(pat, flags):
-                if want not in flags:
-                    log.warning(
-                        "overriding inherited XLA device-count flag with "
-                        "local_device_count=%d", local_device_count)
-                flags = re.sub(pat, want, flags)
-            else:
-                flags = f"{flags} {want}".strip()
-            os.environ["XLA_FLAGS"] = flags
+        jax.config.update("jax_num_cpu_devices", local_device_count)
 
     if coordinator is None and num_processes is None:
         # real pod: everything comes from the cluster environment

@@ -36,12 +36,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
-
-from .ring_attention import _ring_attn_local, shard_map_compat, vary_over
+from .ring_attention import _ring_attn_local
 
 
 @dataclasses.dataclass(frozen=True)
@@ -308,8 +303,9 @@ def make_pipeline_train_step(
             k: v for k, v in p.items()
             if k not in ("embed", "pos", "ln_f", "lm_head")
         }
-        state0 = vary_over(jnp.zeros((mb, T_loc, D), cfg.dtype), AXES)
-        l0 = vary_over(jnp.zeros((), jnp.float32), AXES)
+        state0 = lax.pcast(
+            jnp.zeros((mb, T_loc, D), cfg.dtype), AXES, to="varying")
+        l0 = lax.pcast(jnp.zeros((), jnp.float32), AXES, to="varying")
         (_, loss_sum, cnt), _ = lax.scan(
             tick, (state0, l0, l0), jnp.arange(M + S - 1)
         )
@@ -322,13 +318,11 @@ def make_pipeline_train_step(
     # ---- per-shard loss AND grad in ONE shard-mapped body ----------------
     # value_and_grad lives INSIDE the body (per-shard grads, psum'd over
     # each param's replication axes) instead of wrapping the shard_map:
-    # differentiating through a shard_map with replicated out_specs is
-    # exactly the transform old (pre-vma) jax cannot transpose
-    # (_SpecError), while per-shard AD through the body's collectives is
-    # the classic pmap-era recipe every jax generation supports.  The
-    # math is identical: the final psum's transpose seeds cotangent 1 on
-    # every device, so local partials summed over a param's replication
-    # axes ARE the global grad.
+    # per-shard AD through the body's collectives is the classic
+    # pmap-era recipe, and it keeps the replicated-out_specs transpose
+    # out of the picture.  The math is identical: the final psum's
+    # transpose seeds cotangent 1 on every device, so local partials
+    # summed over a param's replication axes ARE the global grad.
     mesh_axes = tuple(mesh.axis_names)
 
     def _repl_axes(spec: P):
@@ -353,10 +347,10 @@ def make_pipeline_train_step(
         return loss, grads
 
     in_specs = ({k: specs[k] for k in params}, P("dp", "sp"))
-    sharded_loss_and_grad = shard_map_compat(
-        _fwd_loss_and_grad, mesh, in_specs=in_specs,
+    sharded_loss_and_grad = jax.shard_map(
+        _fwd_loss_and_grad, mesh=mesh, in_specs=in_specs,
         out_specs=(P(), {k: specs[k] for k in params}),
-        check=False,
+        check_vma=False,
     )
 
     def _step(p, opt, tokens):

@@ -21,48 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
-
-
-def vary_over(x, axes):
-    """Mark a constant as device-varying over manual mesh axes (shard_map
-    vma typing; pcast on jax >= 0.8, pvary before).  On jax generations
-    WITHOUT vma typing (0.4.x: neither pcast nor pvary exists) the mark
-    is meaningless — closed-over constants are handled by the old
-    ``check_rep`` replication tracking — so the identity is correct."""
-    try:
-        return lax.pcast(x, axes, to="varying")
-    except (AttributeError, TypeError):  # pragma: no cover — older jax
-        pass
-    try:
-        return lax.pvary(x, axes)
-    except AttributeError:  # pre-vma jax: no mark exists or is needed
-        return x
-
-
-def shard_map_compat(body, mesh, in_specs, out_specs, check: bool = True):
-    """``shard_map`` across jax generations: the strictness knob is
-    ``check_vma`` on vma-typed jax (>= 0.8 era), ``check_rep`` on the
-    older replication-tracked jax, and absent before either.  Callers
-    pass ``check=False`` for bodies the checker cannot type (the pallas
-    interpreter emits internal constants without vma, and old jax has no
-    pallas replication rule at all) — the SAME intent lands on whichever
-    kwarg this jax speaks.  One wrapper shared by every manual-SPMD
-    subsystem (ring/ulysses attention, the pipeline-parallel trainer) so
-    the version shim cannot drift between them."""
-    for kwargs in ({"check_vma": check}, {"check_rep": check}, {}):
-        try:
-            return shard_map(
-                body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kwargs,
-            )
-        except TypeError:  # this jax doesn't know the kwarg — next shim
-            continue
-    raise RuntimeError("shard_map rejected every known strictness kwarg")
-
 
 def _block_attn(q, k, v, q_pos, k_pos, causal: bool, scale: float):
     """One (q-block × kv-block) attention contribution.
@@ -128,7 +86,7 @@ def _ring_flash_local(q, k, v, *, axis_name: str, causal: bool,
     B, T, H, D = q.shape
     # this body runs under check_vma=False (the pallas interpreter emits
     # constants without vma, tripping strict varying-axes typing), so the
-    # accumulators need no vary_over marking
+    # accumulators need no varying mark
     lse_acc = jnp.full((B, H, T), _NEG_INF, jnp.float32)
     o_acc = jnp.zeros(q.shape, jnp.float32)
     perm = [(j, (j + 1) % ring_size) for j in range(ring_size)]
@@ -169,9 +127,12 @@ def _ring_attn_local(q, k, v, *, axis_name: str, all_axes, causal: bool):
 
     # constants entering the scan carry must be marked device-varying over
     # the manual mesh axes (shard_map vma typing)
-    m0 = vary_over(jnp.full((B, H, T), -jnp.inf, jnp.float32), all_axes)
-    o0 = vary_over(jnp.zeros(q.shape, jnp.float32), all_axes)
-    l0 = vary_over(jnp.zeros((B, H, T), jnp.float32), all_axes)
+    def varying(x):
+        return lax.pcast(x, all_axes, to="varying")
+
+    m0 = varying(jnp.full((B, H, T), -jnp.inf, jnp.float32))
+    o0 = varying(jnp.zeros(q.shape, jnp.float32))
+    l0 = varying(jnp.zeros((B, H, T), jnp.float32))
     perm = [(j, (j + 1) % axis_size) for j in range(axis_size)]
 
     def step(carry, i):
@@ -237,12 +198,11 @@ def ring_attention(
             causal=causal,
         )
     # the pallas interpreter/lowering emits internal constants without
-    # vma (and pre-vma jax has no pallas replication rule at all);
-    # jax's documented workaround is to disable the check for this body
-    # (the jnp ring keeps strict typing)
-    fn = shard_map_compat(
-        body, mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check=not use_flash,
+    # vma; jax's documented workaround is to disable the check for this
+    # body (the jnp ring keeps strict typing)
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=not use_flash,
     )
     return fn(q, k, v)
 

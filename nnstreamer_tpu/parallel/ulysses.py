@@ -20,15 +20,12 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .ring_attention import (
-    reference_attention,
-    ring_attention,
-    shard_map_compat,
-)
+from .ring_attention import reference_attention, ring_attention
 
 
 def _local_attention(q, k, v, causal: bool):
@@ -87,9 +84,9 @@ def ulysses_attention(
         else (batch_axes[0] if len(batch_axes) == 1 else batch_axes)
     )
     spec = P(batch_spec, seq_axis, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_ulysses_local, seq_axis=seq_axis, causal=causal),
-        mesh,
+        mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
     )

@@ -24,14 +24,9 @@ def has_reference_tree() -> bool:
 @functools.lru_cache(maxsize=None)
 def spmd_stack_ok() -> bool:
     """PROBE-AND-RUN: True when a tiny shard_map program actually runs
-    on this process's multi-device CPU mesh through the repo's own
-    compat shim (``parallel.ring_attention.shard_map_compat`` maps the
-    strictness knob to ``check_vma``/``check_rep``/nothing per jax
-    generation, and ``vary_over`` degrades to the identity pre-vma).
-    The old guard keyed on jax-0.8-era API names (check_vma/pvary) and
-    skipped the whole manual-SPMD suite on any older jax even though
-    the stack runs there — now the capability is the EXECUTION, so the
-    suite runs wherever >= 2 devices exist and the shim holds."""
+    on this process's multi-device CPU mesh — the capability the
+    manual-SPMD suite needs is the EXECUTION, so the suite runs wherever
+    >= 2 devices exist."""
     import jax
 
     try:
@@ -41,20 +36,17 @@ def spmd_stack_ok() -> bool:
         from jax.sharding import PartitionSpec as P
 
         from nnstreamer_tpu.parallel.mesh import make_mesh
-        from nnstreamer_tpu.parallel.ring_attention import (
-            shard_map_compat,
-            vary_over,
-        )
 
         mesh = make_mesh({"sp": 2}, devices=jax.devices()[:2])
 
         def body(x):
-            acc = vary_over(jnp.zeros(x.shape, x.dtype), ("sp",))
+            acc = jax.lax.pcast(
+                jnp.zeros(x.shape, x.dtype), ("sp",), to="varying")
             rolled = jax.lax.ppermute(x, "sp", [(0, 1), (1, 0)])
             return acc + x + rolled
 
-        fn = shard_map_compat(
-            body, mesh, in_specs=(P("sp"),), out_specs=P("sp"))
+        fn = jax.shard_map(
+            body, mesh=mesh, in_specs=(P("sp"),), out_specs=P("sp"))
         out = fn(jnp.arange(4, dtype=jnp.float32))
         return float(out.sum()) == 12.0
     except Exception:
@@ -64,16 +56,10 @@ def spmd_stack_ok() -> bool:
 @functools.lru_cache(maxsize=None)
 def multihost_cpu_ok() -> bool:
     """PROBE-AND-RUN: True when this box can actually host a localhost
-    multi-process "multi-host" gang.  The old guard keyed on
-    ``jax_num_cpu_devices`` existing; ``parallel.multihost.initialize``
-    now falls back to ``XLA_FLAGS=--xla_force_host_platform_device_
-    count`` (workers are FRESH processes, so the flag lands before
-    their backend initializes) and selects the gloo CPU collectives, so
-    the jax version no longer gates these tests.  What still does is
-    the HARDWARE: a 2-4 process gang, each with 4 virtual devices,
-    starves gloo barriers into timeouts on a single-core box under
-    tier-1 load — the one genuine "needs a real multi-host runtime"
-    residue, probed as cores >= 2."""
+    multi-process "multi-host" gang.  What gates these tests is the
+    HARDWARE: a 2-4 process gang, each with 4 virtual devices, starves
+    gloo barriers into timeouts on a single-core box under tier-1 load
+    — probed as cores >= 2."""
     import jax
 
     try:

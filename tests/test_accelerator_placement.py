@@ -46,11 +46,23 @@ class TestPickDevice:
         devs = jax.devices("cpu")
         assert pick_device(["vendorsdk", "cpu.1"]) is devs[1]
 
-    def test_exhausted_list_falls_back_to_default(self):
-        assert pick_device(["tpu.5", "gpu"]) is jax.devices()[0]
+    def test_exhausted_list_raises_naming_wishes_and_devices(self):
+        """A wish list that names hardware and matches none must not be
+        served from some other device: it raises, with the list and
+        jax.devices() in the message (CPU-only host here)."""
+        for wishes in (["tpu"], ["tpu.5", "gpu"], ["vendorsdk"]):
+            with pytest.raises(RuntimeError) as ei:
+                pick_device(wishes)
+            assert str(wishes) in str(ei.value)
+            assert str(jax.devices()) in str(ei.value)
 
-    def test_auto(self):
+    def test_auto_and_default_take_the_default_device(self):
         assert pick_device(["auto"]) is jax.devices()[0]
+        assert pick_device(["default"]) is jax.devices()[0]
+        assert pick_device(["tpu", "cpu"]) is jax.devices("cpu")[0]
+        # ... which is whatever jax.default_device names, not devices()[0]
+        with jax.default_device(jax.devices()[5]):
+            assert pick_device(["auto"]) is jax.devices()[5]
 
 
 class TestPipelinePinning:
@@ -114,12 +126,12 @@ class TestPipelinePinning:
         pipe.stop()
         assert vals == [i + 2.0 for i in range(4)]
 
-    def test_unsatisfiable_ordinal_stays_in_family(self):
-        """cpu.99 with no later wish must stay on CPU (family fallback),
-        never invert an explicit cpu-only request onto the default
-        device (the TPU on real hardware)."""
-        dev = pick_device(["cpu.99"])
-        assert dev.platform == "cpu"
+    def test_unsatisfiable_ordinal_raises(self):
+        """cpu.99 with no later wish matches nothing: it must neither
+        invert an explicit cpu-only request onto the default device (the
+        TPU on real hardware) nor quietly pick another ordinal."""
+        with pytest.raises(RuntimeError, match="matches no device"):
+            pick_device(["cpu.99"])
 
     def test_cross_device_handoff_is_moved_not_ignored(self):
         """An upstream filter's device-resident output pinned elsewhere is

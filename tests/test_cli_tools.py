@@ -111,6 +111,25 @@ class TestConfchk:
         assert "jax-xla" in rep
         assert "decoder subplugins" in rep
 
+    def test_report_states_the_facts_chip_smoke_asserts(self):
+        """Same vocabulary, same in-process probe: platform, device kind
+        and count, compile-cache directory, mailbox implementation."""
+        import jax
+
+        from nnstreamer_tpu.native.runtime import mailbox_impl
+
+        lines = dict(
+            (k.strip(), v.strip()) for k, _, v in
+            (ln.partition(":") for ln in confchk.report().splitlines())
+            if v)
+        dev = jax.devices()[0]
+        assert lines["jax platform"] == dev.platform == "cpu"
+        assert lines["device kind"] == dev.device_kind
+        assert lines["device count"] == str(len(jax.devices()))
+        assert lines["compile cache dir"] == (
+            os.environ.get("JAX_COMPILATION_CACHE_DIR") or "(none: cpu)")
+        assert lines["mailbox"] == mailbox_impl()
+
 
 class TestCodegen:
     def test_python_scaffold_is_loadable(self, tmp_path):

@@ -2,135 +2,93 @@
 
 Reference analog: engine/result caching in backends (TensorRT serialized
 engine cache); here compiled XLA executables persist across processes.
+The directory is placed from outside: ``JAX_COMPILATION_CACHE_DIR`` when
+set (jax reads it; the program writes no directory), else the fixed
+``<checkout>/.jax_cache``.
 """
 
 import os
 import subprocess
 import sys
 
+import jax
+import pytest
+
+from nnstreamer_tpu.core import compile_cache
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_enable_creates_dir_and_sets_config(tmp_path, monkeypatch):
-    from nnstreamer_tpu.core import compile_cache
-
-    compile_cache.reset_for_tests()
-    target = str(tmp_path / "xla_cache")
-    monkeypatch.setenv("NNS_TPU_XLA_CACHE_DIR", target)
-    from nnstreamer_tpu.core import config as nns_config
-
-    nns_config.reset()
-    import jax
-
-    prior = jax.config.jax_compilation_cache_dir
-    try:
-        got = compile_cache.enable()
-        # cache lives in a per-host subtree so AOT entries compiled on a
-        # host with different CPU features can never be loaded here
-        fp = compile_cache.host_fingerprint()
-        assert got == os.path.join(target, fp)
-        assert os.path.isdir(got)
-        assert jax.config.jax_compilation_cache_dir == got
-        # idempotent: second call returns the same dir, no re-init
-        assert compile_cache.enable() == got
-    finally:
-        # restore the process-global flag: later tests must not write
-        # cache entries into this test's doomed tmp_path
-        jax.config.update("jax_compilation_cache_dir", prior)
-        compile_cache.reset_for_tests()
-        monkeypatch.delenv("NNS_TPU_XLA_CACHE_DIR")
-        nns_config.reset()
-
-
-def test_host_fingerprint_stable_and_filesystem_safe():
-    from nnstreamer_tpu.core import compile_cache
-
-    fp = compile_cache.host_fingerprint()
-    assert fp == compile_cache.host_fingerprint()  # deterministic
-    assert fp and "/" not in fp and not fp.startswith(".")
-
-
-def test_enable_warns_on_conflicting_explicit_dir(tmp_path, caplog):
-    from nnstreamer_tpu.core import compile_cache
-
-    compile_cache.reset_for_tests()
-    import jax
-
-    prior = jax.config.jax_compilation_cache_dir
-    try:
-        first = compile_cache.enable(str(tmp_path / "a"))
-        assert first
-        import logging
-
-        with caplog.at_level(logging.WARNING):
-            again = compile_cache.enable(str(tmp_path / "b"))
-        assert again == first  # sticky — but no longer silent
-        assert any("already enabled" in r.message for r in caplog.records)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prior)
-        compile_cache.reset_for_tests()
-
-
-def test_cpu_platform_auto_skips_but_stays_retryable(tmp_path, monkeypatch):
-    # no explicit dir + cpu platform -> no cache (XLA:CPU AOT entries log
-    # feature-mismatch noise on every warm load); a later accelerator
-    # open() in the same process must still be able to enable it
-    from nnstreamer_tpu.core import compile_cache
-    from nnstreamer_tpu.core import config as nns_config
-
-    monkeypatch.delenv("NNS_TPU_XLA_CACHE_DIR", raising=False)
-    # the auto default expands under HOME: point it at tmp_path so the
-    # test neither pollutes ~/.cache nor depends on HOME being writable
-    monkeypatch.setattr(
-        compile_cache, "_DEFAULT_DIR", str(tmp_path / "auto_cache")
-    )
-    nns_config.reset()
-    compile_cache.reset_for_tests()
-    import jax
-
+@pytest.fixture
+def restore_jax_cache_config():
+    """Later tests must not write cache entries where these tests point."""
     prior_dir = jax.config.jax_compilation_cache_dir
     prior_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        assert compile_cache.enable(platform="cpu") is None
-        got = compile_cache.enable(platform="tpu")  # retry succeeds
-        assert got and compile_cache.host_fingerprint() in got
-        assert got.startswith(str(tmp_path))
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prior_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prior_min
-        )
-        compile_cache.reset_for_tests()
-        nns_config.reset()
+    yield
+    jax.config.update("jax_compilation_cache_dir", prior_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", prior_min)
 
 
-def test_disable_via_empty_dir(monkeypatch):
-    from nnstreamer_tpu.core import compile_cache
+def test_variable_set_leaves_jax_config_dir_untouched(
+        tmp_path, monkeypatch, restore_jax_cache_config):
+    target = str(tmp_path / "from_outside")
+    monkeypatch.setenv(compile_cache.ENV_VAR, target)
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable() == target
+    # jax read the variable at import (or did not, in this process): either
+    # way the directory in jax.config is not ours to write
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not os.path.exists(compile_cache.CHECKOUT_CACHE) or (
+        target != compile_cache.CHECKOUT_CACHE)
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
 
-    compile_cache.reset_for_tests()
-    try:
-        assert compile_cache.enable("") is None
-    finally:
-        compile_cache.reset_for_tests()
+
+def test_variable_unset_uses_fixed_checkout_path(
+        monkeypatch, restore_jax_cache_config):
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    assert compile_cache.CHECKOUT_CACHE == os.path.join(ROOT, ".jax_cache")
+    # an accelerator process caches in the checkout ...
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(compile_cache.os, "makedirs", lambda *a, **k: None)
+    assert compile_cache.enable() == compile_cache.CHECKOUT_CACHE
+    assert (jax.config.jax_compilation_cache_dir
+            == compile_cache.CHECKOUT_CACHE)
+    assert compile_cache.enable() == compile_cache.CHECKOUT_CACHE  # idempotent
+
+
+def test_cpu_process_without_variable_caches_nothing(
+        monkeypatch, restore_jax_cache_config):
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable() is None
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_no_competing_knobs():
+    """One way to place the cache: no ini/env knob of our own, no per-host
+    subtree, nothing under $HOME."""
+    src = open(compile_cache.__file__).read()
+    for gone in ("NNS_TPU_XLA", "host_fingerprint", "expanduser", "~/"):
+        assert gone not in src, gone
 
 
 def test_cache_populates_across_processes(tmp_path):
     """A fresh process compiling through the jax-xla backend writes cache
-    entries; a second fresh process starts with a warm cache dir."""
+    entries where the variable points — and nowhere in the checkout; a
+    second fresh process starts with a warm cache dir."""
     cache = str(tmp_path / "xc")
     src = (
         "import os, sys, numpy as np;"
         f"sys.path.insert(0, {ROOT!r});"
-        "import jax; jax.config.update('jax_platforms', 'cpu');"
         "from nnstreamer_tpu.elements.filter import SingleShot;"
         "s = SingleShot(framework='jax-xla', model='zoo',"
         " custom='arch:mnist_cnn,dtype:float32');"
         "out = s.invoke_batch([np.zeros((4, 28, 28, 1), np.float32)]);"
         "s.close(); print('OK', out[0].shape)"
     )
-    env = dict(
-        os.environ, NNS_TPU_XLA_CACHE_DIR=cache, JAX_PLATFORMS="cpu"
-    )
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache,
+               JAX_PLATFORMS="cpu")
+    had_checkout_cache = os.path.exists(compile_cache.CHECKOUT_CACHE)
     r1 = subprocess.run(
         [sys.executable, "-c", src], env=env, capture_output=True,
         text=True, timeout=240,
@@ -138,6 +96,7 @@ def test_cache_populates_across_processes(tmp_path):
     assert r1.returncode == 0, r1.stderr[-2000:]
     entries = os.listdir(cache)
     assert entries, "first run wrote no cache entries"
+    assert os.path.exists(compile_cache.CHECKOUT_CACHE) == had_checkout_cache
     r2 = subprocess.run(
         [sys.executable, "-c", src], env=env, capture_output=True,
         text=True, timeout=240,

@@ -11,7 +11,7 @@ import pytest
 from nnstreamer_tpu.native import runtime
 
 pytestmark = pytest.mark.skipif(
-    not runtime.available(block=True), reason="native core toolchain unavailable"
+    not runtime.available(), reason="native core toolchain unavailable"
 )
 
 
@@ -218,7 +218,7 @@ class TestSampleReader:
 
         from nnstreamer_tpu.native.runtime import SampleReader, available
 
-        if not available(block=True):
+        if not available():
             pytest.skip("native core not buildable")
         rng = np.random.default_rng(0)
         data = rng.integers(0, 255, (10, 64), np.uint8)
@@ -239,7 +239,7 @@ class TestSampleReader:
     def test_open_missing_file(self):
         from nnstreamer_tpu.native.runtime import SampleReader, available
 
-        if not available(block=True):
+        if not available():
             pytest.skip("native core not buildable")
         with pytest.raises(OSError):
             SampleReader("/nonexistent/x.bin", 8)

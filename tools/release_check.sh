@@ -105,7 +105,7 @@ print('pipeline OK:', [f.tensors[0][0] for f in frames])
 say "native core build from installed package data"
 (cd /tmp && run "$VPY" -c "
 from nnstreamer_tpu.native import runtime
-assert runtime.available(block=True), 'native core failed to build'
+assert runtime.available(), 'native core failed to build'
 pool = runtime.BufferPool(block_size=1024, prealloc=2)
 ptr, mv = pool.acquire(); mv[:4] = b'test'; pool.release(ptr)
 assert pool.outstanding == 0
