@@ -1,0 +1,8 @@
+"""1 - the union of device-operation intervals over the traced window."""
+
+
+def read(metric, ctx):
+    r = ctx.reduced
+    if not r or r["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - r["busy_s"] / r["window_s"])
