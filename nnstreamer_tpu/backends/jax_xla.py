@@ -57,6 +57,7 @@ import numpy as np
 
 from ..core.config import EXPORTED_MODEL_EXTS
 from ..core.resilience import DeviceLostError, device_call
+from ..core.tracer import note
 from ..core.types import FORMAT_STATIC, StreamSpec, TensorSpec
 from .base import FilterBackend, register_backend
 
@@ -928,6 +929,7 @@ class JaxXla(FilterBackend):
         by the dp axis so the scatter is even)."""
         n = int(inputs[0].shape[0])
         bucket = self._bucket(n)
+        note(bucket=bucket)  # on the caller's invoke span, if one is open
         with self._reload_lock:
             import jax
 

@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .tracer import span
 from .types import StreamSpec, TensorSpec, FORMAT_STATIC
 
 # monotonic frame sequence for debugging/tracing
@@ -133,7 +134,10 @@ class BatchFrame(TensorFrame):
         """Materialize on host and fan back out into per-frame views.
         Per-frame wrappers come from the frame pool (the split fan-out is
         the hottest frame allocator at chip-rate streams)."""
-        mats = materialize(self.tensors)
+        # the host boundary of a device-resident batch: ready-wait and
+        # device-to-host, apart from the fan-out that follows
+        with span("nns.batch.materialize"):
+            mats = materialize(self.tensors)
         acquire = FRAME_POOL.acquire
         return [
             acquire([m[b] for m in mats], pts=p, duration=d, meta=dict(fm))

@@ -40,15 +40,17 @@ import numpy as np
 from .buffer import DEVICE_POOL, materialize as _materialize
 from .liveness import ThreadBeat
 from .telemetry import Log2Histogram
+from .tracer import name_os_thread, span
 
 
 class _WindowEntry:
-    __slots__ = ("out_b", "payload", "mats", "error", "done", "claimed",
-                 "t_park")
+    __slots__ = ("out_b", "payload", "seq", "mats", "error", "done",
+                 "claimed", "t_park")
 
-    def __init__(self, out_b, payload):
+    def __init__(self, out_b, payload, seq=None):
         self.out_b = out_b
         self.payload = payload
+        self.seq = seq  # the batch's sequence number, for its reap span
         self.mats: Optional[List[np.ndarray]] = None
         self.error: Optional[BaseException] = None
         self.done = False
@@ -103,10 +105,11 @@ class CompletionWindow:
     def __len__(self) -> int:
         return len(self._dq)
 
-    def park(self, out_b: Sequence[Any], payload: Any) -> None:
+    def park(self, out_b: Sequence[Any], payload: Any,
+             seq: Optional[int] = None) -> None:
         with self._cv:
             self._closed = False
-            self._dq.append(_WindowEntry(out_b, payload))
+            self._dq.append(_WindowEntry(out_b, payload, seq))
             if self._reaper is None or not self._reaper.is_alive():
                 self._reaper = threading.Thread(
                     target=self._reap_loop,
@@ -118,6 +121,7 @@ class CompletionWindow:
             self._cv.notify_all()
 
     def _reap_loop(self) -> None:
+        name_os_thread()
         while True:
             self.heartbeat.beat()
             with self._cv:
@@ -138,7 +142,9 @@ class CompletionWindow:
             # stale-beat-while-busy signature the census calls wedged
             self.heartbeat.beat()
             try:
-                mats = self._materialize(entry.out_b)
+                # ready-wait and device-to-host of one parked batch
+                with span("nns.feed.reap", seq=entry.seq):
+                    mats = self._materialize(entry.out_b)
                 err = None
             except (KeyboardInterrupt, SystemExit):
                 raise
@@ -291,7 +297,8 @@ class HostStagingLane:
         # pool keys its rings on it so this lane's buffers never recycle
         # into a lane staging for a different device/mesh
         self._placement = placement
-        self._q: "deque[Tuple[StagedBatch, List[List[np.ndarray]]]]" = deque()
+        self._q: "deque[Tuple[StagedBatch, List[List[np.ndarray]], Any]]" = (
+            deque())
         self._cv = threading.Condition()
         self._worker: Optional[threading.Thread] = None
         self._closed = False
@@ -301,13 +308,15 @@ class HostStagingLane:
         # (named-thread census in filter health)
         self.heartbeat = ThreadBeat(f"{name}-stage")
 
-    def submit(self, per_frame: List[List[np.ndarray]]) -> StagedBatch:
+    def submit(self, per_frame: List[List[np.ndarray]],
+               seq: Optional[int] = None) -> StagedBatch:
         """Stage one micro-batch: ``per_frame`` is a list of per-frame
-        tensor lists (all host arrays, uniform shapes/dtypes)."""
+        tensor lists (all host arrays, uniform shapes/dtypes); ``seq``
+        is the batch's sequence number, for its stage span."""
         job = StagedBatch()
         with self._cv:
             self._closed = False
-            self._q.append((job, per_frame))
+            self._q.append((job, per_frame, seq))
             if self._worker is None or not self._worker.is_alive():
                 self._worker = threading.Thread(
                     target=self._run, name=f"{self.name}-stage", daemon=True,
@@ -319,6 +328,7 @@ class HostStagingLane:
         return job
 
     def _run(self) -> None:
+        name_os_thread()
         while True:
             self.heartbeat.beat()
             with self._cv:
@@ -326,7 +336,7 @@ class HostStagingLane:
                     if self._closed:
                         return
                     self._cv.wait()
-                job, per_frame = self._q.popleft()
+                job, per_frame, seq = self._q.popleft()
             # beat after the (possibly long-idle) dequeue — see the
             # reaper's matching comment
             self.heartbeat.beat()
@@ -334,15 +344,19 @@ class HostStagingLane:
             try:
                 n = len(per_frame)
                 ntensors = len(per_frame[0])
-                for t in range(ntensors):
-                    rows = [pf[t] for pf in per_frame]
-                    a0 = np.asarray(rows[0])
-                    buf = self._pool.acquire(
-                        (n,) + a0.shape, a0.dtype,
-                        placement=self._placement)
-                    np.stack([np.asarray(r) for r in rows], out=buf)
-                    bufs.append(buf)
-                dev = self._to_device(bufs)
+                # one micro-batch's stack and host-to-device placement
+                with span("nns.feed.stage", seq=seq, frames=n) as sp:
+                    for t in range(ntensors):
+                        rows = [pf[t] for pf in per_frame]
+                        a0 = np.asarray(rows[0])
+                        buf = self._pool.acquire(
+                            (n,) + a0.shape, a0.dtype,
+                            placement=self._placement)
+                        np.stack([np.asarray(r) for r in rows], out=buf)
+                        bufs.append(buf)
+                    if sp.live:
+                        sp.set(bytes=sum(int(b.nbytes) for b in bufs))
+                    dev = self._to_device(bufs)
                 self.staged += 1
                 job._finish(list(dev), None)
             except (KeyboardInterrupt, SystemExit):
@@ -361,7 +375,7 @@ class HostStagingLane:
 
     def close(self) -> None:
         with self._cv:
-            abandoned = [job for job, _ in self._q]
+            abandoned = [job for job, _, _ in self._q]
             self._q.clear()
             self._closed = True
             self._cv.notify_all()

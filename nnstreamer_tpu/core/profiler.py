@@ -7,7 +7,9 @@ own profiler, surfaced through the same kind of element properties).
 One process-global trace session (the jax profiler is a singleton):
 elements call :func:`trace_start`/:func:`trace_stop` and refcounting
 keeps the session alive while any element wants it.  View traces with
-TensorBoard or xprof (``trace-dir`` holds the .xplane.pb files).
+TensorBoard or xprof (``trace-dir`` holds the .xplane.pb files).  While
+a session is live the program's layer spans (:func:`span`, defined in
+:mod:`~.tracer` and re-exported here) are written into the same trace.
 
 **Incident-time thread profiler** (`Documentation/observability.md`
 "Thread profiler"): a sampling wall-clock profiler over the NAMED
@@ -32,6 +34,7 @@ from collections import Counter
 from typing import Dict, Optional, Tuple
 
 from .log import get_logger
+from .tracer import record, span, spans_between  # noqa: F401 — the span API
 
 log = get_logger("profiler")
 
@@ -99,13 +102,6 @@ def trace_active() -> bool:
     ``nns.profiler.active`` gauge reads the per-element view via
     ``health_info``; this is the process-wide one)."""
     return _refs > 0
-
-
-def annotate(name: str):
-    """Context manager labeling a region in the trace (TraceAnnotation)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------

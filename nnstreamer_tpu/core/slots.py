@@ -41,6 +41,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import OrderedDict
+from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .continuity import (
@@ -49,6 +50,7 @@ from .continuity import (
 from .liveness import ThreadBeat
 from .log import get_logger
 from .resilience import DeviceLostError, DeviceOomError, device_call
+from .tracer import armed, name_os_thread, record, span
 
 log = get_logger("slots")
 
@@ -288,6 +290,9 @@ class GenStream:
         "deadline_ts", "token_budget_s", "state", "slot", "prefill_pos",
         "gen", "tok", "pending", "pending_n", "chunk_index", "tokens_out",
         "evict_reason", "submitted_ts", "last_token_ts", "joined_ts",
+        # seconds the pump spent in THIS stream's own reset and prefill
+        # steps (what lane wait leaves out)
+        "own_s",
         # stream continuity (core/continuity.py): what the chunked
         # prefill actually runs over (prompt, or prompt + generated
         # prefix on a RESUME), the checkpoint to restart decode from,
@@ -326,6 +331,7 @@ class GenStream:
         self.submitted_ts = now
         self.last_token_ts = now
         self.joined_ts: Optional[float] = None
+        self.own_s = 0.0
         self.prefill_src = prompt         # prompt (+ prefix[:-1] on resume)
         self.resume_tok = 0               # last prefix token (resume only)
         self.resume_gen = 0               # tokens already delivered (resume)
@@ -617,6 +623,19 @@ class SlotEngine:
         self.device_lost = 0        # lost-device events survived
         self.device_lost_evicted = 0  # live streams handed off on loss
         self.remeshes = 0           # models rebuilt on surviving devices
+        # where requests and the pump wait (seconds on ``clock``; the
+        # pump thread is the only writer): submit -> join summed over
+        # joins; join -> first token minus the stream's own reset and
+        # prefill steps, summed over first tokens; pump seconds inside a
+        # turn that are neither a device step or read-back nor the idle
+        # wait for a request
+        self.admit_wait_s = 0.0
+        self.first_tokens = 0
+        self.lane_wait_s = 0.0
+        self.pump_host_s = 0.0
+        # this turn's device and idle seconds (pump-thread-private)
+        self._dev_s = 0.0
+        self._idle_s = 0.0
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> None:
@@ -746,7 +765,8 @@ class SlotEngine:
         "joins", "completions", "evictions", "cancellations",
         "decode_steps", "prefill_chunks", "tokens_total", "resumes",
         "goaway_evicted", "oom_retries", "oom_sheds", "device_lost",
-        "device_lost_evicted", "remeshes",
+        "device_lost_evicted", "remeshes", "admit_wait_s", "first_tokens",
+        "lane_wait_s", "pump_host_s",
     )
 
     def adopt_ledger(self, other: "SlotEngine") -> None:
@@ -835,6 +855,10 @@ class SlotEngine:
                 "gen_device_lost": self.device_lost,
                 "gen_device_lost_evicted": self.device_lost_evicted,
                 "gen_remeshes": self.remeshes,
+                "gen_admit_wait_s": self.admit_wait_s,
+                "gen_first_tokens": self.first_tokens,
+                "gen_lane_wait_s": self.lane_wait_s,
+                "gen_pump_host_s": self.pump_host_s,
             }
         # armed cache only: with the cache off the snapshot is
         # byte-identical to the pre-prefix engine (zero behavior change)
@@ -1018,7 +1042,19 @@ class SlotEngine:
         deterministic ``device.oom`` / ``device.lost`` sites plus
         raw-runtime-error typing) — the pump's recovery ladder keys on
         types, never on XLA status strings."""
-        return device_call(fn, *args)
+        with self._on_device():
+            return device_call(fn, *args)
+
+    @contextmanager
+    def _on_device(self):
+        """Seconds the pump spends in a device step or a read-back (this
+        turn's ``_dev_s``): what ``gen_pump_host_s`` leaves out and a
+        stream's ``own_s`` is made of."""
+        t0 = self.clock()
+        try:
+            yield
+        finally:
+            self._dev_s += self.clock() - t0
 
     def _handle_oom(self) -> None:
         """HBM exhaustion mid-step: shed the LOWEST-priority occupant as
@@ -1170,6 +1206,11 @@ class SlotEngine:
             s.last_token_ts = now
             self._occupants[slot] = s
             self.joins += 1
+            self.admit_wait_s += now - s.submitted_ts
+            if armed():
+                t1 = time.perf_counter()
+                record("nns.gen.admit_wait", t1 - (now - s.submitted_ts),
+                       t1, request=s.sid)
             joined.append(s)
         taken = set(winners)
         self._waiting = [
@@ -1178,6 +1219,7 @@ class SlotEngine:
         return joined
 
     def _pump(self) -> None:
+        name_os_thread()
         try:
             self._pump_loop()
         except BaseException as e:  # noqa: BLE001 — thread boundary
@@ -1188,135 +1230,157 @@ class SlotEngine:
                 log.exception("%s: slot pump failed", self.name)
 
     def _pump_loop(self) -> None:
-        np = self._np
-
         while not self._stop.is_set():
             self.heartbeat.beat()
-            with self._work:
-                self._reap_cancelled()
-                if self._goaway:
-                    self._sweep_goaway()
-                self._sweep_deadlines(self.clock())
-                joined = self._join_waiting(self.clock())
-                have_prefill = any(
-                    s is not None and s.state == "prefill"
-                    for s in self._occupants)
-                have_decode = any(
-                    s is not None and s.state == "decoding"
-                    for s in self._occupants)
-                if not (joined or have_prefill or have_decode):
-                    self._work.wait(0.05)
-                    continue
+            t0 = self.clock()
+            self._dev_s = self._idle_s = 0.0
+            # one span per turn, parent of every phase below: while a
+            # profiler session is live the pump thread always has a
+            # span open, so a device gap is named by the phase it fell in
+            with span("nns.slots.turn"):
+                self._turn()
+            self.pump_host_s += max(
+                0.0, self.clock() - t0 - self._dev_s - self._idle_s)
 
-            # ---- prefill phase: while decoding, up to prefill_priority
-            # chunks interleave per scan (a long prompt never stalls
-            # live streams for more than that); with the decode batch
-            # EMPTY there is nothing to protect — run every pending
-            # joiner's next chunk so the batch fills immediately
-            prefilling = [
+    def _turn(self) -> None:
+        """One iteration of the pump: admit, at most the lane's share of
+        prefill chunks, one decode scan, emission."""
+        np = self._np
+
+        with span("nns.slots.admit"), self._work:
+            self._reap_cancelled()
+            if self._goaway:
+                self._sweep_goaway()
+            self._sweep_deadlines(self.clock())
+            joined = self._join_waiting(self.clock())
+            have_prefill = any(
+                s is not None and s.state == "prefill"
+                for s in self._occupants)
+            have_decode = any(
+                s is not None and s.state == "decoding"
+                for s in self._occupants)
+            if not (joined or have_prefill or have_decode):
+                # the one sleep of the thread that feeds the device:
+                # named as a wait, so an idle chip reads "no request"
+                t0 = self.clock()
+                with span("nns.slots.wait_request"):
+                    self._work.wait(0.05)
+                self._idle_s += self.clock() - t0
+                return
+
+        # ---- prefill phase: while decoding, up to prefill_priority
+        # chunks interleave per scan (a long prompt never stalls
+        # live streams for more than that); with the decode batch
+        # EMPTY there is nothing to protect — run every pending
+        # joiner's next chunk so the batch fills immediately
+        prefilling = [
+            s for s in self._occupants
+            if s is not None and s.state == "prefill"
+            and not s.finished
+        ]
+        budget = (self.prefill_priority if have_decode
+                  else max(1, len(prefilling)))
+        try:
+            for s in prefilling:
+                if budget <= 0:
+                    break
+                budget -= 1
+                self._prefill_one(s)
+        except DeviceOomError:
+            # prefill state is re-entrant (prefill_pos advanced only
+            # on success): shed a slot and re-run next iteration
+            self._handle_oom()
+            self._recover_donated_cache()
+            return
+        except DeviceLostError as e:
+            self._handle_device_lost(e)
+            return
+
+        # ---- decode phase: k tokens for every active slot in ONE
+        # lax.scan dispatch (k = min(chunk, min remaining), so every
+        # stream completes exactly at a scan boundary and joins/
+        # leaves happen at token boundaries)
+        with self._lock:
+            decoding = [
                 s for s in self._occupants
-                if s is not None and s.state == "prefill"
+                if s is not None and s.state == "decoding"
                 and not s.finished
             ]
-            budget = (self.prefill_priority if have_decode
-                      else max(1, len(prefilling)))
+        if not decoding:
+            return
+        k = min(
+            self.chunk,
+            min(s.max_new - s.gen for s in decoding),
+        )
+        k = max(1, k)
+        active = np.zeros((self.slots,), np.int32)
+        for s in decoding:
+            active[s.slot] = 1
+        with span("nns.slots.decode", k=k, active=len(decoding)):
             try:
-                for s in prefilling:
-                    if budget <= 0:
-                        break
-                    budget -= 1
-                    self._prefill_one(s)
-            except DeviceOomError:
-                # prefill state is re-entrant (prefill_pos advanced only
-                # on success): shed a slot and re-run next iteration
-                self._handle_oom()
-                self._recover_donated_cache()
-                continue
-            except DeviceLostError as e:
-                self._handle_device_lost(e)
-                continue
-
-            # ---- decode phase: k tokens for every active slot in ONE
-            # lax.scan dispatch (k = min(chunk, min remaining), so every
-            # stream completes exactly at a scan boundary and joins/
-            # leaves happen at token boundaries)
-            with self._lock:
-                decoding = [
-                    s for s in self._occupants
-                    if s is not None and s.state == "decoding"
-                    and not s.finished
-                ]
-            if not decoding:
-                continue
-            k = min(
-                self.chunk,
-                min(s.max_new - s.gen for s in decoding),
-            )
-            k = max(1, k)
-            active = np.zeros((self.slots,), np.int32)
-            for s in decoding:
-                active[s.slot] = 1
-            try:
-                self._cache, tok, gen, toks = self._device_step(
-                    self._decode_fn(k),
-                    self.params, self._cache, self._tok_vec,
-                    self._gen_vec, active,
-                )
+                with span("nns.slots.decode.dispatch"):
+                    self._cache, tok, gen, toks = self._device_step(
+                        self._decode_fn(k),
+                        self.params, self._cache, self._tok_vec,
+                        self._gen_vec, active,
+                    )
             except DeviceOomError:
                 # the step raised before any state assignment: shed the
                 # lowest-priority slot (its tokens survive as a
                 # resumable chunk) and retry on the smaller batch
                 self._handle_oom()
                 self._recover_donated_cache()
-                continue
+                return
             except DeviceLostError as e:
                 self._handle_device_lost(e)
-                continue
-            # materialize BEFORE emission: a yielded token must EXIST,
-            # not merely be dispatched (generator element contract)
-            toks_host = np.asarray(toks)  # (slots, k)
-            # np.array (not asarray): a jax result view is read-only and
-            # prefill writes per-slot entries in place
-            self._tok_vec = np.array(tok, dtype=np.int32)
-            self._gen_vec = np.array(gen, dtype=np.int32)
+                return
+            with span("nns.slots.decode.sync"), self._on_device():
+                # materialize BEFORE emission: a yielded token must
+                # EXIST, not merely be dispatched (generator element
+                # contract)
+                toks_host = np.asarray(toks)  # (slots, k)
+                # np.array (not asarray): a jax result view is read-only
+                # and prefill writes per-slot entries in place
+                self._tok_vec = np.array(tok, dtype=np.int32)
+                self._gen_vec = np.array(gen, dtype=np.int32)
             now = self.clock()
-            with self._lock:
-                self.decode_steps += 1
-                self.tokens_total += k * len(decoding)
-                a = 0.2  # EWMA horizon ~ last 5 scans
-                self.tokens_per_step = (
-                    len(decoding) if self.decode_steps == 1
-                    else (1 - a) * self.tokens_per_step + a * len(decoding)
+        with span("nns.slots.emit"), self._lock:
+            self.decode_steps += 1
+            self.tokens_total += k * len(decoding)
+            a = 0.2  # EWMA horizon ~ last 5 scans
+            self.tokens_per_step = (
+                len(decoding) if self.decode_steps == 1
+                else (1 - a) * self.tokens_per_step + a * len(decoding)
+            )
+            for s in decoding:
+                if s.finished:  # cancelled mid-scan: tokens discarded
+                    continue
+                row = toks_host[s.slot:s.slot + 1, :]  # (1, k)
+                s.tok = int(row[0, -1])
+                s.gen += k
+                # per-token pace QoS: the scan's OWN per-token rate
+                # against the stream's budget — a stream decoding
+                # slower than its pace is evicted (tokens from this
+                # scan are preserved in the typed-expiry flush)
+                pace_blown = (
+                    s.token_budget_s > 0.0
+                    and (now - s.last_token_ts) / k > s.token_budget_s
                 )
-                for s in decoding:
-                    if s.finished:  # cancelled mid-scan: tokens discarded
-                        continue
-                    row = toks_host[s.slot:s.slot + 1, :]  # (1, k)
-                    s.tok = int(row[0, -1])
-                    s.gen += k
-                    # per-token pace QoS: the scan's OWN per-token rate
-                    # against the stream's budget — a stream decoding
-                    # slower than its pace is evicted (tokens from this
-                    # scan are preserved in the typed-expiry flush)
-                    pace_blown = (
-                        s.token_budget_s > 0.0
-                        and (now - s.last_token_ts) / k > s.token_budget_s
-                    )
-                    # SLO per-token inter-arrival: the scan's k tokens
-                    # as k observations of the same pace — one bucket
-                    # increment, reusing the pace sweep's clock reads
-                    if self.slo is not None:
-                        self.slo.note_tokens(
-                            s.tenant, max(0.0, now - s.last_token_ts), k)
-                    s.last_token_ts = now
-                    s.pending.append(row.astype(np.int32))
-                    s.pending_n += k
-                    if s.gen >= s.max_new:
-                        self._finish(s, "done")
-                    elif pace_blown:
-                        self._evict(s, "token_budget")
-                    else:
-                        self._emit_boundary(s)
+                # SLO per-token inter-arrival: the scan's k tokens
+                # as k observations of the same pace — one bucket
+                # increment, reusing the pace sweep's clock reads
+                if self.slo is not None:
+                    self.slo.note_tokens(
+                        s.tenant, max(0.0, now - s.last_token_ts), k)
+                s.last_token_ts = now
+                s.pending.append(row.astype(np.int32))
+                s.pending_n += k
+                if s.gen >= s.max_new:
+                    self._finish(s, "done")
+                elif pace_blown:
+                    self._evict(s, "token_budget")
+                else:
+                    self._emit_boundary(s)
 
     # -- shared-prefix cache (attach on join, publish at boundaries) --------
     def _attach_prefix(self, s: GenStream, slot: int) -> None:
@@ -1385,68 +1449,92 @@ class SlotEngine:
         np = self._np
 
         slot = np.int32(s.slot)
+        dev0 = self._dev_s
+        try:
+            self._prefill_step(s, slot, dev0)
+        finally:
+            s.own_s += self._dev_s - dev0
+
+    def _prefill_step(self, s: GenStream, slot, dev0: float) -> None:
+        np = self._np
+
         if s.prefill_pos == 0:
-            self._cache = self.model.reset_slot(self._cache, slot)
-            if self.prefix is not None:
-                self._attach_prefix(s, int(s.slot))
-                if s.prefill_pos >= s.prefill_src.shape[1]:
-                    # defensive: attach is capped at tp-1, so the final
-                    # prompt token (whose logits pick token 1) always
-                    # prefills — this branch is unreachable by design
-                    raise AssertionError(
-                        "prefix attach covered the whole prompt")
+            with span("nns.slots.reset", request=s.sid), self._on_device():
+                self._cache = self.model.reset_slot(self._cache, slot)
+                if self.prefix is not None:
+                    self._attach_prefix(s, int(s.slot))
+            if s.prefill_pos >= s.prefill_src.shape[1]:
+                # defensive: attach is capped at tp-1, so the final
+                # prompt token (whose logits pick token 1) always
+                # prefills — this branch is unreachable by design
+                raise AssertionError(
+                    "prefix attach covered the whole prompt")
         tp = s.prefill_src.shape[1]
         n = min(self.prefill_chunk, tp - s.prefill_pos)
-        toks = s.prefill_src[:, s.prefill_pos:s.prefill_pos + n].astype(
-            np.int32)
-        self._cache, logits = self._device_step(
-            self._prefill_fn(n), self.params, self._cache, toks, slot)
-        s.prefill_pos += n
-        if self.prefix is not None:
-            self._publish_prefix(s, int(s.slot))
-        with self._lock:
-            self.prefill_chunks += 1
-        if s.prefill_pos < tp:
-            return
-        if s.resume_gen:
-            # checkpointed restart: no pick, no token-1 emission — the
-            # client already holds tokens 1..resume_gen
-            self._tok_vec[s.slot] = s.resume_tok
-            self._gen_vec[s.slot] = s.resume_gen
-            now = self.clock()
+        with span("nns.slots.prefill", request=s.sid, pos=s.prefill_pos,
+                  n=n):
+            toks = s.prefill_src[:, s.prefill_pos:s.prefill_pos + n].astype(
+                np.int32)
+            self._cache, logits = self._device_step(
+                self._prefill_fn(n), self.params, self._cache, toks, slot)
+            s.prefill_pos += n
+            if self.prefix is not None:
+                self._publish_prefix(s, int(s.slot))
             with self._lock:
-                if s.finished:  # cancelled/handed off during prefill
+                self.prefill_chunks += 1
+            if s.prefill_pos < tp:
+                return
+            if s.resume_gen:
+                # checkpointed restart: no pick, no token-1 emission — the
+                # client already holds tokens 1..resume_gen
+                self._tok_vec[s.slot] = s.resume_tok
+                self._gen_vec[s.slot] = s.resume_gen
+                now = self.clock()
+                with self._lock:
+                    if s.finished:  # cancelled/handed off during prefill
+                        return
+                    s.tok = s.resume_tok
+                    s.gen = s.resume_gen
+                    s.last_token_ts = now
+                    if s.resume_gen >= s.max_new:
+                        self._finish(s, "done")  # defensive: nothing left
+                    else:
+                        s.state = "decoding"
+                return
+            # prompt fully prefilled: pick token 1 (raw gen_seed key — the
+            # exact pick the unslotted prefill applies)
+            with self._on_device():
+                t1 = self.model.pick_first(logits)
+                t1_host = int(np.asarray(t1)[0])
+            self._tok_vec[s.slot] = t1_host
+            self._gen_vec[s.slot] = 1
+            now = self.clock()
+            # SLO TTFT: the promised one-stamp-per-first-token — resumed
+            # streams skip it above (their first token predates this server)
+            if self.slo is not None:
+                self.slo.note_ttft(s.tenant, max(0.0, now - s.submitted_ts))
+            with self._lock:
+                if s.finished:  # cancelled during prefill
                     return
-                s.tok = s.resume_tok
-                s.gen = s.resume_gen
+                s.tok = t1_host
+                s.gen = 1
+                self.tokens_total += 1  # token 1 comes from the prefill pick
+                # lane wait: join to first token, less the seconds the pump
+                # spent in this stream's own reset and prefill steps — the
+                # rest is the stream waiting for its turn in the lane
+                own = s.own_s + (self._dev_s - dev0)
+                lane = max(0.0, now - s.joined_ts - own)
+                self.first_tokens += 1
+                self.lane_wait_s += lane
+                if armed():
+                    t_end = time.perf_counter()
+                    record("nns.gen.lane_wait", t_end - lane - own, t_end,
+                           request=s.sid, own_s=own)
                 s.last_token_ts = now
-                if s.resume_gen >= s.max_new:
-                    self._finish(s, "done")  # defensive: nothing left
+                s.pending.append(np.array([[t1_host]], np.int32))
+                s.pending_n = 1
+                if s.max_new <= 1:
+                    self._finish(s, "done")
                 else:
                     s.state = "decoding"
-            return
-        # prompt fully prefilled: pick token 1 (raw gen_seed key — the
-        # exact pick the unslotted prefill applies)
-        t1 = self.model.pick_first(logits)
-        t1_host = int(np.asarray(t1)[0])
-        self._tok_vec[s.slot] = t1_host
-        self._gen_vec[s.slot] = 1
-        now = self.clock()
-        # SLO TTFT: the promised one-stamp-per-first-token — resumed
-        # streams skip it above (their first token predates this server)
-        if self.slo is not None:
-            self.slo.note_ttft(s.tenant, max(0.0, now - s.submitted_ts))
-        with self._lock:
-            if s.finished:  # cancelled during prefill
-                return
-            s.tok = t1_host
-            s.gen = 1
-            self.tokens_total += 1  # token 1 comes from the prefill pick
-            s.last_token_ts = now
-            s.pending.append(np.array([[t1_host]], np.int32))
-            s.pending_n = 1
-            if s.max_new <= 1:
-                self._finish(s, "done")
-            else:
-                s.state = "decoding"
-                self._emit_boundary(s)
+                    self._emit_boundary(s)

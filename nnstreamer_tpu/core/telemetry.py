@@ -243,6 +243,10 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "nns.gen.device_lost": ("counter", "lost-device events survived by the slot engine"),
     "nns.gen.device_lost_evicted": ("counter", "live streams handed off on device loss"),
     "nns.gen.remeshes": ("counter", "slot models rebuilt on surviving devices"),
+    "nns.gen.admit_wait_seconds": ("counter", "seconds requests waited from submit to a slot, summed over joins"),
+    "nns.gen.first_tokens": ("counter", "streams that reached their first token (resumed streams excluded)"),
+    "nns.gen.lane_wait_seconds": ("counter", "seconds from join to first token less the stream's own reset and prefill steps, summed"),
+    "nns.gen.pump_host_seconds": ("counter", "pump seconds inside a turn outside device steps, read-backs and the idle wait"),
 
     # -- memory-pressure watermarks (core/liveness.py monitor) -------------
     "nns.mem.bytes_in_use": ("gauge", "device HBM bytes in use (most-loaded chip)"),
@@ -468,6 +472,11 @@ HEALTH_KEY_METRICS: Dict[str, str] = {
     "gen_device_lost": "nns.gen.device_lost",
     "gen_device_lost_evicted": "nns.gen.device_lost_evicted",
     "gen_remeshes": "nns.gen.remeshes",
+    # where requests and the pump wait (SlotEngine.snapshot, always on)
+    "gen_admit_wait_s": "nns.gen.admit_wait_seconds",
+    "gen_first_tokens": "nns.gen.first_tokens",
+    "gen_lane_wait_s": "nns.gen.lane_wait_seconds",
+    "gen_pump_host_s": "nns.gen.pump_host_seconds",
     # memory-pressure watermarks (serversrc health row)
     "mem_bytes_in_use": "nns.mem.bytes_in_use",
     "mem_bytes_limit": "nns.mem.bytes_limit",

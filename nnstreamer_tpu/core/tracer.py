@@ -19,8 +19,21 @@ Measurements per element:
   * **bitrate** — payload bytes/sec through the element.
 
 ``report()`` returns plain dicts; ``summary_lines()`` renders the
-gst-shark-style table.  For device-level detail this composes with the
-XLA profiler (``core/profiler.py`` — tensor_filter ``trace`` prop).
+gst-shark-style table.
+
+**Spans on the profiler's clock** (second half of this module):
+:func:`span` is the program's ONE span entry point.  It is armed exactly
+while a jax profiler session is live — whoever started it (the filter's
+``trace=1``, an operator's TensorBoard capture, the benchmark's trace
+window) — and then does two things: it opens a
+``jax.profiler.TraceAnnotation`` so the span lands in the profiler's own
+trace beside the device's events, and on exit it appends one
+:class:`SpanRecord` to a process-global bounded ring that
+:func:`spans_between` reads back.  Off, it is one static call and a
+shared no-op.  What gets a span: WORK, on the thread that does it — a
+thread that merely sleeps on a queue opens none (a trace reducer would
+blame device gaps on it); the waits of the thread that feeds the device
+are the exception and are named as waits.
 """
 
 from __future__ import annotations
@@ -28,13 +41,17 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
 from .telemetry import TRACE_ID_META, Log2Histogram, new_trace_id
 
 META_SRC_TS = "_nns_trace_src_ts"  # wall stamp set when a frame leaves a source
+#: a device-resident micro-batch's sequence number, stamped by the filter
+#: while a profiler session is live so that the element that brings the
+#: batch to the host names its span by it
+BATCH_SEQ_META = "_nns_batch_seq"
 
 
 class _ElementStats:
@@ -89,10 +106,10 @@ class PipelineTracer:
         self.t_started = time.perf_counter()
         # cpuusage: process CPU time vs wall time over the traced window
         self._cpu_started = time.process_time()
-        # detail mode additionally keeps per-call spans (bounded ring) so
-        # export_chrome_trace renders a real timeline, not just aggregates
+        # detail mode additionally records every element call into the
+        # process-wide span ring (:func:`record`), so export_chrome_trace
+        # renders a real timeline, not just aggregates
         self._detail = detail
-        self._spans: deque = deque(maxlen=200_000)
         # optional flight recorder (core/telemetry.py)
         self.recorder = recorder
 
@@ -141,7 +158,10 @@ class PipelineTracer:
         frame=None,
     ) -> None:
         if self._detail:
-            self._spans.append((name, t_in, t_out, nframes))
+            record(name, t_in, t_out,
+                   request=(frame.meta.get(TRACE_ID_META)
+                            if frame is not None else None),
+                   frames=nframes)
         if self.recorder is not None:
             self.recorder.end(name, frame, t_in, t_out, nframes)
         st = self._get(name)
@@ -269,18 +289,42 @@ class PipelineTracer:
 
 
     def export_chrome_trace(self, path: str) -> None:
-        """Write a Chrome-trace JSON (``chrome://tracing`` / Perfetto) so
-        pipeline timing sits next to ``jax.profiler`` device traces — the
-        GstShark→tracing-UI hop the reference gets from HawkTracer
-        (SURVEY §5.1).  With ``detail=True`` every element call becomes a
-        real timeline span (one lane per element); otherwise one summary
-        span per element plus fps counters."""
+        """Write a Chrome-trace JSON (``chrome://tracing`` / Perfetto) of
+        the host side: the GstShark→tracing-UI hop the reference gets
+        from HawkTracer (SURVEY §5.1).  With ``detail=True`` every
+        element call since this tracer began is a real timeline span
+        (one lane per element), read from the process-wide span ring —
+        along with any ``nns.*`` layer span a live profiler session put
+        there (one lane per thread); otherwise one summary span per
+        element plus fps counters.  To see the host beside the DEVICE,
+        use the profiler's own trace (``trace=1`` on the filter): the
+        layer spans are written into it on the device's clock."""
         import json
 
         t0 = self.t_started
         with self._lock:
             names = list(self._stats)
         lanes = {name: i for i, name in enumerate(names)}
+        recs = (spans_between(t0, time.perf_counter())
+                if self._detail else [])
+        spans = []
+        for r in recs:
+            if r.t0 < t0:
+                continue
+            # an element's own calls sit in its lane; layer spans in
+            # their thread's
+            lane = (r.name if r.name in lanes
+                    else f"thread {r.thread}" if r.thread else "waits")
+            tid = lanes.setdefault(lane, len(lanes))
+            args = dict(r.attrs)
+            if r.request is not None:
+                args["request"] = r.request
+            spans.append({
+                "name": r.name, "ph": "X", "pid": 0, "tid": tid,
+                "ts": (r.t0 - t0) * 1e6,
+                "dur": max(0.1, (r.t1 - r.t0) * 1e6),
+                "args": args,
+            })
         events = [
             {
                 "name": "process_name", "ph": "M", "pid": 0,
@@ -292,18 +336,9 @@ class PipelineTracer:
                 "args": {"name": name},
             }
             for name, tid in lanes.items()
-        ]
-        if self._detail and self._spans:
-            for name, t_in, t_out, nframes in list(self._spans):
-                events.append({
-                    "name": name, "ph": "X", "pid": 0,
-                    "tid": lanes.get(name, 0),
-                    "ts": (t_in - t0) * 1e6,
-                    "dur": max(0.1, (t_out - t_in) * 1e6),
-                    "args": {"frames": nframes},
-                })
+        ] + spans
         for name, r in self.report().items():
-            if not (self._detail and self._spans):
+            if not spans:
                 events.append({
                     "name": name, "ph": "X", "pid": 0,
                     "tid": lanes.get(name, 0), "ts": 0,
@@ -324,3 +359,203 @@ def frame_nbytes(item) -> int:
         return sum(int(getattr(t, "nbytes", 0)) for t in item.tensors)
     except Exception:
         return 0
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock: one entry point, one ring
+# ---------------------------------------------------------------------------
+class SpanRecord(NamedTuple):
+    """One closed span.  Times are ``time.perf_counter()`` seconds;
+    ``thread`` is the name of the thread the span ran on, and ``parent``
+    the name of the span open on that thread when this one opened (both
+    None for an interval that crossed threads, :func:`record`);
+    ``request`` is what the spans of one request share (a frame's
+    ``TRACE_ID_META``, a generation stream's ``sid``)."""
+
+    name: str
+    t0: float
+    t1: float
+    thread: Optional[str]
+    parent: Optional[str]
+    request: Any
+    attrs: Dict[str, Any]
+
+
+#: bound on the process-global ring (oldest records fall off)
+SPAN_RING = 200_000
+_ring: "deque[SpanRecord]" = deque(maxlen=SPAN_RING)
+_tls = threading.local()
+_bind_lock = threading.Lock()
+# TraceAnnotation and its static is_enabled, bound at the first span()
+# (this module imports without jax)
+_annotation = None
+_enabled: Optional[Callable[[], bool]] = None
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _bind() -> Callable[[], bool]:
+    global _annotation, _enabled
+    with _bind_lock:
+        if _enabled is None:
+            try:
+                import jax.monitoring
+                import jax.profiler
+
+                _annotation = jax.profiler.TraceAnnotation
+                _annotation.is_enabled()
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile)
+                _enabled = _annotation.is_enabled
+            except Exception:  # noqa: BLE001 — no jax, or one without it
+                _enabled = bool  # bool() is False: never armed
+    return _enabled
+
+
+def armed() -> bool:
+    """True exactly while a jax profiler session is live in this
+    process, whoever started it.  One static call."""
+    return (_enabled or _bind())()
+
+
+class _NoSpan:
+    """What :func:`span` returns while no profiler session is live."""
+
+    __slots__ = ()
+    live = False
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+def _stack() -> list:
+    try:
+        return _tls.stack
+    except AttributeError:
+        _tls.stack = []
+        name_os_thread()
+        return _tls.stack
+
+
+def name_os_thread() -> None:
+    """Give the calling thread an OS-level name of its own.  The profiler
+    names a host thread's line in the trace by the thread's OS name as
+    it stands at the thread's first event of the session, and Python
+    (before 3.14) leaves every thread with the process's: a trace then
+    shows a dozen lines all called "python3", and a reader that keys the
+    lines by name keeps one of them.  The threads that feed the device
+    (segment workers, the staging lane, the reaper, the slot pump) call
+    this as they start; any other thread gets it at its first span.  The
+    name is the tail of the Python name (where the role is: ``-slots``,
+    ``-stage``, ``-reaper``) and the native id, within the kernel's 15
+    bytes.  The main thread keeps its name: it is the process's."""
+    t = threading.current_thread()
+    if t is threading.main_thread() or getattr(_tls, "named", False):
+        return
+    _tls.named = True
+    name = f"{t.name[-9:]}-{threading.get_native_id() % 100000}"
+    try:
+        import ctypes
+
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):  # no prctl here (not Linux)
+        pass
+
+
+class _Span:
+    __slots__ = ("name", "request", "attrs", "parent", "t0", "_ann")
+    live = True
+
+    def __init__(self, name, request, attrs):
+        self.name, self.request, self.attrs = name, request, attrs
+
+    def set(self, request=None, **attrs) -> None:
+        """Attributes (and the request id) learned inside the span."""
+        if request is not None:
+            self.request = request
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        stack = _stack()
+        top = stack[-1] if stack else None
+        self.parent = top.name if top is not None else None
+        if self.request is None and top is not None:
+            self.request = top.request
+        stack.append(self)
+        # the name alone: attributes stay in the ring, so the name the
+        # trace (and a ledger's breakdown) shows is a stable string
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        stack = _stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        _ring.append(SpanRecord(
+            self.name, self.t0, t1, threading.current_thread().name,
+            self.parent, self.request, self.attrs))
+        return False
+
+
+def span(name: str, request=None, **attrs):
+    """Context manager around one piece of WORK at a layer boundary.
+
+    No profiler session live: one static call, the shared
+    :data:`NO_SPAN`, nothing allocated and no clock read (per-frame
+    sites pass no keywords and use ``sp.set(...)`` under ``sp.live``).
+    Live: a ``TraceAnnotation(name)`` on the calling thread, and at exit
+    one :class:`SpanRecord` in the ring.  A span without a ``request``
+    inherits its parent's."""
+    if not (_enabled or _bind())():
+        return NO_SPAN
+    return _Span(name, request, attrs)
+
+
+def note(**attrs) -> None:
+    """Attributes for the span the calling thread has open, from a callee
+    that knows what its caller's span should say (the backend's compile
+    bucket on the filter's invoke span).  No span open: nothing."""
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        stack[-1].set(**attrs)
+
+
+def record(name: str, t0: float, t1: float, request=None, **attrs) -> None:
+    """The ring record alone, for an interval that belongs to no thread's
+    call stack and is only known at its end: a request's wait across
+    threads, a thread asleep on back-pressure.  No annotation, no thread
+    and no parent, and no test of :func:`armed` — per-request callers
+    make it themselves."""
+    _ring.append(SpanRecord(name, t0, t1, None, None, request, attrs))
+
+
+def _on_compile(event: str, duration: float, **_) -> None:
+    """Compiles by cause: jax calls this on the compiling thread, so the
+    record's parent is the span that thread has open."""
+    if event == _COMPILE_EVENT and _enabled():
+        now = time.perf_counter()
+        stack = _stack()
+        top = stack[-1] if stack else None
+        _ring.append(SpanRecord(
+            "nns.compile", now - float(duration), now,
+            threading.current_thread().name,
+            top.name if top is not None else None,
+            top.request if top is not None else None, {}))
+
+
+def spans_between(t0: float, t1: float) -> List[SpanRecord]:
+    """A copy of the ring's records that END in ``[t0, t1]``.  The ring
+    is process-global and outlives any pipeline."""
+    return [r for r in PipelineTracer._snap(_ring) if t0 <= r.t1 <= t1]

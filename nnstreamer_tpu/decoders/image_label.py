@@ -15,6 +15,8 @@ from typing import List, Optional
 import numpy as np
 
 from ..core.buffer import TensorFrame
+from ..core.telemetry import TRACE_ID_META
+from ..core.tracer import span
 from ..core.types import FORMAT_STATIC, StreamSpec, TensorSpec
 from .util import load_labels
 
@@ -42,12 +44,16 @@ class ImageLabeling:
         return self._emit(frame, idx, float(scores[idx]))
 
     def _emit(self, frame: TensorFrame, idx: int, score: float) -> TensorFrame:
-        out = frame.with_tensors([np.asarray([idx], np.int32)])
-        out.meta["label_index"] = idx
-        out.meta["label_score"] = score
-        if self.labels and idx < len(self.labels):
-            out.meta["label"] = self.labels[idx]
-        return out
+        # the decoder's host part: index to label
+        with span("nns.decoder.labels") as sp:
+            if sp.live:
+                sp.set(request=frame.meta.get(TRACE_ID_META))
+            out = frame.with_tensors([np.asarray([idx], np.int32)])
+            out.meta["label_index"] = idx
+            out.meta["label_score"] = score
+            if self.labels and idx < len(self.labels):
+                out.meta["label"] = self.labels[idx]
+            return out
 
     # -- device-fused half (pipeline fusion pass) ---------------------------
     def device_fn(self, outs, single_device=True):
@@ -87,20 +93,21 @@ class ImageLabeling:
         at chip rates the per-frame fan-out dominates the decode)."""
         from ..core.buffer import BatchFrame
 
-        packed = np.asarray(frame.tensors[0], np.float64).reshape(-1, 2)
-        idx = packed[:, 0].astype(np.int32)
-        labels = self.labels
-        infos = []
-        for j, (p, d, m) in enumerate(frame.frames_info):
-            m2 = dict(m)
-            i = int(idx[j])
-            m2["label_index"] = i
-            m2["label_score"] = float(packed[j, 1])
-            if labels and i < len(labels):
-                m2["label"] = labels[i]
-            infos.append((p, d, m2))
-        return BatchFrame(
-            tensors=[idx[:, None]],
-            pts=frame.pts, duration=frame.duration, meta=dict(frame.meta),
-            frames_info=infos,
-        )
+        with span("nns.decoder.labels", frames=len(frame.frames_info)):
+            packed = np.asarray(frame.tensors[0], np.float64).reshape(-1, 2)
+            idx = packed[:, 0].astype(np.int32)
+            labels = self.labels
+            infos = []
+            for j, (p, d, m) in enumerate(frame.frames_info):
+                m2 = dict(m)
+                i = int(idx[j])
+                m2["label_index"] = i
+                m2["label_score"] = float(packed[j, 1])
+                if labels and i < len(labels):
+                    m2["label"] = labels[i]
+                infos.append((p, d, m2))
+            return BatchFrame(
+                tensors=[idx[:, None]],
+                pts=frame.pts, duration=frame.duration, meta=dict(frame.meta),
+                frames_info=infos,
+            )

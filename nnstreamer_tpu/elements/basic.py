@@ -19,6 +19,8 @@ from typing import Any, Callable, Iterator, List, Optional, Sequence
 import numpy as np
 
 from ..core.buffer import FRAME_POOL, BatchFrame, TensorFrame
+from ..core.telemetry import TRACE_ID_META, new_trace_id
+from ..core.tracer import record, span
 from ..core.types import ANY, FORMAT_STATIC, StreamSpec, TensorSpec
 from ..pipeline.element import (
     Element,
@@ -93,29 +95,58 @@ class AppSrc(SourceElement):
     def output_spec(self) -> StreamSpec:
         return self._spec
 
-    def push(self, frame_or_arrays: Any, pts: Optional[float] = None) -> None:
-        if isinstance(frame_or_arrays, TensorFrame):
-            frame = frame_or_arrays
-        else:
-            arrays = (
-                list(frame_or_arrays)
-                if isinstance(frame_or_arrays, (list, tuple))
-                else [frame_or_arrays]
-            )
-            # keep device arrays (jax.Array) as-is — zero-copy into the stream
-            frame = TensorFrame(
-                [a if hasattr(a, "shape") else np.asarray(a) for a in arrays],
-                pts=pts,
-            )
-        if frame.pts is None:
-            fr = self.props["framerate"]
-            if fr:
-                frame.pts = self._count * _frame_interval(fr)
-        self._count += 1
-        # a pushed frame may itself be a BatchFrame (N logical frames):
-        # count what the pop side will count or pending_frames() skews
-        self._pushed_logical += getattr(frame, "batch_size", 1)
+    def _offer(self, frame: TensorFrame, sp):
+        """Hand ``frame`` to the stream from inside its push span.  No
+        profiler session: the plain blocking put.  Live: the frame gets
+        its request id here (the source mints it), and a FULL queue
+        returns the id instead of sleeping inside the span — the caller
+        finishes with :meth:`_put_blocked` once the span has closed, so
+        the span is the push's work and the sleep is recorded apart."""
+        if not sp.live:
+            self._q.put(frame)
+            return None
+        rid = frame.meta.setdefault(TRACE_ID_META, new_trace_id())
+        sp.set(request=rid)
+        try:
+            self._q.put_nowait(frame)
+            return None
+        except _queue.Full:
+            return rid
+
+    def _put_blocked(self, frame: TensorFrame, rid) -> None:
+        t0 = time.perf_counter()
         self._q.put(frame)
+        record("nns.appsrc.blocked", t0, time.perf_counter(), request=rid)
+
+    def push(self, frame_or_arrays: Any, pts: Optional[float] = None) -> None:
+        with span("nns.appsrc.push") as sp:
+            if isinstance(frame_or_arrays, TensorFrame):
+                frame = frame_or_arrays
+            else:
+                arrays = (
+                    list(frame_or_arrays)
+                    if isinstance(frame_or_arrays, (list, tuple))
+                    else [frame_or_arrays]
+                )
+                # keep device arrays (jax.Array) as-is — zero-copy into
+                # the stream
+                frame = TensorFrame(
+                    [a if hasattr(a, "shape") else np.asarray(a)
+                     for a in arrays],
+                    pts=pts,
+                )
+            if frame.pts is None:
+                fr = self.props["framerate"]
+                if fr:
+                    frame.pts = self._count * _frame_interval(fr)
+            self._count += 1
+            # a pushed frame may itself be a BatchFrame (N logical
+            # frames): count what the pop side will count or
+            # pending_frames() skews
+            self._pushed_logical += getattr(frame, "batch_size", 1)
+            rid = self._offer(frame, sp)
+        if rid is not None:
+            self._put_blocked(frame, rid)
 
     def push_block(
         self, arrays: Any, pts: Optional[Sequence[Optional[float]]] = None
@@ -165,7 +196,10 @@ class AppSrc(SourceElement):
         )
         self._count += n
         self._pushed_logical += n
-        self._q.put(frame)
+        with span("nns.appsrc.push") as sp:
+            rid = self._offer(frame, sp)
+        if rid is not None:
+            self._put_blocked(frame, rid)
 
     def push_event(self, event) -> None:
         """Queue an out-of-band event into the stream in arrival order
@@ -302,6 +336,12 @@ class TensorSink(SinkElement):
             for f in frame.split():
                 self.render(f)
             return
+        with span("nns.sink.render") as sp:
+            if sp.live:
+                sp.set(request=frame.meta.get(TRACE_ID_META))
+            self._deliver(frame)
+
+    def _deliver(self, frame: TensorFrame) -> None:
         if self.props["to-host"]:
             frame = frame.to_host()
         self._rendered += getattr(frame, "batch_size", 1)

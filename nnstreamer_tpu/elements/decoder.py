@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from ..core import registry
 from ..core.buffer import BatchFrame
+from ..core.tracer import BATCH_SEQ_META, span
 from ..core.types import ANY
 from ..pipeline.element import ElementError, Property, TransformElement, element
 from .. import decoders as _decoders  # noqa: F401 — registers decoder modes
@@ -115,7 +116,11 @@ class TensorDecoder(TransformElement):
         # micro-batch as ONE device-resident BatchFrame; split() does the
         # single (tiny, post-device_fn) device->host transfer, then the
         # host finisher runs per logical frame.
-        if isinstance(frame, BatchFrame):
+        if not isinstance(frame, BatchFrame):
+            return super().handle_frame(pad, frame)
+        # ``seq`` is the upstream filter's number for this micro-batch
+        with span("nns.decoder.batch", seq=frame.meta.get(BATCH_SEQ_META),
+                  frames=frame.batch_size):
             spec = self.sink_specs.get(0, ANY)
             if (
                 self._fused
@@ -127,4 +132,3 @@ class TensorDecoder(TransformElement):
                 return [(0, self._dec.decode_fused_batch(frame, spec))]
             dec = self._dec.decode_fused if self._fused else self._dec.decode
             return [(0, dec(f, spec)) for f in frame.split()]
-        return super().handle_frame(pad, frame)

@@ -38,6 +38,7 @@ from ..core.liveness import StallError
 from ..core.model_uri import resolve_model_uri
 from ..core.resilience import FAULTS, DeviceLostError, DeviceOomError
 from ..core.telemetry import TL_INVOKE_META, TL_RX_META
+from ..core.tracer import BATCH_SEQ_META, armed, span
 from ..core.types import ANY, FORMAT_FLEXIBLE, StreamSpec
 from ..pipeline.element import ElementError, Property, TransformElement, element
 
@@ -376,7 +377,12 @@ class TensorFilter(TransformElement):
         # host-ingest staging lane + the one-batch staged deferral that
         # double-buffers it (dispatch of batch k happens while k+1 stages)
         self._lane: Optional[HostStagingLane] = None
-        self._staged: Optional[Tuple[StagedBatch, List[TensorFrame], int]] = None
+        self._staged: Optional[
+            Tuple[StagedBatch, List[TensorFrame], int, int]] = None
+        # micro-batch sequence number (dispatch-thread-private): what the
+        # spans of one batch share from staging to emission
+        self._seq = 0
+        self._seq_alloc = 0
         # async-output capability, latched ONCE per backend instance
         # (reset at start()/swap/rollback) — the hot path never re-probes
         self._win_async: Optional[bool] = None
@@ -932,9 +938,14 @@ class TensorFilter(TransformElement):
 
     def _backend_invoke(self, inputs: List[Any]) -> List[Any]:
         sw = self._swapper
-        if sw is None or not sw.observing:
-            return self.backend.timed_invoke(inputs)
-        return self._observed_invoke(False, inputs)
+        # per-frame call site: no keywords (span()'s off branch must
+        # allocate nothing), attributes only while a session is live
+        with span("nns.filter.invoke") as sp:
+            if sp.live:
+                sp.set(frames=1, bucket=1)
+            if sw is None or not sw.observing:
+                return self.backend.timed_invoke(inputs)
+            return self._observed_invoke(False, inputs)
 
     def _backend_invoke_batch(
         self, inputs: List[Any], private: bool = False
@@ -946,11 +957,15 @@ class TensorFilter(TransformElement):
         replayed on the retained old backend with the SAME inputs, which
         donation would have destroyed."""
         sw = self._swapper
-        if sw is None or not sw.observing:
-            if private:
-                return self.backend.timed_invoke_batch_donated(inputs)
-            return self.backend.timed_invoke_batch(inputs)
-        return self._observed_invoke(True, inputs)
+        # ``bucket`` (what the batch was padded to) is the backend's to
+        # say: it notes it on this span (tracer.note)
+        with span("nns.filter.invoke", seq=self._seq,
+                  frames=int(inputs[0].shape[0])):
+            if sw is None or not sw.observing:
+                if private:
+                    return self.backend.timed_invoke_batch_donated(inputs)
+                return self.backend.timed_invoke_batch(inputs)
+            return self._observed_invoke(True, inputs)
 
     # -- device-resource resilience (degrade, don't die) ---------------------
     def _resilient_invoke(self, inputs: List[Any]) -> List[Any]:
@@ -1130,7 +1145,7 @@ class TensorFilter(TransformElement):
         accounting, Pipeline.drain)."""
         n = sum(
             sum(getattr(f, "batch_size", 1) for f in frames)
-            for frames in self._inflight.payloads()
+            for frames, _ in self._inflight.payloads()
         )
         staged = self._staged
         if staged is not None:
@@ -1398,13 +1413,16 @@ class TensorFilter(TransformElement):
             # thread, and dispatch is DEFERRED BY ONE BATCH — by the time
             # batch k's device arrays are needed, its transfer has been
             # overlapping batch k-1's compute (double-buffered staging)
-            job = self._lane.submit(per_frame)
-            prev, self._staged = self._staged, (job, frames, len(frames))
+            seq = self._next_seq()
+            job = self._lane.submit(per_frame, seq=seq)
+            prev, self._staged = self._staged, (
+                job, frames, len(frames), seq)
             if prev is None:
                 return []
-            pjob, pframes, pn = prev
-            batched = self._staged_result(pjob)
-            return self._run_batch(batched, pframes, pn, private=True)
+            pjob, pframes, pn, pseq = prev
+            batched = self._staged_result(pjob, pseq)
+            return self._run_batch(batched, pframes, pn, private=True,
+                                   seq=pseq)
         results = self._flush_staged()  # mixed stream: keep FIFO
         ntensors = len(per_frame[0])
         batched = [
@@ -1414,9 +1432,13 @@ class TensorFilter(TransformElement):
                                        private=True))
         return results
 
+    def _next_seq(self) -> int:
+        self._seq_alloc += 1
+        return self._seq_alloc
+
     def _run_batch(
         self, batched: List[Any], frames: List[TensorFrame], nlogical: int,
-        private: bool = False,
+        private: bool = False, seq: Optional[int] = None,
     ) -> List[Tuple[int, TensorFrame]]:
         """Shared micro-batch tail: one invoke_batch + stats, then either
         batch-through (device residency: the whole micro-batch leaves as
@@ -1424,24 +1446,32 @@ class TensorFilter(TransformElement):
         next batch's stack/dispatch overlaps this one's compute; downstream
         fused decoder / chained filter / sink splits or materializes at the
         real host boundary) or the depth-N dispatch window.  ``private``
-        marks caller-created batches the backend may donate."""
+        marks caller-created batches the backend may donate; ``seq`` is
+        the batch's sequence number where staging already gave it one."""
         import time
 
-        FAULTS.check("filter.invoke", interrupt=lambda: self.interrupted)
-        t0 = time.perf_counter()
-        out_b = self._resilient_invoke_batch(batched, private=private)
-        dt = time.perf_counter() - t0
-        self._record_stats(dt, nlogical)
-        self._stamp_invoke_spans(
-            frames, t0 - self._t_handler if self._t_handler else 0.0, dt)
-        if self.batch_through_active:
-            infos = _logical_infos(frames)
-            p, d, m = infos[0]
-            return [(0, FRAME_POOL.acquire_batch(
-                list(out_b), pts=p, duration=d, meta=dict(m),
-                frames_info=infos,
-            ))]
-        return self._dispatch_or_park(out_b, frames)
+        self._seq = self._next_seq() if seq is None else seq
+        with span("nns.filter.batch", seq=self._seq, frames=nlogical):
+            FAULTS.check("filter.invoke", interrupt=lambda: self.interrupted)
+            t0 = time.perf_counter()
+            out_b = self._resilient_invoke_batch(batched, private=private)
+            dt = time.perf_counter() - t0
+            self._record_stats(dt, nlogical)
+            self._stamp_invoke_spans(
+                frames, t0 - self._t_handler if self._t_handler else 0.0, dt)
+            if self.batch_through_active:
+                infos = _logical_infos(frames)
+                p, d, m = infos[0]
+                meta = dict(m)
+                if armed():
+                    # the batch leaves device-resident: whoever brings it to
+                    # the host (a fused decoder, a sink) names its span by it
+                    meta[BATCH_SEQ_META] = self._seq
+                return [(0, FRAME_POOL.acquire_batch(
+                    list(out_b), pts=p, duration=d, meta=meta,
+                    frames_info=infos,
+                ))]
+            return self._dispatch_or_park(out_b, frames)
 
     def _dispatch_or_park(
         self, out_b: List[Any], frames: List[TensorFrame]
@@ -1474,7 +1504,7 @@ class TensorFilter(TransformElement):
             from ..core.buffer import start_host_copies
 
             start_host_copies(out_b)
-            self._inflight.park(out_b, frames)
+            self._inflight.park(out_b, (frames, self._seq), seq=self._seq)
             results = self._pop_ready()
             while len(self._inflight) > depth - 1:
                 self._wait_window_oldest()
@@ -1483,7 +1513,8 @@ class TensorFilter(TransformElement):
         # synchronous path: drain any batches parked while the window was
         # active (depth lowered mid-stream / backend change) first, so the
         # current batch cannot overtake them
-        return self._drain_inflight() + self._emit_batch(out_b, frames)
+        return self._drain_inflight() + self._emit_batch(
+            out_b, frames, seq=self._seq)
 
     def _handle_prebatched(
         self, frames: List[TensorFrame]
@@ -1528,67 +1559,74 @@ class TensorFilter(TransformElement):
 
     def _emit_batch(
         self, out_b: Optional[List[Any]], frames: List[TensorFrame],
-        out_np: Optional[List[Any]] = None,
+        out_np: Optional[List[Any]] = None, seq: Optional[int] = None,
     ) -> List[Tuple[int, TensorFrame]]:
         """Materialize one micro-batch's outputs (one overlapped
         device->host pass for all tensors, then zero-copy views per
         frame).  ``frames`` may mix plain frames (one output row each)
         and BatchFrames (``batch_size`` consecutive rows).  ``out_np``
         carries outputs the window's reaper already materialized."""
-        from ..core.buffer import materialize
+        with span("nns.filter.emit", seq=seq, frames=len(frames)):
+            from ..core.buffer import materialize
 
-        if out_np is None:
-            out_np = materialize(out_b)
-        # only the tensor indices an 'iN' entry actually reads get pulled
-        # to host; "o0"-style output subsetting (and unreferenced input
-        # tensors) must not drag input blocks over the link
-        need_idx = sorted({
-            i for src, i in (self._out_comb or []) if src == "i"
-        }) if self._out_needs_inputs else []
-        results = []
-        b = 0
-        for f in frames:
-            if isinstance(f, BatchFrame):
-                ins_np: List[Any] = [None] * len(f.tensors)
-                if need_idx:
-                    mats = materialize([f.tensors[i] for i in need_idx])
-                    for k, i in enumerate(need_idx):
-                        ins_np[i] = mats[k]
-                for j, (p, d, m) in enumerate(f.frames_info):
-                    outs = [o[b + j] for o in out_np]
-                    if self._out_comb:
-                        ins = [
-                            (t[j] if t is not None else None) for t in ins_np
-                        ]
-                        outs = self._compose_outputs(ins, outs)
-                    results.append((0, FRAME_POOL.acquire(
-                        outs, pts=p, duration=d, meta=dict(m),
-                    )))
-                b += f.batch_size
-            else:
-                outs = [o[b] for o in out_np]
-                results.append(
-                    (0, f.with_tensors(self._compose_outputs(f.tensors, outs)))
-                )
-                b += 1
-        return results
+            if out_np is None:
+                out_np = materialize(out_b)
+            # only the tensor indices an 'iN' entry actually reads get pulled
+            # to host; "o0"-style output subsetting (and unreferenced input
+            # tensors) must not drag input blocks over the link
+            need_idx = sorted({
+                i for src, i in (self._out_comb or []) if src == "i"
+            }) if self._out_needs_inputs else []
+            results = []
+            b = 0
+            for f in frames:
+                if isinstance(f, BatchFrame):
+                    ins_np: List[Any] = [None] * len(f.tensors)
+                    if need_idx:
+                        mats = materialize([f.tensors[i] for i in need_idx])
+                        for k, i in enumerate(need_idx):
+                            ins_np[i] = mats[k]
+                    for j, (p, d, m) in enumerate(f.frames_info):
+                        outs = [o[b + j] for o in out_np]
+                        if self._out_comb:
+                            ins = [
+                                (t[j] if t is not None else None) for t in ins_np
+                            ]
+                            outs = self._compose_outputs(ins, outs)
+                        results.append((0, FRAME_POOL.acquire(
+                            outs, pts=p, duration=d, meta=dict(m),
+                        )))
+                    b += f.batch_size
+                else:
+                    outs = [o[b] for o in out_np]
+                    results.append(
+                        (0, f.with_tensors(self._compose_outputs(f.tensors, outs)))
+                    )
+                    b += 1
+            return results
 
     def _pop_ready(self) -> List[Tuple[int, TensorFrame]]:
         """Emit every batch the reaper has COMPLETED at the front of the
         window (FIFO), without blocking."""
         results: List[Tuple[int, TensorFrame]] = []
-        for mats, frames in self._inflight.pop_ready():
-            results.extend(self._emit_batch(None, frames, out_np=mats))
+        for mats, (frames, seq) in self._inflight.pop_ready():
+            results.extend(
+                self._emit_batch(None, frames, out_np=mats, seq=seq))
         return results
 
     def _wait_window_oldest(self) -> None:
         """Bounded, cooperatively interruptible wait for the oldest
         parked batch's completion (full-window backpressure)."""
-        while not self._inflight.wait_oldest(timeout=0.05):
-            if self.interrupted:
-                raise StallError(
-                    f"{self.name}: interrupted waiting on the dispatch "
-                    "window")
+        if self._inflight.oldest_ready():
+            return
+        # the dispatch thread blocked because the window is full: the
+        # thread that feeds the device, so the wait is named
+        with span("nns.feed.window_wait", element=self.name):
+            while not self._inflight.wait_oldest(timeout=0.05):
+                if self.interrupted:
+                    raise StallError(
+                        f"{self.name}: interrupted waiting on the dispatch "
+                        "window")
 
     def _drain_inflight(self) -> List[Tuple[int, TensorFrame]]:
         results = self._pop_ready()
@@ -1597,13 +1635,18 @@ class TensorFilter(TransformElement):
             results.extend(self._pop_ready())
         return results
 
-    def _staged_result(self, job: StagedBatch) -> List[Any]:
+    def _staged_result(self, job: StagedBatch, seq: int) -> List[Any]:
         """Collect a staging job's device arrays (bounded waits so a
         wedged transfer stays interruptible)."""
-        while not job.wait(timeout=0.05):
-            if self.interrupted:
-                raise StallError(
-                    f"{self.name}: interrupted waiting on the ingest lane")
+        if not job.wait(timeout=0.0):
+            # the dispatch thread blocked on the lane: host-to-device
+            # time the double buffer did NOT hide
+            with span("nns.filter.stage_wait", seq=seq):
+                while not job.wait(timeout=0.05):
+                    if self.interrupted:
+                        raise StallError(
+                            f"{self.name}: interrupted waiting on the "
+                            "ingest lane")
         # allow-blocking: the wait() loop above already saw _done set —
         # result() returns (or raises the staging error) immediately
         return job.result()
@@ -1615,10 +1658,11 @@ class TensorFilter(TransformElement):
         FIFO order."""
         if self._staged is None:
             return []
-        job, frames, nlogical = self._staged
+        job, frames, nlogical, seq = self._staged
         self._staged = None
-        batched = self._staged_result(job)
-        return self._run_batch(batched, frames, nlogical, private=True)
+        batched = self._staged_result(job, seq)
+        return self._run_batch(batched, frames, nlogical, private=True,
+                               seq=seq)
 
     def handle_eos(self, pad: int) -> List[Tuple[int, TensorFrame]]:
         """Release the staged batch and drain the in-flight window before

@@ -38,6 +38,7 @@ carries tokens (B, n) int32 plus meta ``stream_seq`` (source frame seq),
 
 from __future__ import annotations
 
+import time
 from collections import OrderedDict
 from typing import Any, Dict, Optional
 
@@ -58,6 +59,8 @@ from ..core.liveness import (
     clamp_priority,
     thread_census,
 )
+from ..core.telemetry import TL_RX_META
+from ..core.tracer import armed, record
 from ..core.types import ANY, FORMAT_FLEXIBLE, StreamSpec
 from ..pipeline.element import Element, ElementError, Property, element
 
@@ -851,7 +854,7 @@ class TensorGenerator(Element):
                 if resume is None:
                     rejects.append(self._resume_reject(lf, reason))
                     continue
-            self._engine.submit(
+            stream = self._engine.submit(
                 lf, prompt.astype(np.int32), max_new, chunk,
                 tenant=str(meta.get(TENANT_META, "") or ""),
                 priority=clamp_priority(
@@ -859,6 +862,13 @@ class TensorGenerator(Element):
                 deadline_ts=meta.get(DEADLINE_META),
                 resume=resume,
             )
+            rx = meta.get(TL_RX_META)
+            if rx is not None and armed():
+                # routing: the query server's receive stamp to the
+                # engine's queue (crosses the transport, source and
+                # generator threads, so it is a ring record)
+                record("nns.query.route", rx, time.perf_counter(),
+                       request=stream.sid)
         return rejects + self._engine.pop_ready()
 
     def _check_resume(self, lf, prompt, max_new: int, rs):

@@ -43,7 +43,10 @@ from ..core.liveness import DEADLINE_META, StallError, Watchdog, stamp_deadline
 from ..core.log import get_logger
 from ..core.resilience import FAULTS
 from ..core.telemetry import TL_QPUT_META
-from ..core.tracer import META_SRC_TS, PipelineTracer, frame_nbytes
+from ..core.tracer import (
+    META_SRC_TS, PipelineTracer, armed, frame_nbytes, name_os_thread,
+    record,
+)
 from .element import Element, ElementError, SinkElement, SourceElement
 
 _STOP = object()  # out-of-band worker shutdown sentinel
@@ -1635,17 +1638,35 @@ class Pipeline:
             if is_frame and isinstance(box, _LeakyMailbox):
                 box.put_frame((sink_pad, item))
                 continue
-            while not self._stop_flag.is_set():
-                try:
-                    box.put((sink_pad, item), timeout=0.1)
-                    break
-                except queue.Full:
-                    continue
-            else:
+            if not self._put_blocking(el, box, (sink_pad, item)):
                 return False
         return True
 
-    def _put_many(self, dst: Element, items: list) -> int:
+    def _put_blocking(self, el: Element, box, entry) -> bool:
+        """Bounded-wait put that keeps the stop flag responsive; False if
+        stopping.  A put that finds the mailbox full is back-pressure on
+        ``el``'s thread: while a profiler session is live its wait goes
+        to the span ring (``nns.pipeline.push_wait``; ring only — a
+        thread asleep on a queue opens no annotation)."""
+        try:
+            box.put_nowait(entry)
+            return True
+        except queue.Full:
+            t0 = time.perf_counter() if armed() else None
+        try:
+            while not self._stop_flag.is_set():
+                try:
+                    box.put(entry, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
+            return False
+        finally:
+            if t0 is not None:
+                record("nns.pipeline.push_wait", t0, time.perf_counter(),
+                       element=el.name)
+
+    def _put_many(self, el: Element, dst: Element, items: list) -> int:
         """Deliver an ordered run of ``(pad, item)`` entries into ``dst``'s
         mailbox, amortizing the lock/condvar cost over the run when the
         mailbox supports bulk insertion (block handoff); falls back to the
@@ -1671,14 +1692,7 @@ class Pipeline:
                     continue  # partial progress: retry the remainder
             # blocked (or no bulk support): bounded-wait single put so the
             # stop flag stays responsive and events are never dropped
-            entry = items[idx]
-            while not self._stop_flag.is_set():
-                try:
-                    box.put(entry, timeout=0.1)
-                    break
-                except queue.Full:
-                    continue
-            else:
+            if not self._put_blocking(el, box, items[idx]):
                 return idx
             idx += 1
         return idx
@@ -1717,7 +1731,7 @@ class Pipeline:
                     runs[k][1].append((sink_pad, out))
         track_each = st is not None and len(runs) == 1
         for dst, items in runs:
-            n = self._put_many(dst, items)
+            n = self._put_many(el, dst, items)
             if track_each:
                 for _, item in items[:n]:
                     if isinstance(item, TensorFrame):
@@ -1983,6 +1997,7 @@ class Pipeline:
         return self._route_outs(seg, st, outs)
 
     def _run_segment(self, seg: _Seg) -> None:
+        name_os_thread()
         for st in seg.states.values():
             st.watch = self._watches.get(st.el.name)
         head = seg.chain[0]

@@ -266,6 +266,12 @@ def test_fleet_observatory_armed_identity_floor():
         while done["n"] < n and time.perf_counter() - t0 < 60:
             time.sleep(0.002)
         fps = done["n"] / (time.perf_counter() - t0)
+        # the sweeper is a thread of its own: on a fast, quiet machine the
+        # timed frames can be through before its first tick, so give it a
+        # bounded moment (outside the timed window) before the stop
+        t_s = time.time()
+        while pub.published == 0 and time.time() - t_s < 10:
+            time.sleep(0.01)
         src.end_of_stream()
         pipe.wait(timeout=30)
         pipe.stop()
@@ -322,6 +328,10 @@ def test_autoscale_controller_armed_identity_floor():
         while done["n"] < n and time.perf_counter() - t0 < 60:
             time.sleep(0.002)
         fps = done["n"] / (time.perf_counter() - t0)
+        # as above: let the sweeper reach its first tick before the stop
+        t_s = time.time()
+        while ctrl.ticks == 0 and time.time() - t_s < 10:
+            time.sleep(0.01)
         src.end_of_stream()
         pipe.wait(timeout=30)
         pipe.stop()
