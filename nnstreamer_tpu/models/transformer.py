@@ -15,6 +15,7 @@ trainer element).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import flax.linen as nn
@@ -47,18 +48,110 @@ class TransformerConfig:
     quant: bool = False
 
 
+def kv_attend_write(ck, cv, q, k, v, pos, n_heads):
+    """The ONE decode-cache step every generation path shares: attend
+    over the cache leaves as they lie plus the new rows, then write the
+    new rows into the leaves.
+
+    ``ck``/``cv`` are lane-dense ``(B, max_seq, d_model)`` leaves (heads
+    contiguous in the minor dim, so a row is whole 128-lane tiles and the
+    device layout is the logical one); ``q``/``k``/``v`` are ``(B, T,
+    d_model)`` in the cache dtype; ``pos`` is ``(B,)``: row ``b`` holds
+    ``pos[b]`` older positions, its new rows are positions ``pos[b] ..
+    pos[b]+T-1``, and query ``i`` sees the older ones and new rows
+    ``<= i``.  Returns ``(ck, cv, attn (B, T, d_model))``.
+
+    Reading the leaves BEFORE the write is what keeps the step to one
+    read of K and of V: the leaf the contraction reads is the loop's
+    own carry, the write is an in-place row scatter nothing downstream
+    reads, and no whole-leaf buffer has to exist beside it.  (Written
+    first and read second, XLA:TPU stages each leaf through VMEM and
+    copies all of it back.)  The write is ``mode="drop"``: a row at or
+    past ``max_seq`` writes nothing, where a clamping
+    ``dynamic_update_slice`` would overwrite the last rows.
+
+    Scores, mask, softmax and accumulation are float32; K and V are read
+    in the dtype they are stored in and the probabilities are never
+    rounded.  The softmax is taken over both parts at once: one shared
+    max, exponentials summed over cache and new rows, one division after
+    the value contraction.
+
+    ``T == 1`` (the per-token step) contracts on the MXU against the
+    leaf's own layout: ``q`` is laid block-diagonal over ``d_model`` so
+    ``(S, D) x (D, H)`` gives every head's scores, and ``e (H, S) x
+    (S, D)`` gives every head's mix of every head's values, of which the
+    head diagonal is kept.  The idle products cost H x the MACs on a
+    unit the step barely uses; what they buy is that no transposed or
+    float32 copy of a leaf is ever made.  ``T > 1`` (prefill) splits the
+    rows it reads into heads, which copies them once: small beside the
+    chunk's matmuls, and without the H x.
+    """
+    B, T, D = q.shape
+    S = ck.shape[1]
+    H, Dh = n_heads, D // n_heads
+    dot = functools.partial(
+        jnp.einsum, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    scale = 1.0 / np.sqrt(Dh)
+    q4, k4, v4 = (t.reshape(B, T, H, Dh) for t in (q, k, v))
+    if T == 1:
+        own = jnp.eye(H, dtype=bool)
+        q_diag = jnp.where(
+            own[None, :, None, :], q.reshape(B, H, Dh, 1), 0
+        ).reshape(B, D, H)
+        s_old = dot("bsd,bdh->bhs", ck, q_diag)[:, :, None]
+
+        def mix_old(e):  # (B, H, 1, S) -> (B, H, 1, Dh)
+            every = dot("bhs,bsd->bhd", e[:, :, 0], cv).reshape(B, H, H, Dh)
+            return jnp.where(own[None, :, :, None], every, 0).sum(axis=1)[:, :, None]
+    else:
+        s_old = dot("bthd,bshd->bhts", q4, ck.reshape(B, S, H, Dh))
+
+        def mix_old(e):  # (B, H, T, S) -> (B, H, T, Dh)
+            return dot("bhts,bshd->bhtd", e, cv.reshape(B, S, H, Dh))
+
+    older = jnp.arange(S)[None, :] < pos[:, None]  # (B, S)
+    s_old = jnp.where(older[:, None, None], s_old * scale, -1e30)
+    s_new = jnp.where(
+        jnp.tri(T, dtype=bool), dot("bthd,buhd->bhtu", q4, k4) * scale, -1e30
+    )  # (B, H, T, T)
+    top = jnp.maximum(s_old.max(axis=-1), s_new.max(axis=-1))[..., None]
+    e_old, e_new = jnp.exp(s_old - top), jnp.exp(s_new - top)
+    total = e_old.sum(axis=-1) + e_new.sum(axis=-1)  # (B, H, T)
+    mix = mix_old(e_old) + dot("bhtu,buhd->bhtd", e_new, v4)
+    attn = jnp.moveaxis(mix / total[..., None], 1, 2)  # (B, T, H, Dh)
+
+    slot = jnp.arange(B)[:, None]
+    rows = pos[:, None] + jnp.arange(T)[None, :]  # (B, T)
+
+    def write(c, new):
+        return c.at[slot, rows].set(
+            new.astype(c.dtype), mode="drop", indices_are_sorted=True,
+            unique_indices=True,
+        )
+
+    return write(ck, k), write(cv, v), attn.reshape(B, T, D).astype(q.dtype)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     mesh: Optional[Mesh] = None
     seq_axis: str = "sp"
-    decode: bool = False  # KV-cache single-token step (generation serving)
-    # continuous batching (core/slots.py): the cache becomes SLOT-INDEXED
-    # pages — per-slot write positions instead of one shared scalar, so
+    # KV-cache step (generation serving): T == 1 is the per-token decode
+    # step, T > 1 is chunked PREFILL (the chunk attends causally in one
+    # pass while filling the cache).  The cache is a static-shape pair of
+    # (B, max_seq, d_model) leaves, so the generate loop is one compiled
+    # program with no growing shapes; kv_attend_write is its one read
+    # and its one write.
+    decode: bool = False
+    # continuous batching (core/slots.py): the batch rows are SLOTS, each
+    # with its own write position instead of one shared scalar, so
     # independent generation streams at different depths share one batch.
-    # Each slot's pages are written through its own dynamic_update_slice
-    # (a joining stream touches only its slot; a leaving stream's pages
-    # are reusable without touching neighbors) and the causal mask is
-    # per-slot, so the jitted step stays shape-stable as streams churn.
+    # A step writes one row block per slot in place (a joining stream
+    # touches only its slot; a leaving stream's pages are reusable
+    # without touching neighbors) and the causal mask is per slot, so the
+    # jitted step stays shape-stable as streams churn.
     slotted: bool = False
 
     def _dense(self, features, name):
@@ -75,120 +168,46 @@ class Block(nn.Module):
         h = nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x)
         qkv = self._dense(3 * D, "attn_qkv")(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, H, D // H)
-        k = k.reshape(B, T, H, D // H)
-        v = v.reshape(B, T, H, D // H)
-        if self.decode and self.slotted:
-            # per-slot paged KV cache: index is a VECTOR (one write
-            # position per slot).  Idle slots (active=0) keep writing
-            # harmlessly into their frozen position but never advance —
-            # the mask math stays identical for every occupied slot, so
-            # a single occupant's row is bit-identical to the unslotted
-            # path (row independence; pinned in tests).
-            ck = self.variable(
-                "cache", "key",
-                lambda: jnp.zeros((B, cfg.max_seq, H, D // H), cfg.dtype),
-            )
-            cv = self.variable(
-                "cache", "value",
-                lambda: jnp.zeros((B, cfg.max_seq, H, D // H), cfg.dtype),
-            )
-            idx = self.variable(
-                "cache", "index", lambda: jnp.zeros((B,), jnp.int32)
-            )
-            pos = idx.value  # (B,)
+        if self.decode:
+            def pages():
+                return jnp.zeros((B, cfg.max_seq, D), cfg.dtype)
 
-            # per-slot page write WITHOUT a scatter: vmapped
-            # dynamic_update_slice lowers to lax.scatter, which XLA's CPU
-            # backend executes orders of magnitude slower than the
-            # equivalent dense select; one broadcast `where` per chunk
-            # position (T is static) keeps the write a single vectorized
-            # pass over the slot's pages
-            def write(c, kk):
-                for t in range(T):
-                    hit = (
-                        jnp.arange(cfg.max_seq)[None, :]
-                        == (pos + t)[:, None]
-                    )[..., None, None]  # (B, S, 1, 1)
-                    c = jnp.where(hit, kk[:, t:t + 1], c)
-                return c
-
-            ck.value = write(ck.value, k)
-            cv.value = write(cv.value, v)
-            adv = T if active is None else T * active.astype(jnp.int32)
-            idx.value = pos + adv
-            # slot b, query i (global position pos[b]+i) sees cache
-            # slots <= pos[b]+i
-            mask = (
-                jnp.arange(cfg.max_seq)[None, None, :]
-                <= (pos[:, None] + jnp.arange(T)[None, :])[..., None]
-            )  # (B, T, S)
-            scores = jnp.einsum(
-                "bthd,bshd->bhts", q.astype(jnp.float32),
-                ck.value.astype(jnp.float32),
-            ) / np.sqrt(D // H)
-            scores = jnp.where(mask[:, None], scores, -1e30)
-            attn = jnp.einsum(
-                "bhts,bshd->bthd",
-                jax.nn.softmax(scores, axis=-1),
-                cv.value.astype(jnp.float32),
-            ).astype(cfg.dtype)
-        elif self.decode:
-            # KV-cache attention over a static-shape ring of max_seq slots
-            # (dynamic_update_slice keeps the generate loop one compiled
-            # program — no growing shapes).  T == 1 is the per-token decode
-            # step; T > 1 is chunked PREFILL: the whole prompt attends
-            # causally in one pass while filling the cache, so prefill
-            # costs one forward instead of T sequential steps.
-            ck = self.variable(
-                "cache", "key",
-                lambda: jnp.zeros((B, cfg.max_seq, H, D // H), cfg.dtype),
-            )
-            cv = self.variable(
-                "cache", "value",
-                lambda: jnp.zeros((B, cfg.max_seq, H, D // H), cfg.dtype),
-            )
+            ck = self.variable("cache", "key", pages)
+            cv = self.variable("cache", "value", pages)
+            # slotted: one write position per slot; unslotted: one shared
+            # scalar, broadcast, so both run the same program per row and
+            # a single occupant's slotted row is bit-identical to the
+            # unslotted path (row independence; pinned in tests)
             idx = self.variable(
-                "cache", "index", lambda: jnp.zeros((), jnp.int32)
+                "cache", "index",
+                lambda: jnp.zeros((B,) if self.slotted else (), jnp.int32),
             )
             pos = idx.value
-            ck.value = jax.lax.dynamic_update_slice(
-                ck.value, k, (0, pos, 0, 0)
+            ck.value, cv.value, attn = kv_attend_write(
+                ck.value, cv.value, q, k, v,
+                jnp.broadcast_to(pos, (B,)), H,
             )
-            cv.value = jax.lax.dynamic_update_slice(
-                cv.value, v, (0, pos, 0, 0)
-            )
-            idx.value = pos + T
-            # query i (global position pos+i) sees cache slots <= pos+i
-            mask = (
-                jnp.arange(cfg.max_seq)[None, :]
-                <= (pos + jnp.arange(T))[:, None]
-            )  # (T, S)
-            scores = jnp.einsum(
-                "bthd,bshd->bhts", q.astype(jnp.float32),
-                ck.value.astype(jnp.float32),
-            ) / np.sqrt(D // H)
-            scores = jnp.where(mask[None, None], scores, -1e30)
-            attn = jnp.einsum(
-                "bhts,bshd->bthd",
-                jax.nn.softmax(scores, axis=-1),
-                cv.value.astype(jnp.float32),
-            ).astype(cfg.dtype)
-        elif self.mesh is not None and self.mesh.shape.get(self.seq_axis, 1) > 1:
-            from ..parallel.ulysses import sequence_attention
-
-            attn = sequence_attention(
-                q, k, v, self.mesh, seq_axis=self.seq_axis, causal=True,
-                strategy=cfg.sp_strategy,
-            )
-        elif cfg.attn_impl == "flash":
-            from ..ops.flash_attention import flash_attention_grad
-
-            # differentiable wrapper: kernel forward, recompute backward
-            attn = flash_attention_grad(q, k, v, True)
+            # idle slots (active=0) keep writing harmlessly into their
+            # frozen position but never advance
+            adv = T if active is None else T * active.astype(jnp.int32)
+            idx.value = pos + adv
         else:
-            attn = reference_attention(q, k, v, causal=True)
-        attn = attn.reshape(B, T, D)
+            q, k, v = (t.reshape(B, T, H, D // H) for t in (q, k, v))
+            if self.mesh is not None and self.mesh.shape.get(self.seq_axis, 1) > 1:
+                from ..parallel.ulysses import sequence_attention
+
+                attn = sequence_attention(
+                    q, k, v, self.mesh, seq_axis=self.seq_axis, causal=True,
+                    strategy=cfg.sp_strategy,
+                )
+            elif cfg.attn_impl == "flash":
+                from ..ops.flash_attention import flash_attention_grad
+
+                # differentiable wrapper: kernel forward, recompute backward
+                attn = flash_attention_grad(q, k, v, True)
+            else:
+                attn = reference_attention(q, k, v, causal=True)
+            attn = attn.reshape(B, T, D)
         x = x + self._dense(D, "attn_out")(attn)
         h = nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x)
         h = self._dense(cfg.d_ff, "mlp_up")(h)
@@ -432,7 +451,15 @@ class SlotModel:
     occupant's token stream is bit-identical to the seed ``generate:<N>``
     one-shot path and to the unslotted streaming path.
 
-    * ``init_cache()`` — zeroed (slots, max_seq, ...) page pytree;
+    * ``init_cache()`` — zeroed page pytree: per layer a K and a V leaf
+      of shape ``(slots, max_seq, d_model)`` in the model dtype, plus the
+      per-slot ``(slots,)`` write positions.  The leaf is LANE-DENSE on
+      purpose: with all heads side by side in the minor dim a row is
+      whole 128-lane tiles and the TPU keeps the leaf in its logical
+      order, so a step's row scatter lands in place and the decode
+      contraction reads the leaf as it lies (:func:`kv_attend_write`);
+      a ``(…, heads, head_dim)`` leaf is laid out sequence-minor on the
+      device and every row write re-lays the whole cache out;
     * ``reset_slot(cache, slot)`` — zero ONE slot's pages + positions (a
       join touches only its own slot; jitted once, slot is traced);
     * ``prefill_chunk(params, cache, toks (1,n), slot)`` — slice the
@@ -472,9 +499,10 @@ class SlotModel:
         self._temperature = temperature
         self._key0 = jax.random.PRNGKey(seed)
         # mesh-sharded decode (continuous batching past one chip): the
-        # per-slot KV pages shard on HEADS along tp — pages are
-        # (slots, max_seq, H, D/H), so dim 2 scatters and every device
-        # holds all slots' pages for its head shard; the slot batch
+        # per-slot KV pages shard on HEADS along tp — a leaf is (slots,
+        # max_seq, d_model) with heads contiguous in d_model, so dim 2
+        # splits at head boundaries when tp divides the heads and every
+        # device holds all slots' pages for its head shard; the slot batch
         # itself stays replicated (the engine's tok/gen/active vectors
         # are tiny).  GSPMD propagates the placements through the jitted
         # step, so the shape-stable bucket contract is unchanged.
@@ -494,9 +522,9 @@ class SlotModel:
             tp = mesh.shape.get("tp", 1)
 
             def page_spec(shape):
-                # shard the heads dim when it exists and divides; the
+                # shard d_model by whole heads when tp divides them; the
                 # per-slot index/step vectors replicate
-                if len(shape) >= 3 and shape[2] % tp == 0 and tp > 1:
+                if len(shape) >= 3 and cfg.n_heads % tp == 0 and tp > 1:
                     return NamedSharding(mesh, P(None, None, "tp"))
                 return NamedSharding(mesh, P())
 
