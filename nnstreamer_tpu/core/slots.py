@@ -42,7 +42,9 @@ import threading
 import time
 from collections import OrderedDict
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (
+    Any, Callable, Dict, List, Optional, Protocol, Tuple, runtime_checkable,
+)
 
 from .continuity import (
     GOAWAY_META, PREFIX_GRAIN, RESUME_META, prefix_digests, prompt_digest,
@@ -345,6 +347,62 @@ class GenStream:
         return self.state in DONE_STATES
 
 
+@runtime_checkable
+class SlotModelProtocol(Protocol):
+    """What :class:`SlotEngine` calls on a slot model, written down once.
+    ``models.transformer.SlotModel`` (dense decoder), ``models.hybrid_lm.
+    HybridSlotModel`` (Mamba-2 / attention / routed experts) and
+    :class:`SimSlotModel` satisfy it; the engine never learns which family
+    it drives.
+
+    * ``slots`` — the fixed width of the slot batch;
+    * ``init_cache()`` — the zeroed per-slot state, OPAQUE to the engine
+      (K/V rows by position, recurrent state without a position axis, a
+      counter: the model's own business);
+    * ``reset_slot(cache, slot) -> cache`` — zero ONE slot's state;
+    * ``prefill_fn(n)(params, cache, toks (1, n), slot) -> (cache, logits
+      (1, V))`` — one chunk of one slot's prompt, from the state the
+      slot's previous chunk left;
+    * ``pick_first(logits) -> (1,)`` — token 1;
+    * ``decode_fn(k)(params, cache, tok, gen, active) -> (cache, tok,
+      gen, toks (slots, k)[, counts])`` — ``k`` tokens for every active
+      slot; a row with ``active == 0`` keeps its token, its count and its
+      state.  ``counts``, where ``counter_names`` is not empty, is one
+      integer per name, summed since the last dispatch took them: the
+      engine adds them to always-on counters of those names in
+      :meth:`SlotEngine.snapshot`, on the read-back it makes anyway;
+    * ``decode_compiles`` / ``prefill_compiles`` — trace counts (the
+      shape-stability contract is observable);
+    * ``place_params(params)`` — stage a parameter tree where the model
+      runs;
+    * ``supports_prefix`` with ``export_prefix`` / ``attach_prefix`` —
+      whether a prefix of a slot's state can be cut out by position (the
+      shared-prefix pool); a model that says no is refused the pool.
+    """
+
+    slots: int
+    decode_compiles: int
+    prefill_compiles: int
+    counter_names: Tuple[str, ...]
+    supports_prefix: bool
+
+    def init_cache(self) -> Any: ...
+
+    def reset_slot(self, cache, slot) -> Any: ...
+
+    def prefill_fn(self, n: int) -> Callable[..., Any]: ...
+
+    def pick_first(self, logits) -> Any: ...
+
+    def decode_fn(self, k: int) -> Callable[..., Any]: ...
+
+    def place_params(self, params) -> Any: ...
+
+    def export_prefix(self, cache, slot, start: int, stop: int) -> Any: ...
+
+    def attach_prefix(self, cache, slot, pages_list, n: int) -> Any: ...
+
+
 class SimSlotModel:
     """Deterministic SIMULATED slot model (the async-sim discipline,
     PR-6): duck-types ``models.transformer.SlotModel`` but replaces the
@@ -365,6 +423,9 @@ class SimSlotModel:
     position counter that asserts slot isolation (a write to slot i can
     never touch slot j by construction, and tests pin the counters).
     """
+
+    counter_names = ()
+    supports_prefix = True
 
     def __init__(self, slots: int, vocab: int = 997,
                  step_base_ms: float = 1.0, step_per_slot_ms: float = 0.05,
@@ -405,6 +466,9 @@ class SimSlotModel:
         if kind not in ("oom", "lost"):
             raise ValueError(f"fail_next({kind!r}): want oom|lost")
         self._pending_fault = kind
+
+    def place_params(self, params):
+        return params  # no device: the oracle holds no parameters
 
     def init_cache(self):
         np = self._np
@@ -552,6 +616,10 @@ class SlotEngine:
         # runs would see different chunk boundaries (different XLA
         # programs / float reduction orders) and bit-exactness breaks.
         self.prefix = prefix_cache
+        if prefix_cache is not None and not model.supports_prefix:
+            raise ValueError(
+                "prefix cache: this model's slot state cannot be cut by "
+                "position (a recurrent state has no position axis)")
         if prefix_cache is not None and (
                 prefix_cache.grain % self.prefill_chunk != 0):
             raise ValueError(
@@ -633,6 +701,10 @@ class SlotEngine:
         self.first_tokens = 0
         self.lane_wait_s = 0.0
         self.pump_host_s = 0.0
+        # the model's own counters (``counter_names``), as its decode
+        # dispatches hand them over
+        self.model_counts: Dict[str, int] = dict.fromkeys(
+            model.counter_names, 0)
         # this turn's device and idle seconds (pump-thread-private)
         self._dev_s = 0.0
         self._idle_s = 0.0
@@ -859,6 +931,7 @@ class SlotEngine:
                 "gen_first_tokens": self.first_tokens,
                 "gen_lane_wait_s": self.lane_wait_s,
                 "gen_pump_host_s": self.pump_host_s,
+                **self.model_counts,
             }
         # armed cache only: with the cache off the snapshot is
         # byte-identical to the pre-prefix engine (zero behavior change)
@@ -1319,7 +1392,9 @@ class SlotEngine:
         with span("nns.slots.decode", k=k, active=len(decoding)):
             try:
                 with span("nns.slots.decode.dispatch"):
-                    self._cache, tok, gen, toks = self._device_step(
+                    # a model with counters of its own hands them over
+                    # as a fifth result
+                    self._cache, tok, gen, toks, *counts = self._device_step(
                         self._decode_fn(k),
                         self.params, self._cache, self._tok_vec,
                         self._gen_vec, active,
@@ -1343,10 +1418,13 @@ class SlotEngine:
                 # and prefill writes per-slot entries in place
                 self._tok_vec = np.array(tok, dtype=np.int32)
                 self._gen_vec = np.array(gen, dtype=np.int32)
+                counts = np.asarray(counts[0]).tolist() if counts else ()
             now = self.clock()
         with span("nns.slots.emit"), self._lock:
             self.decode_steps += 1
             self.tokens_total += k * len(decoding)
+            for name, n in zip(self.model.counter_names, counts):
+                self.model_counts[name] += n
             a = 0.2  # EWMA horizon ~ last 5 scans
             self.tokens_per_step = (
                 len(decoding) if self.decode_steps == 1

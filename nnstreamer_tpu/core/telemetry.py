@@ -247,6 +247,12 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "nns.gen.first_tokens": ("counter", "streams that reached their first token (resumed streams excluded)"),
     "nns.gen.lane_wait_seconds": ("counter", "seconds from join to first token less the stream's own reset and prefill steps, summed"),
     "nns.gen.pump_host_seconds": ("counter", "pump seconds inside a turn outside device steps, read-backs and the idle wait"),
+    "nns.gen.moe_local": ("counter", "token-expert choices that fell on an expert this server holds"),
+    "nns.gen.moe_expert_reads": ("counter", "distinct held experts with at least one token, summed over (layer, step) pairs"),
+    "nns.gen.moe_max_load": ("counter", "tokens on the busiest held expert, summed over (layer, step) pairs"),
+    "nns.gen.moe_layer_steps": ("counter", "(expert layer, step) pairs counted: decode steps and prefill chunks"),
+    "nns.gen.moe_prefill_local": ("counter", "the prefill chunks' part of moe_local"),
+    "nns.gen.moe_prefill_reads": ("counter", "the prefill chunks' part of moe_expert_reads"),
 
     # -- memory-pressure watermarks (core/liveness.py monitor) -------------
     "nns.mem.bytes_in_use": ("gauge", "device HBM bytes in use (most-loaded chip)"),
@@ -477,6 +483,14 @@ HEALTH_KEY_METRICS: Dict[str, str] = {
     "gen_first_tokens": "nns.gen.first_tokens",
     "gen_lane_wait_s": "nns.gen.lane_wait_seconds",
     "gen_pump_host_s": "nns.gen.pump_host_seconds",
+    # routed-expert counters a slot model hands over with its decode
+    # read-back (models/hybrid_lm.py COUNTER_NAMES; absent for a dense model)
+    "gen_moe_local": "nns.gen.moe_local",
+    "gen_moe_expert_reads": "nns.gen.moe_expert_reads",
+    "gen_moe_max_load": "nns.gen.moe_max_load",
+    "gen_moe_layer_steps": "nns.gen.moe_layer_steps",
+    "gen_moe_prefill_local": "nns.gen.moe_prefill_local",
+    "gen_moe_prefill_reads": "nns.gen.moe_prefill_reads",
     # memory-pressure watermarks (serversrc health row)
     "mem_bytes_in_use": "nns.mem.bytes_in_use",
     "mem_bytes_limit": "nns.mem.bytes_limit",

@@ -79,7 +79,9 @@ class TensorGenerator(Element):
         "custom": Property(
             str, "",
             "zoo-transformer dialect: vocab:N,d_model:N,heads:N,layers:N,"
-            "d_ff:N,seq:N,seed:N[,temperature:F,top_k:N,gen_seed:N]",
+            "d_ff:N,seq:N,seed:N[,temperature:F,top_k:N,gen_seed:N]; "
+            "arch:nemotron_h selects the hybrid family (layers:<pattern "
+            "of M, E, *> and its widths: Documentation/examples.md)",
         ),
         "max-new": Property(int, 32, "tokens to generate per prompt"),
         "chunk": Property(int, 8, "tokens per streamed chunk frame"),
@@ -206,7 +208,8 @@ class TensorGenerator(Element):
             if ":" in part:
                 k, _, v = part.partition(":")
                 props[k.strip()] = v.strip()
-        props.pop("arch", None)  # tolerated for zoo-dialect symmetry
+        # arch: names the model family (_zoo_family); absent or unknown,
+        # the dense transformer is built exactly as before
         self._resize_target = 0
         slots = int(self.props["slots"])
         if slots < 0:
@@ -270,13 +273,27 @@ class TensorGenerator(Element):
                     "sim", vocab=int(props.get("vocab", "997")),
                     max_new=max_new)
             else:
+                # the family's name and EVERY field of its config: a
+                # stream never resumes across families or expert shares
+                family, module = self._zoo_family(props)
+                try:
+                    fields = module.resume_fields(props)
+                except (KeyError, ValueError) as e:
+                    raise ElementError(
+                        f"{self.name}: custom={self.props['custom']!r}: "
+                        f"{e}") from None
                 self._resume_sig = resume_signature(
-                    "zoo", max_new=max_new, **{
-                        k: props.get(k, "")
-                        for k in ("vocab", "d_model", "heads", "layers",
-                                  "d_ff", "seq", "seed", "gen_seed",
-                                  "temperature", "top_k")
-                    })
+                    family, max_new=max_new, **fields)
+                if family != "zoo" and mesh is not None:
+                    raise ElementError(
+                        f"{self.name}: mesh= is not served for arch:"
+                        f"{family} (the experts' ep axis and its exchange "
+                        "do not exist yet: one chip holds one share)")
+                if family != "zoo" and self.props["prefix-cache"] == "on":
+                    raise ElementError(
+                        f"{self.name}: prefix-cache=on is not served for "
+                        f"arch:{family}: a recurrent state cannot be cut "
+                        "by position")
             if sim and mesh is not None:
                 raise ElementError(
                     f"{self.name}: mesh= needs the real transformer "
@@ -337,6 +354,10 @@ class TensorGenerator(Element):
                 f"{self.name}: custom sim: needs slots >= 1 (the sim "
                 "proxy drives the slot engine; slots=1 is the "
                 "request-serial baseline)")
+        if self._zoo_family(props)[0] != "zoo":
+            raise ElementError(
+                f"{self.name}: arch:{props['arch']} needs slots >= 1 (its "
+                "state lives in the slot engine)")
         prefill, decode_chunk, params, self._max_seq = build_stream(
             props, device=self._serving_device())
         self._prefill = jax.jit(prefill)
@@ -650,14 +671,27 @@ class TensorGenerator(Element):
 
         return surviving_device(pick_device(["auto"]), self._mesh_exclude)
 
-    def _build_zoo_slot_model(self, props, slots: int, mesh):
-        """(model, params, max_seq) for the real transformer — the one
-        build every path shares (start, resize, device-loss rebuild):
-        params AND KV cache land on the mesh, or unsharded on
-        :meth:`_serving_device`, before the first step."""
-        from ..models.transformer import build_slot_stream
+    @staticmethod
+    def _zoo_family(props):
+        """(family name, module) of the model the ``custom=`` dialect
+        names: ``arch:nemotron_h`` is the hybrid family
+        (models/hybrid_lm.py); anything else is the dense transformer.
+        The ONE place a family is chosen; a module gives
+        ``build_slot_stream`` and ``resume_fields``."""
+        if props.get("arch") == "nemotron_h":
+            from ..models import hybrid_lm
 
-        return build_slot_stream(
+            return hybrid_lm.FAMILY, hybrid_lm
+        from ..models import transformer
+
+        return "zoo", transformer
+
+    def _build_zoo_slot_model(self, props, slots: int, mesh):
+        """(model, params, max_seq) for a real model — the one build
+        every path shares (start, resize, device-loss rebuild): params
+        AND slot state land on the mesh, or unsharded on
+        :meth:`_serving_device`, before the first step."""
+        return self._zoo_family(props)[1].build_slot_stream(
             props, slots, mesh=mesh,
             device=None if mesh is not None else self._serving_device())
 

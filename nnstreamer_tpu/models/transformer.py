@@ -48,7 +48,7 @@ class TransformerConfig:
     quant: bool = False
 
 
-def kv_attend_write(ck, cv, q, k, v, pos, n_heads):
+def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None):
     """The ONE decode-cache step every generation path shares: attend
     over the cache leaves as they lie plus the new rows, then write the
     new rows into the leaves.
@@ -60,6 +60,13 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads):
     ``pos[b]`` older positions, its new rows are positions ``pos[b] ..
     pos[b]+T-1``, and query ``i`` sees the older ones and new rows
     ``<= i``.  Returns ``(ck, cv, attn (B, T, d_model))``.
+
+    ``n_kv_heads`` (grouped-query attention): the leaves, ``k`` and ``v``
+    are ``n_kv_heads x head_dim`` wide, read from the leaves' own minor
+    dim, and the ``n_heads / n_kv_heads`` queries of one group share a KV
+    head (:func:`_gqa_scores`; no KV row is repeated).  Left out (or
+    equal to ``n_heads``) the function is the multi-head one it was,
+    operation for operation (:func:`_mha_scores`).
 
     Reading the leaves BEFORE the write is what keeps the step to one
     read of K and of V: the leaf the contraction reads is the loop's
@@ -94,6 +101,38 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads):
         preferred_element_type=jnp.float32,
     )
     scale = 1.0 / np.sqrt(Dh)
+    if (n_kv_heads or H) != H:
+        s_old, mix_old, s_new, mix_new = _gqa_scores(
+            ck, cv, q, k, v, H, n_kv_heads, dot)
+    else:
+        s_old, mix_old, s_new, mix_new = _mha_scores(ck, cv, q, k, v, H, dot)
+    older = jnp.arange(S)[None, :] < pos[:, None]  # (B, S)
+    s_old = jnp.where(older[:, None, None], s_old * scale, -1e30)
+    s_new = jnp.where(jnp.tri(T, dtype=bool), s_new * scale, -1e30)
+    top = jnp.maximum(s_old.max(axis=-1), s_new.max(axis=-1))[..., None]
+    e_old, e_new = jnp.exp(s_old - top), jnp.exp(s_new - top)
+    total = e_old.sum(axis=-1) + e_new.sum(axis=-1)  # (B, H, T)
+    mix = mix_old(e_old) + mix_new(e_new)
+    attn = jnp.moveaxis(mix / total[..., None], 1, 2)  # (B, T, H, Dh)
+
+    slot = jnp.arange(B)[:, None]
+    rows = pos[:, None] + jnp.arange(T)[None, :]  # (B, T)
+
+    def write(c, new):
+        return c.at[slot, rows].set(
+            new.astype(c.dtype), mode="drop", indices_are_sorted=True,
+            unique_indices=True,
+        )
+
+    return write(ck, k), write(cv, v), attn.reshape(B, T, D).astype(q.dtype)
+
+
+def _mha_scores(ck, cv, q, k, v, H, dot):
+    """Multi-head scores and mixes of :func:`kv_attend_write` (every query
+    head has a KV head of its own): ``(s_old (B, H, T, S), mix_old, s_new
+    (B, H, T, T), mix_new)``, unscaled and unmasked."""
+    B, T, D = q.shape
+    S, Dh = ck.shape[1], D // H
     q4, k4, v4 = (t.reshape(B, T, H, Dh) for t in (q, k, v))
     if T == 1:
         own = jnp.eye(H, dtype=bool)
@@ -111,27 +150,47 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads):
         def mix_old(e):  # (B, H, T, S) -> (B, H, T, Dh)
             return dot("bhts,bshd->bhtd", e, cv.reshape(B, S, H, Dh))
 
-    older = jnp.arange(S)[None, :] < pos[:, None]  # (B, S)
-    s_old = jnp.where(older[:, None, None], s_old * scale, -1e30)
-    s_new = jnp.where(
-        jnp.tri(T, dtype=bool), dot("bthd,buhd->bhtu", q4, k4) * scale, -1e30
-    )  # (B, H, T, T)
-    top = jnp.maximum(s_old.max(axis=-1), s_new.max(axis=-1))[..., None]
-    e_old, e_new = jnp.exp(s_old - top), jnp.exp(s_new - top)
-    total = e_old.sum(axis=-1) + e_new.sum(axis=-1)  # (B, H, T)
-    mix = mix_old(e_old) + dot("bhtu,buhd->bhtd", e_new, v4)
-    attn = jnp.moveaxis(mix / total[..., None], 1, 2)  # (B, T, H, Dh)
+    def mix_new(e):  # (B, H, T, T) -> (B, H, T, Dh)
+        return dot("bhtu,buhd->bhtd", e, v4)
 
-    slot = jnp.arange(B)[:, None]
-    rows = pos[:, None] + jnp.arange(T)[None, :]  # (B, T)
+    return s_old, mix_old, dot("bthd,buhd->bhtu", q4, k4), mix_new
 
-    def write(c, new):
-        return c.at[slot, rows].set(
-            new.astype(c.dtype), mode="drop", indices_are_sorted=True,
-            unique_indices=True,
-        )
 
-    return write(ck, k), write(cv, v), attn.reshape(B, T, D).astype(q.dtype)
+def _gqa_scores(ck, cv, q, k, v, H, J, dot):
+    """Grouped-query twin of :func:`_mha_scores`: ``H`` query heads share
+    ``J`` KV heads, ``G = H / J`` to a group.  ``T == 1`` lays ``q``
+    block-diagonal over the leaf's own ``J x head_dim`` minor dim (idle
+    products J x the MACs, no copy of a leaf); ``T > 1`` makes the group a
+    batch dimension of the contractions."""
+    B, T, D = q.shape
+    S, Dh, G = ck.shape[1], D // H, H // J
+    q5 = q.reshape(B, T, J, G, Dh)
+    k4, v4 = (t.reshape(B, T, J, Dh) for t in (k, v))
+    if T == 1:
+        own = jnp.arange(H)[:, None] // G == jnp.arange(J)[None, :]  # (H, J)
+        q_diag = jnp.where(
+            own.T[None, :, None, :],
+            jnp.swapaxes(q.reshape(B, H, Dh), 1, 2)[:, None], 0,
+        ).reshape(B, J * Dh, H)
+        s_old = dot("bsd,bdh->bhs", ck, q_diag)[:, :, None]
+
+        def mix_old(e):  # (B, H, 1, S) -> (B, H, 1, Dh)
+            every = dot("bhs,bsd->bhd", e[:, :, 0], cv).reshape(B, H, J, Dh)
+            return jnp.where(own[None, :, :, None], every, 0).sum(axis=2)[:, :, None]
+    else:
+        ck4, cv4 = (t.reshape(B, S, J, Dh) for t in (ck, cv))
+        s_old = dot("btjgd,bsjd->bjgts", q5, ck4).reshape(B, H, T, S)
+
+        def mix_old(e):  # (B, H, T, S) -> (B, H, T, Dh)
+            return dot("bjgts,bsjd->bjgtd", e.reshape(B, J, G, T, S),
+                       cv4).reshape(B, H, T, Dh)
+
+    def mix_new(e):  # (B, H, T, T) -> (B, H, T, Dh)
+        return dot("bjgtu,bujd->bjgtd", e.reshape(B, J, G, T, T),
+                   v4).reshape(B, H, T, Dh)
+
+    s_new = dot("btjgd,bujd->bjgtu", q5, k4).reshape(B, H, T, T)
+    return s_old, mix_old, s_new, mix_new
 
 
 class Block(nn.Module):
@@ -279,6 +338,22 @@ def _cfg_from_props(props: Dict[str, str]) -> TransformerConfig:
     )
 
 
+def config_resume_fields(cfg, props: Dict[str, str]) -> Dict[str, Any]:
+    """Everything that determines the token sequence, for any family:
+    EVERY field of its config dataclass and the seeds and sampling rule
+    (mesh, device and slot width shape placement and latency, never
+    tokens)."""
+    fields = dataclasses.asdict(cfg)
+    fields["dtype"] = jnp.dtype(fields["dtype"]).name
+    for key in ("seed", "gen_seed", "temperature", "top_k"):
+        fields["sampling_" + key] = props.get(key, "0")
+    return fields
+
+
+def resume_fields(props: Dict[str, str]) -> Dict[str, Any]:
+    return config_resume_fields(_cfg_from_props(props), props)
+
+
 def make_generate(
     cfg: TransformerConfig,
     max_new: int,
@@ -345,6 +420,23 @@ def _make_pick(temperature: float, top_k: int):
         return jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
 
     return pick
+
+
+def pick_slots(pick, key0, temperature, lg, gen):
+    """The slotted pick every slot model shares: ``lg`` (S, V), ``gen``
+    (S,) -> (S,).  Greedy at ``temperature <= 0``; else a per-slot key
+    folded at the slot's OWN generated count — the same fold the
+    unslotted scan applies at global step t (vmap of a key-batched draw
+    is bit-equal to the per-row loop)."""
+    if temperature <= 0.0:
+        return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+    keys = jax.vmap(lambda g: jax.random.fold_in(key0, g))(gen)
+    keys = jnp.where((gen == 0)[:, None], key0[None], keys)
+
+    def one(l, k):  # (V,), key -> ()
+        return pick(l[None], k)[0]
+
+    return jax.vmap(one)(lg, keys).astype(jnp.int32)
 
 
 def make_stream_generate(
@@ -485,6 +577,11 @@ class SlotModel:
     device), so every prefill and decode step runs there — nothing is
     left for jit to place by default.
     """
+
+    #: no counters of its own ride the decode read-back
+    counter_names = ()
+    #: K/V rows below a position are immutable: a prefix can be cut out
+    supports_prefix = True
 
     def __init__(self, cfg: TransformerConfig, slots: int,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
@@ -673,20 +770,7 @@ class SlotModel:
 
     # -- decode (whole slot batch, k tokens per dispatch) -------------------
     def _pick_slots(self, lg, gen):  # (S, V), (S,) -> (S,)
-        if self._temperature <= 0.0:
-            return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        # per-slot key folded at the slot's OWN generated count — the
-        # same fold the unslotted scan applies at global step t (vmap of
-        # a key-batched draw is bit-equal to the per-row loop)
-        key0 = self._key0
-        keys = jax.vmap(lambda g: jax.random.fold_in(key0, g))(gen)
-        keys = jnp.where((gen == 0)[:, None], key0[None], keys)
-        pick = self._pick
-
-        def one(l, k):  # (V,), key -> ()
-            return pick(l[None], k)[0]
-
-        return jax.vmap(one)(lg, keys).astype(jnp.int32)
+        return pick_slots(self._pick, self._key0, self._temperature, lg, gen)
 
     def _decode_scan(self, k, params, cache, tok, gen, active):
         def step(carry, _i):

@@ -1,0 +1,533 @@
+"""The hybrid decoder family (models/hybrid_lm.py: Mamba-2, grouped-query
+attention and routed experts chosen by a pattern string) on the slotted
+generation path, against the plain float32 reference
+(benchmark/configs/ref_nemotron_h.py), at a tiny size with every layer kind.
+
+Oracles: the reference's full forward over one sequence (no cache, no slots,
+no chunking) for chunked prefill and slotted decode; the step recurrence for
+the chunked scan; a stream served alone for a stream served under churn; the
+uncut reference layer for the sum of two expert shares; plain attention for
+the grouped-query cache step.
+"""
+
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.configs import ref_nemotron_h as ref
+from nnstreamer_tpu.core.buffer import TensorFrame
+from nnstreamer_tpu.core.continuity import resume_signature
+from nnstreamer_tpu.core.slots import (
+    PrefixCache, SimSlotModel, SlotEngine, SlotModelProtocol,
+)
+from nnstreamer_tpu.models import hybrid_lm as H
+from nnstreamer_tpu.models.transformer import (
+    build_slot_stream as build_dense,
+    kv_attend_write,
+    resume_fields as dense_fields,
+)
+from nnstreamer_tpu.pipeline import parse_pipeline
+
+VOCAB, SEED = 97, 5
+#: the reference's configuration, under the published key names
+REF = {
+    "hybrid_override_pattern": "MEM*EME", "hidden_size": 64, "vocab_size": VOCAB,
+    "mamba_num_heads": 4, "mamba_head_dim": 16, "n_groups": 2, "ssm_state_size": 16,
+    "conv_kernel": 4, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "router_experts": 8, "n_routed_experts": 8, "expert_offset": 0,
+    "num_experts_per_tok": 2, "moe_intermediate_size": 32,
+    "moe_shared_expert_intermediate_size": 64, "routed_scaling_factor": 2.5,
+    "norm_eps": 1e-5, "time_step_min": 0.001, "time_step_max": 0.1,
+    "time_step_floor": 1e-4,
+}
+
+
+def props(**over):
+    """The same configuration in the generator's ``custom=`` dialect."""
+    p = {
+        "arch": "nemotron_h", "layers": REF["hybrid_override_pattern"],
+        "vocab": VOCAB, "d_model": 64, "ssm_heads": 4, "ssm_head_dim": 16,
+        "ssm_groups": 2, "ssm_state": 16, "conv": 4, "scan_chunk": 8, "heads": 4,
+        "kv_heads": 2, "head_dim": 16, "experts": 8, "experts_held": 8,
+        "expert_offset": 0, "experts_per_tok": 2, "d_expert": 32, "d_shared": 64,
+        "routed_scale": 2.5, "eps": 1e-5, "seq": 96, "dtype": "float32", "seed": SEED,
+    }
+    p.update(over)
+    return {k: str(v) for k, v in p.items()}
+
+
+def custom(**over):
+    return ",".join(f"{k}:{v}" for k, v in props(**over).items())
+
+
+@pytest.fixture(scope="module")
+def served():
+    model, params, max_seq = H.build_slot_stream(props(), 4)
+    return model, params, max_seq
+
+
+def flat(tree):
+    return {jax.tree_util.keystr(k): np.asarray(v)
+            for k, v in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+def test_the_reference_makes_the_programs_weights_without_the_program(served):
+    _, params, _ = served
+    for i, kind in enumerate(REF["hybrid_override_pattern"]):
+        mine, theirs = flat(ref.part(REF, SEED, i)), flat(params["blocks"][i])
+        assert mine.keys() == theirs.keys()
+        for k in mine:
+            a, b = mine[k], theirs[k]
+            if kind == "E" and "experts" in k:   # the program pads an expert's width
+                b = b[:, :, :a.shape[2]] if "up" in k else b[:, :a.shape[1]]
+            assert np.array_equal(a, b), (i, k)
+    for name in ("embed", "norm_f", "lm_head"):
+        mine, theirs = flat(ref.part(REF, SEED, name)), flat(params[name])
+        assert all(np.array_equal(mine[k], theirs[k]) for k in mine), name
+
+
+def test_served_weights_are_cast_on_the_device_and_small_ones_stay_float32():
+    cfg = H.cfg_from_props(props(dtype="bfloat16"))
+    params = H.init_params(cfg, SEED)
+    for path, leaf in flat(params).items():
+        keep = any(n in path for n in ("scale", "A_log", "'D'", "dt_bias", "router"))
+        assert leaf.dtype == (np.float32 if keep else jnp.bfloat16), path
+    # the padding of an expert's width holds zeros
+    up = np.asarray(params["blocks"][1]["mixer"]["experts"]["up"], np.float32)
+    assert up.shape == (8, 64, 128) and not up[:, :, 32:].any() and up[:, :, :32].any()
+
+
+def test_two_seeds_share_one_init_program_per_layer_kind():
+    cfg = H.cfg_from_props(props())
+    a, b = H.init_params(cfg, 1), H.init_params(cfg, 2)
+    assert not np.array_equal(a["blocks"][0]["mixer"]["in_proj"]["kernel"],
+                              b["blocks"][0]["mixer"]["in_proj"]["kernel"])
+    # blocks 0, 2, 5 are all 'M': one program, three different keys
+    m = [np.asarray(a["blocks"][i]["mixer"]["A_log"]) for i in (0, 2, 5)]
+    assert not np.array_equal(m[0], m[1]) and not np.array_equal(m[1], m[2])
+
+
+# ---------------------------------------------------------------------------
+# logits: chunked prefill, then slotted decode, against the full forward
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("n_prompt,chunk", [(16, 8), (21, 8), (12, 6), (23, 6)])
+def test_chunked_prefill_then_slotted_decode_match_the_full_forward(
+        served, rng, n_prompt, chunk):
+    model, params, _ = served
+    steps, slot = 5, 2
+    seq = rng.integers(0, VOCAB, (n_prompt + steps,)).astype(np.int32)
+    want = np.asarray(ref.forward(ref.make_params(REF, SEED), seq, REF))
+    cache = model.reset_slot(model.init_cache(), np.int32(slot))
+    for a in range(0, n_prompt, chunk):
+        piece = seq[None, a:min(a + chunk, n_prompt)]
+        cache, logits = model.prefill_fn(piece.shape[1])(
+            params, cache, piece, np.int32(slot))
+        # every chunk's last position, so state crosses chunk boundaries
+        at = a + piece.shape[1] - 1
+        np.testing.assert_allclose(np.asarray(logits)[0], want[at], atol=1e-4)
+    active = np.zeros(4, np.int32)
+    active[slot] = 1
+    step = jax.jit(model.step_logits)
+    for j in range(steps):
+        tok = np.zeros(4, np.int32)
+        tok[slot] = seq[n_prompt + j]
+        cache, logits = step(params, cache, tok, active)
+        np.testing.assert_allclose(
+            np.asarray(logits)[slot], want[n_prompt + j], atol=1e-4)
+    assert int(cache["pos"][slot]) == n_prompt + steps
+    assert not np.asarray(cache["pos"])[[0, 1, 3]].any()
+
+
+@pytest.mark.parametrize("T,chunk", [(16, 8), (19, 8), (5, 8), (24, 6), (25, 6)])
+def test_chunked_scan_matches_the_step_recurrence(rng, T, chunk):
+    B, G, Hg, P, N = 2, 2, 2, 8, 16
+    u = rng.standard_normal((B, T, G, Hg, P)).astype(np.float32)
+    dt = rng.uniform(0.001, 0.5, (B, T, G, Hg)).astype(np.float32)
+    a = -rng.uniform(1.0, 16.0, (G, Hg)).astype(np.float32)
+    bm = rng.standard_normal((B, T, G, N)).astype(np.float32)
+    cm = rng.standard_normal((B, T, G, N)).astype(np.float32)
+    h0 = rng.standard_normal((B, G, Hg, P, N)).astype(np.float32)
+    h, ys = jnp.asarray(h0), []
+    for t in range(T):
+        h, y = H.ssm_step(h, u[:, t], dt[:, t], a, bm[:, t], cm[:, t])
+        ys.append(y)
+    h2, y2 = H.ssm_chunked(jnp.asarray(h0), u, dt, a, bm, cm, chunk)
+    np.testing.assert_allclose(np.asarray(y2), np.stack(ys, 1), atol=2e-4, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(h2), np.asarray(h), atol=2e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# slots
+# ---------------------------------------------------------------------------
+def _engine(model, params, max_seq, **kw):
+    eng = SlotEngine(model, params, max_seq=max_seq, chunk=4, prefill_chunk=8,
+                     name="hybrid", **kw)
+    eng.start()
+    return eng
+
+
+def _serve(eng, prompts, max_new, gap_s=0.0, timeout=120.0):
+    """Tokens of every prompt, by submission order."""
+    for p in prompts:
+        eng.submit(TensorFrame([p], meta={}), p, max_new=max_new, chunk=4)
+        time.sleep(gap_s)
+    frames, deadline = [], time.monotonic() + timeout
+    while sum(1 for f in frames if f.meta["final"]) < len(prompts):
+        assert time.monotonic() < deadline, "engine drain timed out"
+        frames += [f for _pad, f in eng.pop_ready()]
+        eng.wait_progress(0.02)
+    out = {}
+    for f in sorted(frames, key=lambda f: (f.meta["stream_seq"], f.meta["chunk_index"])):
+        out.setdefault(f.meta["stream_seq"], []).extend(
+            np.asarray(f.tensors[0]).reshape(-1).tolist() if f.tensors else [])
+    return [np.asarray(out[k], np.int32) for k in sorted(out)]
+
+
+def test_streams_under_churn_get_the_tokens_they_get_alone(rng):
+    model, params, max_seq = H.build_slot_stream(props(), 4)
+    prompts = [rng.integers(0, VOCAB, (1, n)).astype(np.int32)
+               for n in (5, 17, 9, 24, 3, 12, 20, 8, 16, 7)]
+    lens = [6, 13, 9, 13, 6, 9, 13, 6, 9, 13]
+    eng = _engine(model, params, max_seq)
+    try:
+        alone = [_serve(eng, [p], n)[0] for p, n in zip(prompts, lens)]
+        buckets = model.decode_compiles
+        churn = []
+        for n in sorted(set(lens)):  # 10 streams through 4 slots, staggered joins
+            batch = [p for p, m in zip(prompts, lens) if m == n]
+            for got, p in zip(_serve(eng, batch, n, gap_s=0.03), batch):
+                churn.append((p, got))
+        for p, got in churn:
+            want = next(a for q, a in zip(prompts, alone) if q is p)
+            np.testing.assert_array_equal(got, want)
+        # join/leave churn compiles nothing: the buckets the lone streams made
+        assert model.decode_compiles == buckets <= 4
+        snap = eng.snapshot()
+        assert snap["gen_decode_compiles"] == buckets
+        # the routing counters are always on, and add up
+        assert snap["gen_moe_layer_steps"] > 0
+        assert 0 < snap["gen_moe_prefill_local"] < snap["gen_moe_local"]
+        assert snap["gen_moe_expert_reads"] <= 8 * snap["gen_moe_layer_steps"]
+        assert snap["gen_moe_max_load"] >= snap["gen_moe_layer_steps"]
+    finally:
+        eng.stop()
+    # and each stream is what the reference's full forward would pick
+    handle = ref.make_params(REF, SEED)
+    for p, got in list(zip(prompts, alone))[:3]:
+        seq = np.concatenate([p[0], got])
+        logits = np.asarray(ref.forward(handle, seq, REF))[p.shape[1] - 1:-1]
+        best = logits.max(-1)
+        assert np.all(best - logits[np.arange(len(got)), got] <= 1e-4)
+
+
+def test_a_reset_slot_is_zero_and_an_idle_slot_is_bit_equal_across_a_dispatch(served, rng):
+    model, params, _ = served
+    cache = model.init_cache()
+    for slot, n in ((1, 11), (3, 7)):
+        p = rng.integers(0, VOCAB, (1, n)).astype(np.int32)
+        cache, _ = model.prefill_fn(n)(params, cache, p, np.int32(slot))
+
+    def rows(cache, slot, filled=None):
+        """One slot's state; K/V rows only below ``filled`` (the row AT an
+        idle slot's frozen position is rewritten harmlessly by every step,
+        as in the dense model, and overwritten by its next real token)."""
+        out = [np.array(cache["pos"])[slot]]
+        for leaves in cache["layers"].values():
+            for name, leaf in leaves.items():
+                row = np.array(leaf)[slot]
+                out.append(row[:filled] if name in "kv" and filled is not None else row)
+        return out
+
+    idle_before = rows(cache, 3, filled=7)
+    assert any(r.any() for r in idle_before)
+    tok = rng.integers(0, VOCAB, (4,)).astype(np.int32)
+    cache, _tok, gen, toks, counts = model.decode_fn(3)(
+        params, cache, tok, np.zeros(4, np.int32), np.array([0, 1, 0, 0], np.int32))
+    # slot 3 holds state and did not decode: conv window, scan state, filled
+    # K/V rows and position come out bit-equal
+    for b, a in zip(idle_before, rows(cache, 3, filled=7)):
+        np.testing.assert_array_equal(b, a)
+    assert int(cache["pos"][1]) == 14 and int(gen[1]) == 3 and toks.shape == (4, 3)
+    # 3 steps and 2 prefill chunks, 3 expert layers each; handed over, then zero
+    assert np.asarray(counts).tolist()[3] == 15 and not np.asarray(cache["counts"]).any()
+    cache = model.reset_slot(cache, np.int32(3))
+    assert not any(r.any() for r in rows(cache, 3))
+    assert any(r.any() for r in rows(cache, 1))
+
+
+def test_an_idle_rows_token_routes_nowhere(served, rng):
+    """An idle slot's row computes (the batch is fixed) but touches no
+    expert and counts nothing."""
+    model, params, _ = served
+    x = jnp.asarray(rng.standard_normal((4, 1, 64)), jnp.float32)
+    p = params["blocks"][1]["mixer"]
+    _, all_live = H.moe_mix(p, x, model.cfg, jnp.ones(4, bool))
+    _, one_live = H.moe_mix(p, x, model.cfg, jnp.array([False, True, False, False]))
+    assert int(all_live[0]) == 8 and int(one_live[0]) == 2 and int(one_live[1]) == 2
+
+
+# ---------------------------------------------------------------------------
+# the expert share (model-configs guide, section 4)
+# ---------------------------------------------------------------------------
+def test_two_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(rng):
+    x = rng.standard_normal((2, 9, 64)).astype(np.float32)
+    p_ref = ref.part(REF, SEED, 1)["mixer"]
+    flat_x = jnp.asarray(x.reshape(18, 64))
+    want = np.asarray(ref.routed(flat_x, p_ref, REF) + ref.shared(flat_x, p_ref))
+    total, local = 0.0, 0
+    for offset in (0, 4):
+        cfg = H.cfg_from_props(props(experts_held=4, expert_offset=offset))
+        p = H.init_params(cfg, SEED)["blocks"][1]["mixer"]
+        out, counts = H.moe_mix(p, jnp.asarray(x), cfg)
+        total = total + np.asarray(out).reshape(18, 64)
+        local += int(counts[0])
+    total = total - np.asarray(ref.shared(flat_x, p_ref))    # computed by both shares
+    np.testing.assert_allclose(total, want, atol=1e-4)
+    assert local == 18 * 2          # every choice fell on exactly one share
+    # a share alone is NOT the layer (what the absent experts add is left out)
+    assert np.abs(np.asarray(out).reshape(18, 64) - want).max() > 1e-2
+
+
+def test_every_token_on_one_expert_and_nothing_is_dropped(rng):
+    cfg = H.cfg_from_props(props(experts_held=4, expert_offset=0))
+    p = H.init_params(cfg, SEED)["blocks"][1]["mixer"]
+    # the bias puts expert 2 (held) and expert 6 (absent) first for every token
+    bias = jnp.zeros((8,)).at[2].set(50.0).at[6].set(40.0)
+    p = {**p, "router": {**p["router"], "bias": bias}}
+    x = rng.standard_normal((4, 16, 64)).astype(np.float32)
+    out, counts = H.moe_mix(p, jnp.asarray(x), cfg)
+    assert np.asarray(counts).tolist() == [64, 1, 64, 1]   # 64 tokens, all on expert 2
+    ref_cfg = {**REF, "n_routed_experts": 4}
+    p_ref = jax.tree.map(jnp.asarray, ref.part(ref_cfg, SEED, 1)["mixer"])
+    p_ref["router"]["bias"] = bias
+    flat_x = jnp.asarray(x.reshape(64, 64))
+    want = ref.routed(flat_x, p_ref, ref_cfg) + ref.shared(flat_x, p_ref)
+    np.testing.assert_allclose(np.asarray(out).reshape(64, 64), np.asarray(want), atol=1e-4)
+
+
+@pytest.mark.parametrize("case", ["spread", "one_expert", "no_held_expert", "two_blocks"])
+def test_the_small_batch_kernel_matches_the_reference_loop_over_held_experts(rng, case):
+    """ops/expert_ffn.py in the Pallas interpreter (a TPU lowers it in
+    ``moe_mix``'s place for a small batch): the touched experts only, every
+    token through each, weighed by its gate; nothing dropped under skew, and
+    zeros where no token chose a held expert."""
+    from nnstreamer_tpu.ops.expert_ffn import touched_experts_ffn
+
+    cfg = H.cfg_from_props(props(experts_held=4, expert_offset=4))
+    p = H.init_params(cfg, SEED)["blocks"][1]["mixer"]
+    rows = 300 if case == "two_blocks" else 21  # past MAX_TOKENS: blocks of rows
+    bias = {"spread": p["router"]["bias"], "two_blocks": p["router"]["bias"],
+            "one_expert": jnp.zeros((8,)).at[5].set(50.0).at[1].set(40.0),
+            "no_held_expert": jnp.zeros((8,)).at[0].set(50.0).at[1].set(40.0)}[case]
+    p = {**p, "router": {**p["router"], "bias": bias}}
+    x = jnp.asarray(rng.standard_normal((rows, 64)).astype(np.float32))
+    ids, w = H.route(p, x, cfg)
+    held = (ids[:, :, None] - cfg.expert_offset) == jnp.arange(4)[None, None, :]
+    gates = jnp.sum(jnp.where(held, w[:, :, None], 0.0), axis=1)
+    got = touched_experts_ffn(x, gates, p["experts"]["up"], p["experts"]["down"],
+                              interpret=True)
+    ref_cfg = {**REF, "n_routed_experts": 4, "expert_offset": 4}
+    p_ref = jax.tree.map(jnp.asarray, ref.part(ref_cfg, SEED, 1)["mixer"])
+    p_ref["router"]["bias"] = bias
+    want = np.asarray(ref.routed(x, p_ref, ref_cfg))
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-4)
+    touched = {"one_expert": 1, "no_held_expert": 0}.get(case)
+    if touched is not None:
+        assert int(jnp.sum(jnp.any(gates != 0, axis=0))) == touched
+    if case == "no_held_expert":
+        assert not np.asarray(got).any()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip: the TPU compiler is installed
+    here, so the kernel's Mosaic lowering is checked at no chip time."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("tokens", [32, 128, 512])
+def test_the_small_batch_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, tokens):
+    """Interpret mode cannot see tiling or VMEM limits: compile the kernel for
+    the chip at the benchmark cell's shapes (32 slots a decode step, a
+    128-token prefill chunk; 64 held experts of 2688 x 1920 in bf16) and for
+    a chunk past one call's rows."""
+    from nnstreamer_tpu.ops.expert_ffn import touched_experts_ffn
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = touched_experts_ffn.lower(
+        arg((tokens, 2688), jnp.bfloat16), arg((tokens, 64), jnp.float32),
+        arg((64, 2688, 1920), jnp.bfloat16), arg((64, 1920, 2688), jnp.bfloat16),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the weights are streamed through VMEM, never copied whole in HBM
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_cells_decode_program_compiles_for_a_v5e_and_fits_its_memory(one_chip):
+    """The benchmark cell's decode scan (16 blocks at the published widths,
+    32 slots, 4096 positions) through the chip's compiler: the kernel stands
+    in every expert layer, XLA's grouped product in none, and parameters,
+    slot state and temporaries fit one chip's 16 GiB."""
+    cfg = H.HybridConfig(
+        pattern="MEMEM*EMEMEM*EME", vocab=65536, d_model=2688, ssm_heads=64,
+        ssm_head_dim=64, ssm_groups=8, ssm_state=128, n_heads=32, n_kv_heads=2,
+        head_dim=128, experts=128, experts_held=64, top_k=6, d_expert=1856,
+        d_shared=3712, max_seq=4096)
+    model = H.HybridSlotModel(cfg, 32, device=one_chip._device, donate=True)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+
+    def born(spec):
+        return jax.eval_shape(lambda: H._cast(
+            H._Tree(spec).init(jax.random.PRNGKey(0))["params"], cfg.dtype))
+
+    params = on_chip({
+        "embed": born((("embedding", ((cfg.vocab, cfg.d_model), jax.nn.initializers.zeros)),)),
+        "blocks": [born(H.block_spec(cfg, kind)) for kind in cfg.pattern],
+        "norm_f": born(H._norm(cfg.d_model)),
+        "lm_head": born(H._dense(cfg.d_model, cfg.vocab))})
+    cache = on_chip(jax.eval_shape(lambda: {
+        "pos": jnp.zeros((32,), jnp.int32),
+        "counts": jnp.zeros((len(H.COUNTER_NAMES),), jnp.int32),
+        "layers": {i: {n: jnp.zeros(*sd) for n, sd in leaves.items()}
+                   for i, leaves in model._layer_shapes().items()}}))
+    vec = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    compiled = model.decode_fn(8).lower(params, cache, vec, vec, vec).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 7
+    assert "ragged" not in text
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13 << 30
+    assert mem.temp_size_in_bytes < 256 << 20   # no whole-stack copy of the experts
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention through the one cache step
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("T", [1, 5])
+@pytest.mark.parametrize("H_J", [(4, 2), (8, 1), (4, 4)])
+def test_grouped_query_cache_step_matches_plain_attention(rng, T, H_J):
+    nh, nj = H_J
+    B, S, Dh = 3, 16, 8
+    pos = np.array([0, 4, 9], np.int32)
+    ck = rng.standard_normal((B, S, nj * Dh)).astype(np.float32)
+    cv = rng.standard_normal((B, S, nj * Dh)).astype(np.float32)
+    q = rng.standard_normal((B, T, nh * Dh)).astype(np.float32)
+    k = rng.standard_normal((B, T, nj * Dh)).astype(np.float32)
+    v = rng.standard_normal((B, T, nj * Dh)).astype(np.float32)
+    nk, nv, attn = kv_attend_write(
+        *map(jnp.asarray, (ck, cv, q, k, v, pos)), nh, n_kv_heads=nj)
+    for b in range(B):
+        n = pos[b]
+        keys = np.concatenate([ck[b, :n], k[b]]).reshape(n + T, nj, Dh)
+        vals = np.concatenate([cv[b, :n], v[b]]).reshape(n + T, nj, Dh)
+        keys, vals = np.repeat(keys, nh // nj, 1), np.repeat(vals, nh // nj, 1)
+        s = np.einsum("thd,shd->hts", q[b].reshape(T, nh, Dh), keys) / np.sqrt(Dh)
+        s = np.where(np.arange(n + T)[None, None] <= n + np.arange(T)[None, :, None], s, -1e30)
+        e = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("hts,shd->thd", e / e.sum(-1, keepdims=True), vals)
+        np.testing.assert_allclose(np.asarray(attn)[b], want.reshape(T, nh * Dh), atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(nk)[b, n:n + T], k[b])
+        np.testing.assert_array_equal(np.asarray(nv)[b, :n], cv[b, :n])
+
+
+# ---------------------------------------------------------------------------
+# the element: selection, refusals, the resume signature
+# ---------------------------------------------------------------------------
+def test_the_generator_serves_the_family_by_custom_alone(rng):
+    pipe = parse_pipeline(
+        f"appsrc name=src ! tensor_generator name=gen slots=2 custom={custom()} "
+        "max-new=6 chunk=3 prefill-chunk=8 ! tensor_sink name=out max-stored=64")
+    frames = []
+    pipe["out"].connect_new_data(frames.append)
+    pipe.start()
+    try:
+        prompt = rng.integers(0, VOCAB, (1, 13)).astype(np.int32)
+        pipe["src"].push(prompt)
+        deadline = time.monotonic() + 120
+        while not any(f.meta.get("final") for f in frames):
+            assert time.monotonic() < deadline
+            time.sleep(0.02)
+        health = pipe.health()["gen"]
+    finally:
+        pipe.stop()
+    got = np.concatenate([np.asarray(f.tensors[0]).reshape(-1) for f in frames if f.tensors])
+    seq = np.concatenate([prompt[0], got])
+    logits = np.asarray(ref.forward(ref.make_params(REF, SEED), seq, REF))[12:-1]
+    assert len(got) == 6 and np.all(logits.max(-1) - logits[np.arange(6), got] <= 1e-4)
+    for name in H.COUNTER_NAMES:   # always on: tracing is off here
+        assert health[name] > 0 or name.startswith("gen_moe_prefill"), name
+
+
+@pytest.mark.parametrize("line,why", [
+    ("slots=2 prefix-cache=on", "recurrent state cannot be cut by position"),
+    ("slots=2 mesh=tp:2", "mesh= is not served for arch:nemotron_h"),
+    ("slots=0", "arch:nemotron_h needs slots >= 1"),
+])
+def test_what_the_family_does_not_serve_is_refused_by_name(line, why):
+    pipe = parse_pipeline(
+        f"appsrc name=src ! tensor_generator {line} custom={custom()} ! tensor_sink name=out")
+    with pytest.raises(Exception, match=why):
+        pipe.start()
+    pipe.stop()
+
+
+def test_a_bad_pattern_or_share_is_refused_by_name():
+    with pytest.raises(ValueError, match="one of M, E"):
+        H.cfg_from_props(props(layers="MXE"))
+    with pytest.raises(ValueError, match="not a share"):
+        H.cfg_from_props(props(experts_held=6, expert_offset=4))
+    with pytest.raises(ValueError, match="cannot be cut by position"):
+        model, params, max_seq = H.build_slot_stream(props(), 2)
+        SlotEngine(model, params, max_seq=max_seq, prefill_chunk=8,
+                   prefix_cache=PrefixCache(grain=8))
+    with pytest.raises(ValueError, match="mesh="):
+        H.build_slot_stream(props(), 2, mesh=object())
+
+
+def test_the_resume_signature_covers_family_and_every_config_field():
+    dense = {"vocab": "97", "d_model": "64", "heads": "4", "layers": "2", "seq": "96"}
+
+    def sig(family, fields):
+        return resume_signature(family, max_new=8, **fields)
+
+    base = sig(H.FAMILY, H.resume_fields(props()))
+    assert base == sig(H.FAMILY, H.resume_fields(props()))
+    assert base != sig("zoo", dense_fields(dense))
+    for key, value in (("expert_offset", 4), ("experts_held", 4), ("layers", "MEM*EMM"),
+                       ("kv_heads", 1), ("ssm_state", 8), ("routed_scale", 1.0),
+                       ("seed", 6), ("gen_seed", 1), ("temperature", 0.5)):
+        over = {key: value, **({"experts_held": 4} if key == "expert_offset" else {})}
+        assert sig(H.FAMILY, H.resume_fields(props(**over))) != base, key
+    # the dense family's signature follows its config's fields too
+    d0 = sig("zoo", dense_fields(dense))
+    for key, value in (("d_ff", "128"), ("dtype", "float32"), ("attn", "flash"),
+                       ("seed", "3"), ("top_k", "5")):
+        assert sig("zoo", dense_fields({**dense, key: value})) != d0, key
+
+
+def test_the_three_slot_models_satisfy_the_one_protocol(served):
+    dense, _, _ = build_dense(
+        {"vocab": "31", "d_model": "16", "heads": "2", "layers": "1", "seq": "16",
+         "dtype": "float32"}, 2)
+    for model in (served[0], dense, SimSlotModel(2)):
+        assert isinstance(model, SlotModelProtocol), type(model)
+    assert served[0].counter_names == H.COUNTER_NAMES and not served[0].supports_prefix
+    assert dense.counter_names == () and dense.supports_prefix
