@@ -1021,9 +1021,10 @@ def pipeline_row(which: str, batch: int, n_frames: int, dtype: str,
             "option2=257:257 option4=heatmap-offset ! "
         )
     elif which == "vit":
-        # transformer-era vision row (net-new vs BASELINE.md): flash
-        # attention on TPU, same labeling pipeline as the headline
-        size, family, props = 224, "vit", {"dtype": dtype, "attn": "flash"}
+        # transformer-era vision row (net-new vs BASELINE.md): the fused
+        # attention kernel on a TPU (chosen by the model from platform and
+        # shape, no prop), same labeling pipeline as the headline
+        size, family, props = 224, "vit", {"dtype": dtype}
         if quant_applied(which):
             props["quantize"] = "int8"
         decoder = f"tensor_decoder mode=image_labeling option1={labels_path} ! "

@@ -623,7 +623,8 @@ def _run_kernel(fn, args, interpret):
 
 def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
                   classes=1001, norm_shape=(128, 224, 224, 3),
-                  attn_shapes=((2, 256, 4, 32), (2, 197, 3, 64))):
+                  attn_shapes=((2, 256, 4, 32), (2, 197, 3, 64)),
+                  attn_stream_shape=(2, 577, 16, 64)):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -657,11 +658,14 @@ def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
 
     # bf16 in, f32 accumulation: ~2^-8 relative on O(1) outputs
     tol = 2e-2
-    for shape in attn_shapes:
+    # the stream cell's shape (ViT-L/16-384) runs non-causal only: the one
+    # pass over resident keys, an overhanging 577 -> 640 block, head pairs
+    for shape, causals in [(s, (True, False)) for s in attn_shapes] + [
+            (attn_stream_shape, (False,))]:
         q, k, v = (jnp.asarray(rng.normal(size=shape).astype(np.float32))
                    .astype(jnp.bfloat16) for _ in range(3))
         f32 = [a.astype(jnp.float32) for a in (q, k, v)]
-        for causal in (True, False):
+        for causal in causals:
             out = _run_kernel(
                 lambda q, k, v, interpret, c=causal: flash_attention(
                     q, k, v, causal=c, interpret=interpret),

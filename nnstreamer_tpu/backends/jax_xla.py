@@ -546,21 +546,20 @@ class JaxXla(FilterBackend):
         (e.g. the Pallas top-1) cannot be auto-partitioned over a mesh,
         so such a post keeps to jnp when it is False.
         """
-        import inspect
-
-        try:
-            takes = "single_device" in inspect.signature(fn).parameters
-        except (TypeError, ValueError):
-            takes = False
-        if takes:
-            wrapped = lambda outs, _fn=fn: _fn(  # noqa: E731
-                outs, single_device=self._mesh is None
-            )
-        else:
-            wrapped = fn
-        self._posts.append(wrapped)
+        self._posts.append(self._tell_single_device(fn))
         with self._cache_lock:
             self._jit_cache.clear()
+
+    def _tell_single_device(self, fn):
+        """``fn`` with ``single_device=`` bound to whether this backend
+        compiles for one device, where ``fn`` takes that keyword (a model
+        or a post that holds a Mosaic kernel); ``fn`` itself otherwise."""
+        from ..models import takes_single_device
+
+        if not takes_single_device(fn):
+            return fn
+        return lambda *args, _fn=fn: _fn(
+            *args, single_device=self._mesh is None)
 
     def _apply_posts(self, outs: List[Any]) -> List[Any]:
         for post in self._posts:
@@ -575,8 +574,9 @@ class JaxXla(FilterBackend):
         dummies = [
             jax.ShapeDtypeStruct(t.shape, t.dtype) for t in in_spec.tensors
         ]
+        model = self._tell_single_device(self._fn)
         outs = jax.eval_shape(
-            lambda p, xs: self._apply_posts(self._normalize_out(self._fn(p, xs))),
+            lambda p, xs: self._apply_posts(self._normalize_out(model(p, xs))),
             self._params, dummies,
         )
         spec = StreamSpec(
@@ -620,7 +620,7 @@ class JaxXla(FilterBackend):
         def build(_key):
             import jax
 
-            model = self._fn
+            model = self._tell_single_device(self._fn)
             out_sharding = None
             if self._mesh is not None:
                 # mesh mode: outputs carry explicit NamedSharding specs —

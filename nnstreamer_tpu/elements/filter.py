@@ -254,7 +254,13 @@ class TensorFilter(TransformElement):
     PROPERTIES = {
         "framework": Property(str, "auto", "backend name or 'auto'"),
         "model": Property(str, "", "model path / registry key"),
-        "custom": Property(str, "", "backend-specific options 'k1:v1,k2:v2'"),
+        "custom": Property(
+            str, "",
+            "backend-specific options 'k1:v1,k2:v2' (jax-xla zoo: "
+            "arch:<family> and its sizes, e.g. arch:vit,size,patch,d_model,"
+            "heads,layers,d_ff,classes,dtype,quantize,seed; no key selects "
+            "a kernel: Documentation/performance.md 'Kernels on the default "
+            "path')"),
         "accelerator": Property(str, "", "'true:tpu.N,cpu' ordered wish list -> real device pinning"),
         # mesh-sharded serving (parallel/mesh.py grammar): one logical
         # filter across a device mesh — params sharded by the parallel

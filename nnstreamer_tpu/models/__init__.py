@@ -4,7 +4,9 @@ CNN, plus a long-context transformer for the parallel/ subsystem).
 
 ``build(name, custom_props)`` returns ``(fn, params, in_spec, out_spec)``
 with ``fn(params, inputs: list) -> list`` jit-traceable — the contract the
-jax-xla backend consumes (``custom=arch:<name>``).
+jax-xla backend consumes (``custom=arch:<name>``).  A family that holds a
+Mosaic kernel (the ViT's attention) also takes ``single_device=True``: its
+compiler passes False under a mesh, where such a call cannot be partitioned.
 """
 
 from __future__ import annotations
@@ -23,6 +25,17 @@ _ZOO = {
     "kws_cnn": "nnstreamer_tpu.models.kws_cnn",
     "vit": "nnstreamer_tpu.models.vit",
 }
+
+
+def takes_single_device(fn) -> bool:
+    """Whether ``fn`` (a zoo model, a fused postprocess) takes the
+    ``single_device`` keyword its compiler binds."""
+    import inspect
+
+    try:
+        return "single_device" in inspect.signature(fn).parameters
+    except (TypeError, ValueError):
+        return False
 
 
 def available() -> Tuple[str, ...]:

@@ -3,13 +3,18 @@
 The reference's model zoo is convnet-centric (per-vendor tflite/onnx
 classifiers); a Vision Transformer is the TPU-native complement: patch
 embedding + attention blocks are large dense matmuls that map straight
-onto the MXU, and the encoder reuses this framework's transformer Block
-machinery (``models/transformer.py``) including the flash-attention
-Pallas kernel via ``attn:flash``.
+onto the MXU.  Attention is ONE call, ``ops/flash_attention.py``'s
+``flash_attention_qkv`` on the fused qkv projection, and what runs is
+decided by what the code can observe, not by a property: a program
+lowered for one TPU device runs the Pallas kernel (the score matrix stays
+in VMEM), every other platform — and a program compiled for a mesh, which
+a Mosaic call cannot be partitioned over — the fused-XLA reference.
 
-Zoo entry ``vit``: fn(params, [images_u8 (N,S,S,3)]) -> [logits (N,classes)].
+Zoo entry ``vit``: fn(params, [images_u8 (N,S,S,3)], single_device=True)
+-> [logits (N,classes)]; ``single_device`` comes from whoever compiles the
+function (``backends/jax_xla.py``, the trainer: False under ``mesh=``).
 Props: size (default 224), patch (16), d_model (192), heads (3),
-layers (6), d_ff (768), classes (1001), dtype, attn (xla|flash).
+layers (6), d_ff (768), classes (1001), dtype.
 """
 
 from __future__ import annotations
@@ -30,8 +35,8 @@ class EncoderBlock(nn.Module):
     n_heads: int
     d_ff: int
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "xla"
     quant: bool = False  # int8 MXU dense layers (_quant_flax.QuantDense)
+    single_device: bool = True  # False: compiled for a mesh, no Mosaic call
 
     def _dense(self, features, name):
         from ._quant_flax import dense_or_quant
@@ -41,24 +46,14 @@ class EncoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):  # (B, T, D), pre-norm ViT block
-        B, T, D = x.shape
-        H = self.n_heads
+        from ..ops.flash_attention import flash_attention_qkv
+
+        D = x.shape[-1]
         h = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
         qkv = self._dense(3 * D, "attn_qkv")(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, H, D // H)
-        k = k.reshape(B, T, H, D // H)
-        v = v.reshape(B, T, H, D // H)
-        if self.attn_impl == "flash":
-            from ..ops.flash_attention import flash_attention_grad
-
-            # differentiable wrapper: kernel forward, recompute backward
-            a = flash_attention_grad(q, k, v, False)
-        else:
-            from ..parallel.ring_attention import reference_attention
-
-            a = reference_attention(q, k, v, causal=False)
-        x = x + self._dense(D, "attn_out")(a.reshape(B, T, D))
+        # kernel forward on a TPU, recompute backward; (B, T, 3D) -> (B, T, D)
+        a = flash_attention_qkv(qkv, self.n_heads, False, self.single_device)
+        x = x + self._dense(D, "attn_out")(a)
         h = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
         h = self._dense(self.d_ff, "mlp_up")(h)
         h = jax.nn.gelu(h)
@@ -74,8 +69,8 @@ class ViT(nn.Module):
     d_ff: int = 768
     num_classes: int = 1001
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "xla"
     quant: bool = False
+    single_device: bool = True
 
     @nn.compact
     def __call__(self, x):  # (B, S, S, 3) uint8 or float
@@ -105,8 +100,8 @@ class ViT(nn.Module):
         for i in range(self.n_layers):
             x = EncoderBlock(
                 self.d_model, self.n_heads, self.d_ff,
-                dtype=self.dtype, attn_impl=self.attn_impl,
-                quant=self.quant, name=f"block{i}",
+                dtype=self.dtype, quant=self.quant,
+                single_device=self.single_device, name=f"block{i}",
             )(x)
         x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
         return nn.Dense(
@@ -133,21 +128,22 @@ def build(custom_props=None):
         d_ff=int(props.get("d_ff", "768")),
         num_classes=int(props.get("classes", "1001")),
         dtype=dtype,
-        attn_impl=props.get("attn", "xla"),
         quant=props.get("quantize", "") == "int8",
     )
+    # init needs the shapes only: its program keeps to XLA, whatever
+    # device it is compiled for
     variables = host_init(
-        model.init,
+        model.clone(single_device=False).init,
         int(props.get("seed", "0")),
         np.zeros((1, size, size, 3), np.uint8),
     )
 
-    def fn(params, inputs: List[Any]) -> List[Any]:
+    def fn(params, inputs: List[Any], single_device: bool = True) -> List[Any]:
         x = inputs[0]
         single = x.ndim == 3
         if single:
             x = x[None]
-        out = model.apply(params, x)
+        out = model.clone(single_device=single_device).apply(params, x)
         return [out[0] if single else out]
 
     in_spec = StreamSpec(

@@ -1,22 +1,42 @@
 """Flash attention as a Pallas TPU kernel (single-device block).
 
-The MXU-native attention inner loop for the transformer family: Q blocks
-stream over K/V blocks with an online softmax, so the (Tq x Tk) score
-matrix never materializes in HBM — scores live in VMEM one block at a
-time, accumulation in f32.  Pattern references: Dao et al. FlashAttention;
-the public jax pallas attention examples (PAPERS.md / SNIPPETS.md).
+The MXU-native attention inner loop for the transformer family: the
+(Tq x Tk) score matrix never goes to HBM — scores live in VMEM, float32,
+and only Q, K, V and the output cross the memory bus.  Pattern references:
+Dao et al. FlashAttention; the public jax pallas attention examples
+(PAPERS.md / SNIPPETS.md).
+
+ONE kernel, its form read from the shape (``_blocks``):
+
+* **short sequences** (all keys of a head fit in VMEM beside their
+  scores: ViT's 197 or 577 tokens): the key axis of the grid has one
+  step and the softmax is computed once — max, ``exp``, sum, value
+  product — with no running-max rescale;
+* **long sequences**: Q blocks stream over 128-wide K/V blocks with the
+  online-softmax recurrence, the running max, sum and accumulator in VMEM
+  scratch across the sequential key axis.
+
+Q, K and V are read where the projections left them, ``(B, T, H*D)`` rows
+(or the packed ``(B, T, 3*H*D)`` of a fused qkv projection, each third
+addressed by its column blocks), and the output is written in
+``(B, T, H*D)``: no transpose and no pad in HBM.  A program takes as many
+heads as fill whole 128-lane tiles (two heads of 64) and tells them apart
+by lane masks: a 128-deep MXU pass costs the same with 64 live lanes as a
+64-deep one, and nothing is shuffled across lanes.  A sequence that does
+not fill its last block overhangs the array; the rows past the end are
+zeroed and their columns masked inside the program (static lengths).
 
 This is the intra-device complement of the sequence-parallel layers:
 ``parallel/ring_attention.py`` shards T across chips and rotates K/V;
 each device's local block product is exactly what this kernel computes.
 
 ``flash_attention(q, k, v)`` takes (B, T, H, D) like the rest of the
-stack.  A program lowered for a TPU runs the kernel; every other
-platform lowers the fused-XLA reference (``lax.platform_dependent``: the
-choice follows the device the program is compiled for, and nothing on a
-TPU declines the kernel quietly — a shape it cannot take raises).
-``interpret=True`` (tests only) runs the kernel in the Pallas interpreter
-instead.
+stack; ``flash_attention_qkv(qkv, n_heads)`` takes the packed projection.
+A program lowered for a TPU runs the kernel; every other platform lowers
+the fused-XLA reference (``lax.platform_dependent``: the choice follows
+the device the program is compiled for, and nothing on a TPU declines the
+kernel quietly — a shape it cannot take raises).  ``interpret=True``
+(tests only) runs the kernel in the Pallas interpreter instead.
 """
 
 from __future__ import annotations
@@ -31,28 +51,96 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # large-negative instead of -inf: avoids NaN in exp-diff
-_LANES = 128      # running max / sum live lane-replicated in VMEM scratch
+_LANES = 128      # a lane tile; running max / sum live lane-replicated
+# One pass while all keys of a head sit in VMEM beside a (block_q, keys)
+# float32 score block, its exp and the bf16 copy of it: 512 x 1024 scores
+# are 5 MB of them, inside the 16 MiB a program may use on every TPU so far
+# (ViT-L/16-384: all 577 -> 640 rows x 640 keys in one block).
+_ONE_PASS_KEYS = 1024
+_ONE_PASS_SCORES = 512 * 1024
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
-                  block_k: int, causal: bool, scale: float, seq_len: int,
-                  valid_len: int, with_lse: bool):
-    """One (batch*head, q-block, kv-block) program of the online softmax.
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, heads: int,
+                  head_dim: int, block_q: int, block_k: int, kv_len: int,
+                  causal: bool, scale: float, fold_scale: bool,
+                  one_pass: bool, with_lse: bool):
+    """One (batch, head group, q-block, kv-block) program.
 
-    The kv-block axis is the innermost, sequential grid axis: the running
-    max ``m``, sum ``l`` (both (block_q, 128), lane-replicated) and the
-    f32 accumulator persist in VMEM scratch across it; the first kv step
-    initializes them, the last one normalizes into ``o_ref``.  q_ref
-    (block_q, D); k_ref/v_ref (block_k, D) — only one K/V block is ever
-    resident, so T is bounded by HBM, not VMEM.  ``valid_len`` < seq_len
-    marks wrapper padding: K columns at or past it are masked out (static
-    python int — the mask compiles to constants).
+    q_ref/o_ref (block_q, W) and k_ref/v_ref (block_k, W) hold the W =
+    heads * head_dim columns of this program's heads.  ``one_pass``: the
+    kv axis has one step, the softmax is taken whole.  Otherwise the kv
+    axis is the innermost, sequential grid axis: per head the running max
+    ``m`` and sum ``l`` (both (block_q, 128), lane-replicated) and the
+    shared f32 accumulator persist in VMEM scratch across it; the first kv
+    step initializes them, the last one normalizes into ``o_ref``.
+    ``kv_len`` is the true key count (static): where it does not fill the
+    last block, that block's rows past it are zeroed and their columns
+    masked.
     """
-    if with_lse:
-        lse_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        m_scr, l_scr, acc_scr = rest
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    lse_ref = rest[0] if with_lse else None
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    W = heads * head_dim
+    ragged = kv_len % block_k != 0
+    lane = lax.broadcasted_iota(jnp.int32, (1, W), 1)
+
+    def in_head(h):
+        return (lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+
+    def scores():
+        """Per head: the masked float32 scores and the value block."""
+        k, v = k_ref[...], v_ref[...]
+        if ragged:
+            # past the array's end the buffer holds anything: 0 * NaN
+            row = ki * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            k = jnp.where(row < kv_len, k, jnp.zeros_like(k))
+            v = jnp.where(row < kv_len, v, jnp.zeros_like(v))
+        q = q_ref[...]
+        if fold_scale:  # 1/sqrt(D) on (block_q, W), not on the scores
+            q = (q * scale).astype(q.dtype)
+        if causal or ragged:
+            k_pos = ki * block_k + lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+        if causal:
+            visible = qi * block_q + lax.broadcasted_iota(
+                jnp.int32, (block_q, 1), 0) >= k_pos
+        if ragged:
+            pad_bias = jnp.where(k_pos < kv_len, 0.0, _NEG_INF)
+        for h in range(heads):
+            qh = jnp.where(in_head(h), q, jnp.zeros_like(q)) if heads > 1 else q
+            # q . k^T on the MXU in the input dtype, f32 accumulation
+            s = lax.dot_general(
+                qh, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (block_q, block_k)
+            if not fold_scale:
+                s = s * scale
+            if causal:
+                s = jnp.where(visible, s, _NEG_INF)
+            if ragged:
+                s = s + pad_bias
+            yield h, s, v
+
+    def put(whole, h, part):
+        """``whole`` with head h's lanes taken from ``part``."""
+        return part if whole is None else jnp.where(in_head(h), part, whole)
+
+    if one_pass:
+        out = None
+        for h, s, v in scores():
+            m = jnp.max(s, axis=1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=1, keepdims=True)  # >= 1: the max's own exp
+            # normalized after the value product: (block_q, W) multiplies
+            out = put(out, h, jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32,
+            ) * (1.0 / l))
+            if with_lse:
+                lse_ref[h] = m + jnp.log(l)
+        o_ref[...] = out.astype(o_ref.dtype)
+        return
+
+    m_scr, l_scr, acc_scr = rest[-3:]
 
     @pl.when(ki == 0)
     def _init():
@@ -61,30 +149,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def _step():
-        k = k_ref[...]
-        v = v_ref[...]
-        # q . k^T on the MXU in the input dtype, f32 accumulation
-        s = lax.dot_general(
-            q_ref[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (block_q, block_k)
-        if causal or valid_len < seq_len:
-            k_pos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-        if causal:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        if valid_len < seq_len:
-            s = jnp.where(k_pos < valid_len, s, _NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new[:, :1])
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        acc = acc_scr[...]
+        new = None
+        for h, s, v in scores():
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new[:, :1])
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[h] = m_new
+            new = put(new, h, acc * alpha[:, :1] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32))
+        acc_scr[...] = new
 
     if causal:
         # kv blocks strictly above the diagonal contribute nothing
@@ -92,134 +168,175 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
     else:
         _step()
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(ki == pl.num_programs(3) - 1)
     def _finish():
-        l = l_scr[:, :1]
-        o_ref[...] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(
-            o_ref.dtype)
-        if with_lse:
-            # per-row log-sum-exp of the (masked) scores: the cross-block
-            # merge statistic for ring attention (sequence parallelism);
-            # fully-masked rows keep a large-negative lse (l == 0)
-            lse_ref[...] = jnp.where(
-                l > 0.0, m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30)),
-                _NEG_INF)
+        acc = acc_scr[...]
+        out = None
+        for h in range(heads):
+            l = l_scr[h][:, :1]
+            out = put(out, h, acc * (1.0 / jnp.maximum(l, 1e-30)))
+            if with_lse:
+                # per-row log-sum-exp of the (masked) scores: the cross-block
+                # merge statistic for ring attention (sequence parallelism);
+                # fully-masked rows keep a large-negative lse (l == 0)
+                lse_ref[h] = jnp.where(
+                    l > 0.0,
+                    m_scr[h][:, :1] + jnp.log(jnp.maximum(l, 1e-30)),
+                    _NEG_INF)
+        o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _head_group(n_heads: int, head_dim: int) -> int:
+    """Heads per program: the fewest whose columns fill whole 128-lane
+    tiles (two heads of 64); where no count does, all of them — a block
+    as wide as the array is legal at any width."""
+    for g in range(1, n_heads + 1):
+        if n_heads % g == 0 and (g * head_dim) % _LANES == 0:
+            return g
+    return n_heads
+
+
+def _blocks(Tq: int, Tk: int, causal: bool, block_q=None, block_k=None):
+    """(block_q, block_k) from the sequence lengths; a block given by the
+    caller is kept.  Keys: up to ``_ONE_PASS_KEYS`` of them (rounded up to
+    whole lane tiles) are ONE block — the one-pass form — and a longer
+    sequence, or a causal one (whose blocks above the diagonal are
+    skipped), streams 128-wide blocks.  Rows beside resident keys: as many
+    as keep the score block within ``_ONE_PASS_SCORES``, the whole sequence
+    if it fits (measured on a v5e at 577 tokens and batch 128: 2.7 ms a
+    layer with all rows in one block, 3.3 in two, nearly twice in five);
+    128 otherwise.  A block never exceeds its rounded sequence; the last
+    block may overhang the array."""
+    def up(n, to):
+        return -(-n // to) * to
+
+    # keys are the scores' lanes: whole lane tiles; rows need only sublanes
+    keys = up(Tk, _LANES) if Tk > _LANES else up(Tk, 16)
+    if block_k is None:
+        block_k = keys if not causal and keys <= _ONE_PASS_KEYS else _LANES
+    block_k = min(block_k, keys)
+    if block_q is None:
+        block_q = _LANES
+        if block_k >= Tk:
+            block_q = max(
+                _LANES, _ONE_PASS_SCORES // block_k // _LANES * _LANES)
+    return min(block_q, up(Tq, 16)), block_k
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret",
-                     "valid_len", "with_lse"),
+    static_argnames=("n_heads", "head_dim", "cols", "causal", "block_q",
+                     "block_k", "with_lse", "interpret"),
 )
-def _flash_bh(qf, kf, vf, causal: bool, block_q: int, block_k: int,
-              interpret: bool, valid_len: int, with_lse: bool = False):
-    """(BH, Tq, D) + (BH, Tk, D) K/V -> (BH, Tq, D) [+ (BH, Tq, 1) f32
-    lse]; grid over (BH, Tq/block_q, Tk/block_k).  Tk may differ from Tq
-    (ring hops / partial-key calls) — causal requires Tq == Tk (aligned
-    positions)."""
-    BH, Tq, D = qf.shape
-    Tk = kf.shape[1]
+def _flash_call(q, k, v, *, n_heads: int, head_dim: int, cols=(0, 0, 0),
+                causal: bool, block_q=None, block_k=None,
+                with_lse: bool = False, interpret: bool = False):
+    """(B, Tq, ·), (B, Tk, ·), (B, Tk, ·) -> (B, Tq, H*D) [+ (B, H, Tq, 1)
+    f32 lse]; grid over (B, head groups, q blocks, kv blocks).
+
+    Head h of q lives in columns ``(cols[0] * H + h) * D`` on (``cols`` in
+    units of H*D: (0, 0, 0) for three arrays of width H*D, (0, 1, 2) for
+    one packed qkv array passed three times).  Tk may differ from Tq (ring
+    hops / partial-key calls) — causal requires Tq == Tk (aligned
+    positions).  Jitted so that a model's layers, which call it with the
+    same shapes, trace and lower the kernel once a program and not once a
+    layer (24 of them cost ViT-L a second a program before any compile)."""
+    B, Tq = q.shape[:2]
+    Tk = k.shape[1]
     if causal and Tq != Tk:
         # ValueError, not assert: survives python -O — a misaligned direct
         # caller must fail loud, never silently mis-mask
         raise ValueError(
             f"causal flash needs aligned q/k positions (Tq={Tq}, Tk={Tk})"
         )
-    if Tq % block_q or Tk % block_k:
-        raise ValueError(
-            f"flash blocks ({block_q}, {block_k}) do not tile (Tq={Tq}, "
-            f"Tk={Tk})")
+    G = _head_group(n_heads, head_dim)
+    W, HG = G * head_dim, n_heads // G
+    bq, bk = _blocks(Tq, Tk, causal, block_q, block_k)
+    nq, nk = -(-Tq // bq), -(-Tk // bk)
+    scale = 1.0 / math.sqrt(head_dim)
     kern = functools.partial(
-        _flash_kernel, block_q=block_q, block_k=block_k, causal=causal,
-        scale=1.0 / (D**0.5), seq_len=Tk, valid_len=valid_len,
-        with_lse=with_lse,
+        _flash_kernel, heads=G, head_dim=head_dim, block_q=bq, block_k=bk,
+        kv_len=Tk, causal=causal, scale=scale,
+        # folded into q only where that rounds nothing: a power of two, or
+        # float32 operands
+        fold_scale=(math.frexp(scale)[0] == 0.5
+                    or q.dtype == jnp.float32),
+        one_pass=nk == 1, with_lse=with_lse,
     )
     # under shard_map (ring hops) outputs must declare their varying
     # mesh axes (vma typing); inherit from the traced input
-    vma = getattr(qf.aval, "vma", None) or frozenset()
+    vma = getattr(q.aval, "vma", None) or frozenset()
+    cq, ck, cv = (c * HG for c in cols)
 
-    out_shape = [jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype, vma=vma)]
-    # None squeezes the batch*head dim out of the kernel refs
-    out_specs = [pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct(
+        (B, Tq, n_heads * head_dim), q.dtype, vma=vma)]
+    # None squeezes the batch dim out of the kernel refs
+    out_specs = [pl.BlockSpec((None, bq, W), lambda b, g, i, j: (b, i, g))]
     if with_lse:
-        # trailing length-1 lane dim keeps the ref 2-D for Mosaic tiling
-        out_shape.append(
-            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32, vma=vma))
+        # trailing length-1 lane dim keeps the ref's minor dims 2-D
+        out_shape.append(jax.ShapeDtypeStruct(
+            (B, n_heads, Tq, 1), jnp.float32, vma=vma))
         out_specs.append(
-            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)))
+            pl.BlockSpec((None, G, bq, 1), lambda b, g, i, j: (b, g, i, 0)))
+    scratch = [] if nk == 1 else [
+        pltpu.VMEM((G, bq, _LANES), jnp.float32),  # running max, per head
+        pltpu.VMEM((G, bq, _LANES), jnp.float32),  # running sum, per head
+        pltpu.VMEM((bq, W), jnp.float32),          # accumulator
+    ]
     res = pl.pallas_call(
         kern,
         out_shape=out_shape,
-        grid=(BH, Tq // block_q, Tk // block_k),
+        grid=(B, HG, nq, nk),
         in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, bq, W), lambda b, g, i, j: (b, i, cq + g)),
+            pl.BlockSpec((None, bk, W), lambda b, g, i, j: (b, j, ck + g)),
+            pl.BlockSpec((None, bk, W), lambda b, g, i, j: (b, j, cv + g)),
         ],
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, D), jnp.float32),       # accumulator
-        ],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf)
+        name="nns_flash_attention",
+    )(q, k, v)
     return res if with_lse else res[0]
 
 
-def _blocks(T: int, block_q: int, block_k: int):
-    """(T_pad, bq, bk): the padded length and the blocks that tile it.  A
-    sequence shorter than a block becomes ONE block, rounded up to the
-    sublane tile; a longer one pads to a multiple of both blocks."""
-    if T <= min(block_q, block_k):
-        T_pad = -(-T // 16) * 16
-        return T_pad, T_pad, T_pad
-    blk = math.lcm(block_q, block_k)
-    T_pad = -(-T // blk) * blk
-    return T_pad, block_q, block_k
+def _rows(x):
+    # (B, T, H, D) -> (B, T, H*D): the projection's own rows, a bitcast
+    return x.reshape(x.shape[0], x.shape[1], -1)
 
 
-def _to_heads(x):
-    # (B, T, H, D) -> (B*H, T, D): each (batch, head) is one independent
-    # attention problem
-    B, T, H, D = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+def _reference(q, k, v, causal):
+    from ..parallel.ring_attention import reference_attention
+
+    return reference_attention(q, k, v, causal=causal).astype(q.dtype)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
+def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
+                    block_k=None, interpret: bool = False):
     """Exact attention, (B, T, H, D) -> (B, T, H, D).
 
-    Lowered for a TPU: the Pallas kernel.  Lowered for anything else: the
-    fused-XLA reference path (same numerics contract), unless
-    ``interpret=True`` asks for the kernel in the Pallas interpreter —
-    TESTS only, orders of magnitude slower than XLA.
+    Lowered for a TPU: the Pallas kernel, its blocks from the shape
+    (``_blocks``) unless given.  Lowered for anything else: the fused-XLA
+    reference path (same numerics contract), unless ``interpret=True``
+    asks for the kernel in the Pallas interpreter — TESTS only, orders of
+    magnitude slower than XLA.
     """
     B, T, H, D = q.shape
-    # non-divisible T (e.g. ViT's (S/p)^2 + 1 tokens): pad K/V/Q up to a
-    # multiple of BOTH block sizes; padded K columns are masked inside the
-    # kernel via the static valid_len, padded Q rows are sliced off below
-    T_pad, bq, bk = _blocks(T, block_q, block_k)
 
     def kernel(q, k, v):
-        qf, kf, vf = _to_heads(q), _to_heads(k), _to_heads(v)
-        if T_pad != T:
-            pad = ((0, 0), (0, T_pad - T), (0, 0))
-            qf, kf, vf = jnp.pad(qf, pad), jnp.pad(kf, pad), jnp.pad(vf, pad)
-        out = _flash_bh(
-            qf, kf, vf, causal, bq, bk, bool(interpret), valid_len=T)
-        return out[:, :T].reshape(B, H, T, D).transpose(0, 2, 1, 3)
-
-    def reference(q, k, v):
-        from ..parallel.ring_attention import reference_attention
-
-        return reference_attention(q, k, v, causal=causal).astype(q.dtype)
+        return _flash_call(
+            _rows(q), _rows(k), _rows(v), n_heads=H, head_dim=D,
+            causal=causal, block_q=block_q, block_k=block_k,
+            interpret=bool(interpret)).reshape(B, T, H, D)
 
     if interpret:
         return kernel(q, k, v)
-    return lax.platform_dependent(q, k, v, tpu=kernel, default=reference)
+    return lax.platform_dependent(
+        q, k, v, tpu=kernel,
+        default=functools.partial(_reference, causal=causal))
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 128,
@@ -235,23 +352,16 @@ def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 128,
 
     which is how ``parallel/ring_attention.py`` composes this kernel
     across the ``sp`` ring (each hop's K/V block -> one kernel call).
-    Ring blocks are uniform, so there is no padding path: blocks that do
-    not tile the sequence raise.  Platform choice as in
-    :func:`flash_attention`.
+    Platform choice as in :func:`flash_attention`.
     """
     B, T, H, D = q.shape
-    Tk = k.shape[1]
-    bq, bk = min(block_q, T), min(block_k, Tk)
 
     def kernel(q, k, v):
-        out, lse = _flash_bh(
-            _to_heads(q), _to_heads(k), _to_heads(v), causal, bq, bk,
-            bool(interpret), valid_len=Tk, with_lse=True,
-        )
-        return (
-            out.reshape(B, H, T, D).transpose(0, 2, 1, 3),
-            lse.reshape(B, H, T),
-        )
+        out, lse = _flash_call(
+            _rows(q), _rows(k), _rows(v), n_heads=H, head_dim=D,
+            causal=causal, block_q=block_q, block_k=block_k,
+            with_lse=True, interpret=bool(interpret))
+        return out.reshape(B, T, H, D), lse.reshape(B, H, T)
 
     if interpret:
         return kernel(q, k, v)
@@ -292,12 +402,13 @@ def reference_attention_lse(q, k, v, causal: bool = True):
 # the recompute backward still materializes the (B,H,T,T) score matrix
 # under XLA autodiff, so training peak memory stays O(T^2) per layer
 # until a backward kernel lands (long-context training shards T via
-# parallel/ring_attention.py instead).  The model zoo's flash branches
-# call this entry point; inference-only code may call flash_attention.
+# parallel/ring_attention.py instead).  The model zoo calls these entry
+# points (``flash_attention_grad``, ``flash_attention_qkv``);
+# inference-only code may call flash_attention.
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention_grad(q, k, v, causal: bool = True, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = False):
+def flash_attention_grad(q, k, v, causal: bool = True, block_q=None,
+                         block_k=None, interpret: bool = False):
     """Differentiable flash attention: (B, T, H, D) -> (B, T, H, D).
 
     Forward runs the Pallas kernel (or its documented fallbacks);
@@ -310,26 +421,72 @@ def flash_attention_grad(q, k, v, causal: bool = True, block_q: int = 128,
     )
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out = flash_attention(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
-    return out, (q, k, v)
+def _fa_fwd(q, k, v, *static):
+    return flash_attention_grad(q, k, v, *static), (q, k, v)
+
+
+def _recompute(q, k, v, causal):
+    # f32 score accumulation + f32 softmax, matching the kernel's forward
+    # numerics — a bf16 recompute would round the softmax row-sums and
+    # skew gradients ~2% at T=128 (growing with T)
+    return reference_attention_lse(q, k, v, causal=causal)[0].astype(q.dtype)
 
 
 def _fa_bwd(causal, block_q, block_k, interpret, res, g):
-    q, k, v = res
-
-    def ref(q_, k_, v_):
-        # f32 score accumulation + f32 softmax, matching the kernel's
-        # forward numerics — a bf16 recompute would round the softmax
-        # row-sums and skew gradients ~2% at T=128 (growing with T)
-        out, _ = reference_attention_lse(q_, k_, v_, causal=causal)
-        return out.astype(q_.dtype)
-
-    _, vjp = jax.vjp(ref, q, k, v)
-    return vjp(g)
+    return jax.vjp(functools.partial(_recompute, causal=causal), *res)[1](g)
 
 
 flash_attention_grad.defvjp(_fa_fwd, _fa_bwd)
+
+
+def _split_heads(qkv, n_heads):
+    B, T, C = qkv.shape
+    return [x.reshape(B, T, n_heads, C // (3 * n_heads))
+            for x in jnp.split(qkv, 3, axis=-1)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def flash_attention_qkv(qkv, n_heads: int, causal: bool = False,
+                        single_device: bool = True, interpret: bool = False):
+    """Attention over a packed projection: (B, T, 3*H*D) -> (B, T, H*D),
+    differentiable (recompute backward, as ``flash_attention_grad``).
+
+    The kernel reads q, k and v out of ``qkv`` by column blocks, so the
+    projection's output is never split or copied in HBM (where a head
+    group is not whole lane tiles the thirds are split first).
+    ``single_device=False`` — the caller compiles for a mesh, where a
+    Mosaic call cannot be partitioned — keeps to the reference on every
+    platform."""
+    B, T, C = qkv.shape
+    D = C // (3 * n_heads)
+
+    def kernel(qkv):
+        kw = dict(n_heads=n_heads, head_dim=D, causal=causal,
+                  interpret=bool(interpret))
+        if (_head_group(n_heads, D) * D) % _LANES == 0:
+            return _flash_call(qkv, qkv, qkv, cols=(0, 1, 2), **kw)
+        return _flash_call(*jnp.split(qkv, 3, axis=-1), **kw)
+
+    def reference(qkv):
+        return _reference(*_split_heads(qkv, n_heads), causal).reshape(
+            B, T, C // 3)
+
+    if interpret:
+        return kernel(qkv)
+    if not single_device:
+        return reference(qkv)
+    return lax.platform_dependent(qkv, tpu=kernel, default=reference)
+
+
+def _fa_qkv_fwd(qkv, *static):
+    return flash_attention_qkv(qkv, *static), qkv
+
+
+def _fa_qkv_bwd(n_heads, causal, single_device, interpret, qkv, g):
+    def ref(x):
+        return _recompute(*_split_heads(x, n_heads), causal).reshape(g.shape)
+
+    return jax.vjp(ref, qkv)[1](g)
+
+
+flash_attention_qkv.defvjp(_fa_qkv_fwd, _fa_qkv_bwd)

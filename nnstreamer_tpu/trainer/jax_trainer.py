@@ -44,6 +44,7 @@ Crash safety (net-new vs the reference; the preemptible-TPU contract):
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import queue
@@ -233,6 +234,10 @@ class JaxTrainer(TrainerBackend):
         mesh_spec = str(self._props.get("mesh") or "")
         if mesh_spec.strip() not in ("", "0", "off", "none"):
             params = self._arm_mesh(mesh_spec, params)
+            if zoo.takes_single_device(fn):
+                # a model that holds a Mosaic kernel (the ViT's attention)
+                # keeps to XLA in a program partitioned over the mesh
+                fn = functools.partial(fn, single_device=False)
         else:
             params = jax.device_put(params, default_device())
         lr = float(self._cfg.get("learning_rate", 1e-3))

@@ -58,7 +58,8 @@ def test_kernels_phase_tiny_runs_every_pallas_kernel_interpreted():
     (interpreter), next to their TPU lowering as text."""
     r = chip_smoke.phase_kernels(
         platform="cpu", interpret=True, top1_batches=(1, 2, 8), classes=17,
-        norm_shape=(2, 8, 8, 3), attn_shapes=((1, 32, 2, 8), (1, 21, 3, 8)))
+        norm_shape=(2, 8, 8, 3), attn_shapes=((1, 32, 2, 8), (1, 21, 3, 8)),
+        attn_stream_shape=(1, 37, 4, 8))
     names = " ".join(r["kernels"])
     for kernel in ("top1", "normalize_u8", "flash(", "flash_grad"):
         assert kernel in names
@@ -83,7 +84,8 @@ def test_a_failing_phase_is_fatal():
     with pytest.raises(AssertionError, match="must live on 'tpu'"):
         chip_smoke.phase_kernels(
             platform="tpu", interpret=True, top1_batches=(2,), classes=17,
-            norm_shape=(1, 8, 8, 3), attn_shapes=((1, 16, 1, 8),))
+            norm_shape=(1, 8, 8, 3), attn_shapes=((1, 16, 1, 8),),
+            attn_stream_shape=(1, 16, 1, 8))
 
 
 def test_main_refuses_to_run_without_a_tpu(capsys):

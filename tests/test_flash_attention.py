@@ -91,6 +91,93 @@ class TestFlashAttention:
         assert out.shape == (64, 64) and np.isfinite(out).all()
 
 
+def _forms(T):
+    """How the kernel is asked to run at T tokens: by the shape rule (one
+    pass for these lengths) or with streamed blocks forced."""
+    blk = 128 if T > 128 else 16
+    return {"one_pass": {}, "streamed": {"block_q": blk, "block_k": blk}}
+
+
+class TestShortSequenceForms:
+    """ViT's shapes, non-causal: all keys of a head resident and ONE pass
+    (the shape rule), the streamed recurrence forced on the same data, and
+    the packed-qkv entry the ViT calls — each against the float32
+    ``highest`` reference."""
+
+    @pytest.mark.parametrize("form", ["one_pass", "streamed", "packed"])
+    @pytest.mark.parametrize("shape,dtype", [
+        ((1, 577, 4, 64), jnp.bfloat16),   # the stream cell's tokens, B = 1
+        ((2, 197, 3, 64), jnp.float32),    # ViT-224: 192 lanes, no head pair
+        ((1, 17, 2, 16), jnp.float32),     # a test-sized ViT
+    ])
+    def test_matches_float32_reference(self, shape, dtype, form):
+        from nnstreamer_tpu.ops.flash_attention import (
+            _blocks, flash_attention_qkv)
+
+        B, T, H, D = shape
+        q, k, v = _qkv(B, T, H, D, dtype, seed=T)
+        if form == "packed":
+            qkv = jnp.concatenate(
+                [x.reshape(B, T, H * D) for x in (q, k, v)], axis=-1)
+            out = flash_attention_qkv(qkv, H, False, True, True).reshape(shape)
+        else:
+            kw = _forms(T)[form]
+            nk = -(-T // _blocks(T, T, False, **kw)[1])
+            assert (nk == 1) == (form == "one_pass")
+            out = flash_attention(q, k, v, causal=False, interpret=True, **kw)
+        assert out.dtype == dtype and out.shape == shape
+        with jax.default_matmul_precision("highest"):
+            ref = reference_attention(
+                *(x.astype(jnp.float32) for x in (q, k, v)), causal=False)
+        # bf16: the output's own rounding (2^-9 of values up to ~1)
+        tol = 8e-3 if dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref), atol=tol)
+
+    @pytest.mark.parametrize("form", ["one_pass", "streamed"])
+    def test_padded_columns_carry_no_weight(self, form):
+        """577 keys in a 640-wide block: with every value 1 a row's output
+        is the sum of its probabilities over the TRUE keys over the sum
+        over ALL columns — exactly 1 only if the overhang weighs nothing;
+        and the lse is the log-sum-exp over the 577."""
+        from nnstreamer_tpu.ops.flash_attention import flash_attention_lse
+
+        q, k, _ = _qkv(1, 577, 2, 64, seed=7)
+        v = jnp.ones_like(q)
+        kw = _forms(577)[form] or {"block_q": None, "block_k": None}
+        out, lse = flash_attention_lse(
+            q, k, v, causal=False, interpret=True, **kw)
+        np.testing.assert_allclose(np.asarray(out), 1.0, atol=1e-6)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / 8.0
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(jax.nn.logsumexp(s, axis=-1)),
+            atol=2e-5)
+
+    @pytest.mark.parametrize("args,want", [
+        ((577, 577, False), (592, 640)),      # ViT-L/16-384: one block
+        ((197, 197, False), (208, 256)),
+        ((17, 17, False), (32, 32)),
+        ((1024, 1024, False), (512, 1024)),   # rows bounded by the scores
+        ((1025, 1025, False), (128, 128)),    # past VMEM: streamed
+        ((577, 577, True), (128, 128)),       # causal keeps its blocks
+        ((32, 577, False), (32, 640)),        # few rows, resident keys
+        ((100, 100, False, 96, 64), (96, 64)),  # a given block is kept
+        ((64, 32, False, 128, 128), (64, 32)),  # never past the sequence
+    ])
+    def test_blocks_follow_the_shape(self, args, want):
+        from nnstreamer_tpu.ops.flash_attention import _blocks
+
+        assert _blocks(*args) == want
+
+    def test_heads_are_taken_in_whole_lane_tiles(self):
+        from nnstreamer_tpu.ops.flash_attention import _head_group
+
+        assert _head_group(16, 64) == 2     # ViT-L: pairs, 128 lanes
+        assert _head_group(8, 128) == 1
+        assert _head_group(4, 32) == 4
+        assert _head_group(3, 64) == 3      # 192 lanes: the whole width
+
+
 class TestFlashAttentionLse:
     """flash_attention_lse: the (out, lse) pair whose exact two-partial
     merge composes the kernel across ring hops (sequence parallelism)."""

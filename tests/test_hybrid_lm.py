@@ -346,17 +346,24 @@ def test_the_small_batch_kernel_matches_the_reference_loop_over_held_experts(rng
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described (not attached) v5e chip: the TPU compiler is installed
-    here, so the kernel's Mosaic lowering is checked at no chip time."""
+def v5e():
+    """A described (not attached) host of four v5e chips: the TPU compiler
+    is installed here, so Mosaic lowerings are checked at no chip time.
+    (The one place of the test suite that loads libtpu: the ViT's compile
+    tests below live in this file for that reason.)"""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 @pytest.mark.parametrize("tokens", [32, 128, 512])
@@ -416,6 +423,57 @@ def test_the_cells_decode_program_compiles_for_a_v5e_and_fits_its_memory(one_chi
     assert "ragged" not in text
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13 << 30
     assert mem.temp_size_in_bytes < 256 << 20   # no whole-stack copy of the experts
+
+
+# ---------------------------------------------------------------------------
+# the stream cell's ViT through the chip's compiler (models/vit.py,
+# ops/flash_attention.py; here because this file holds the v5e fixture)
+# ---------------------------------------------------------------------------
+def _vit_step(props, batch, sharding_of, **fn_kw):
+    """The zoo ViT's jitted step lowered on shapes: (lowered, n layers)."""
+    from nnstreamer_tpu.models import build
+
+    fn, params, _, _ = build("vit", props)
+    size = int(props["size"])
+    p_sh, x_sh = sharding_of
+    lowered = jax.jit(lambda p, x: fn(p, [x], **fn_kw)[0]).lower(
+        jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.dtype(props["dtype"]), sharding=p_sh), params),
+        jax.ShapeDtypeStruct((batch, size, size, 3), jnp.uint8, sharding=x_sh))
+    return lowered, int(props["layers"])
+
+
+@pytest.mark.parametrize("batch", [128, 1])
+def test_the_vit_cells_step_compiles_for_a_v5e_with_its_scores_in_vmem(one_chip, batch):
+    """ViT-L/16-384 at the stream cell's widths, cut to two layers to keep
+    the test short, at its largest and its smallest bucket: one
+    ``nns_flash_attention`` call a layer, and no array anywhere in the
+    program with two dimensions of 577 (or of the padded 640) — the score
+    matrix and its softmax exist only inside the kernel."""
+    props = {"size": "384", "patch": "16", "d_model": "1024", "heads": "16",
+             "layers": "2", "d_ff": "4096", "classes": "1000", "dtype": "bfloat16"}
+    lowered, layers = _vit_step(props, batch, (one_chip, one_chip))
+    text = lowered.compile().as_text()
+    assert len(set(re.findall(r"%nns_flash_attention[.\d]* =", text))) == layers
+    squares = {shape for shape in re.findall(r"\[([\d,]+)\]", text)
+               if sum(d in ("577", "640") for d in shape.split(",")) >= 2}
+    assert not squares, squares
+
+
+def test_a_small_vit_compiles_for_a_v5e_mesh(v5e):
+    """``mesh=dp:4`` as backends/jax_xla.py compiles it (parameters
+    replicated, the batch scattered on dp, ``single_device=False``): a
+    Mosaic call cannot be partitioned, so the program must hold none."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(v5e.devices).reshape(4), ("dp",))
+    props = {"size": "64", "patch": "16", "d_model": "128", "heads": "2",
+             "layers": "2", "d_ff": "256", "classes": "10", "dtype": "bfloat16"}
+    lowered, _ = _vit_step(
+        props, 8, (NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))),
+        single_device=False)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" not in text and "nns_flash_attention" not in text
 
 
 # ---------------------------------------------------------------------------

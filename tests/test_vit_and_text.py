@@ -27,21 +27,61 @@ class TestViT:
         assert out.shape == (2, 11)
         assert np.all(np.isfinite(np.asarray(out)))
 
-    def test_attn_flash_prop_accepted_off_tpu(self, rng):
-        """Off-TPU, attn:flash falls back to the reference path without
-        error at ViT's non-block-divisible token count ((64/16)^2+1=17).
-        The kernel itself (incl. padding/masking) is pinned by
-        test_vit_token_count_flash_kernel below and
-        tests/test_flash_attention.py — NOT by this fallback path."""
-        f_f, p_f, _, _ = build(
-            "vit",
-            {"dtype": "float32", "size": "64", "patch": "16",
-             "d_model": "32", "heads": "2", "layers": "1", "d_ff": "64",
-             "classes": "7", "seed": "3", "attn": "flash"},
-        )
+    def test_default_path_runs_off_tpu(self, rng):
+        """Off-TPU the ViT's one attention call lowers the fused-XLA
+        reference, without error at ViT's non-block-divisible token count
+        ((64/16)^2+1=17), and an ``attn`` property is no longer read.
+        The kernel itself (incl. the overhanging block and its masking)
+        is pinned by test_vit_token_count_flash_kernel below and
+        tests/test_flash_attention.py — NOT by this path."""
+        props = {"dtype": "float32", "size": "64", "patch": "16",
+                 "d_model": "32", "heads": "2", "layers": "1", "d_ff": "64",
+                 "classes": "7", "seed": "3"}
+        f_f, p_f, _, _ = build("vit", props)
         imgs = rng.integers(0, 255, (1, 64, 64, 3), np.uint8)
         y = np.asarray(f_f(p_f, [imgs])[0])
         assert y.shape == (1, 7) and np.all(np.isfinite(y))
+        f_k, p_k, _, _ = build("vit", {**props, "attn": "flash"})
+        np.testing.assert_array_equal(np.asarray(f_k(p_k, [imgs])[0]), y)
+        text = jax.jit(lambda p, x: f_f(p, [x])[0]).lower(p_f, imgs).as_text()
+        assert "tpu_custom_call" not in text
+
+    @pytest.mark.parametrize("mesh", ["", "dp:2"])
+    def test_backend_tells_the_vit_whether_it_compiles_for_one_device(
+            self, rng, monkeypatch, mesh):
+        """A Mosaic call cannot sit in a mesh-partitioned program: under
+        ``mesh=`` the backend binds ``single_device=False`` and the ViT's
+        attention keeps to XLA; without a mesh it is True."""
+        import importlib
+
+        # (the package re-exports a function of the module's name)
+        fa = importlib.import_module("nnstreamer_tpu.ops.flash_attention")
+        seen = []
+        real = fa.flash_attention_qkv
+
+        def spy(qkv, n_heads, causal, single_device):
+            seen.append(single_device)
+            return real(qkv, n_heads, causal, single_device)
+
+        monkeypatch.setattr(fa, "flash_attention_qkv", spy)
+        custom = ("arch:vit,size:32,patch:16,d_model:32,heads:2,layers:1,"
+                  "d_ff:64,classes:5,dtype:float32")
+        pipe = parse_pipeline(
+            f"appsrc name=src ! tensor_filter framework=jax-xla model=zoo "
+            f"custom={custom} max-batch=2 {('mesh=' + mesh) if mesh else ''} "
+            "! tensor_sink name=out")
+        pipe.start()
+        try:
+            for _ in range(2):
+                pipe["src"].push(rng.integers(0, 255, (32, 32, 3), np.uint8))
+            pipe["src"].end_of_stream()
+            pipe.wait(timeout=120)
+        finally:
+            pipe.stop()
+        assert len(pipe["out"].frames) == 2
+        # the first call is the zoo's init program, which never holds the
+        # kernel (it needs shapes only); the rest are the backend's
+        assert not seen[0] and seen[1:] and set(seen[1:]) == {not mesh}
 
     def test_vit_token_count_flash_kernel(self, rng):
         """The REAL kernel (interpret mode) at ViT-224's token count
