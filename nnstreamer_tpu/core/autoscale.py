@@ -23,7 +23,7 @@ decision logic pure and the side effects pluggable:
   Performance Model for Tensor Processing Units" scaled down to the
   digest features we actually have.  The TTFT observable is the PR-11
   log2 histogram estimate carried in each digest (``ttft_p95_ms``);
-  bench rows bank through :meth:`PerfModel.feed_bench_row`.  When the
+  recorded rows bank through :meth:`PerfModel.feed_bench_row`.  When the
   model has enough samples the planner acts on PROJECTED SLO burn
   (scale before the burn, not after it); below ``min_samples`` the
   reactive path is the always-correct fallback.
@@ -757,9 +757,9 @@ class PerfModel:
         self._dirty = True
 
     def feed_bench_row(self, row: Dict[str, Any]) -> bool:
-        """Bank one banked-bench evidence row (``tools/bench.py``
-        attaches ``pipeline_digest_stats`` evidence): needs occupancy
-        (or slots+occupied) and at least one of tokens/s / TTFT."""
+        """Bank one recorded row of ``pipeline_digest_stats`` fields:
+        needs occupancy (or slots+occupied) and at least one of
+        tokens/s / TTFT."""
         try:
             if "occupancy" in row:
                 occ = float(row["occupancy"])

@@ -411,8 +411,8 @@ class SimSlotModel:
     dispatch, the memory-bound LLM-decode regime batching amortizes)
     plus a small per-active-slot increment.
 
-    This is what the ``pytest -m perf`` continuous-batching floor and
-    the chaos harness drive: the object under test is the SLOT
+    This is what the slot engine's dispatch-ledger tests and the chaos
+    harness drive: the object under test is the SLOT
     SCHEDULER (join/evict correctness, multiplexing win, emission-path
     overhead), not XLA-CPU GEMM scaling, which inverts the real
     accelerator's batch economics at zoo-model sizes.

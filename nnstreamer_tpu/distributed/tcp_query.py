@@ -5,8 +5,8 @@ library's custom TCP framing (``tensor_query_client.c:657-699`` →
 nns_edge_send; ``nnstreamer-edge`` repo).  The gRPC transport
 (:mod:`.service`) stays the default for interop; this one exists to feed
 a chip at target rate: Python gRPC costs several whole-payload copies per
-request, which caps the measured client ceiling below chip rate at real
-payload sizes (BENCH_FANOUT r3: 713 fps @150 KB).
+request, which caps one client's ceiling below chip rate at real payload
+sizes (``tools/bench_fanout.py`` echo mode measures that ceiling).
 
 Design for copy-freedom on the hot path:
 

@@ -1489,9 +1489,9 @@ class TensorFilter(TransformElement):
         ``device_get``: when the window is full it waits on the oldest
         batch's completion event (bounded, cooperatively interruptible)
         as pure backpressure, and by the time an entry is popped its
-        device->host sync has already happened on the reaper.  The raw
-        benchmark sustains its rate at exactly this structure (bench.py
-        BENCH_RAW); the reference's steady state is synchronous
+        device->host sync has already happened on the reaper.  A bare
+        jitted model driven with several calls in flight has exactly
+        this structure; the reference's steady state is synchronous
         map->invoke->append (tensor_filter.c:642-930)."""
         depth = max(1, int(self.props["dispatch-depth"]))
         if self._win_async is None:

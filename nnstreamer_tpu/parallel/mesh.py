@@ -35,8 +35,8 @@ def parse_mesh_spec(text: str) -> Dict[str, int]:
     ``"dp:2,tp:2"`` / ``"dp:-1"`` (-1 = remaining devices, at most one
     axis) into ``{axis: size}``.  Empty/``"0"``/``"off"`` -> ``{}``
     (unsharded).  The one grammar shared by the tensor_filter /
-    tensor_generator ``mesh=`` props, the jax-xla backend, and bench's
-    ``BENCH_MESH`` axis — config surfaces cannot drift."""
+    tensor_generator ``mesh=`` props and the jax-xla backend — config
+    surfaces cannot drift."""
     text = (text or "").strip()
     if text in ("", "0", "off", "none"):
         return {}

@@ -537,9 +537,8 @@ class Pipeline:
         )
 
     def telemetry_summary(self) -> Dict[str, float]:
-        """Compact {metric_name: value} dump (counters summed across
-        elements, gauges maxed) — the labeled snapshot bench.py attaches
-        to each evidence row."""
+        """Compact {metric_name: value} dump of this pipeline's labeled
+        snapshot (counters summed across elements, gauges maxed)."""
         return self.metrics_snapshot().flat()
 
     def serve_metrics(self, port: int = 0, host: str = "127.0.0.1") -> int:

@@ -36,54 +36,6 @@ def rng():
 
 
 # ---------------------------------------------------------------------------
-# Perf-floor load shielding (by construction, not operator discipline):
-# `pytest -m perf` timing tests measure real clocks, so a fleet/chaos suite
-# interleaved INTO the perf block by pytest-randomly turns ambient load
-# into flaky floor failures.  Two guards:
-#
-# 1. a hookwrapper collection hook (runs AFTER every other implementation,
-#    pytest-randomly's shuffle included) gathers perf-marked items into
-#    ONE CONTIGUOUS block at the position of the first perf item, so no
-#    fleet/chaos test can run BETWEEN two timing floors (moving the block
-#    to the very front was tried and is itself a flake source: timing
-#    floors in a cold process measure thread-pool/allocator warmup);
-# 2. an autouse fixture makes each perf test wait (bounded) until no
-#    framework threads from a previous test are still winding down.
-#
-# When BISECTING a perf failure, additionally run the perf-marked files
-# with `-p no:randomly` (tier-1 already does): pytest-randomly reseeds
-# NumPy/random per test, and while guard 1 keeps the perf BLOCK
-# contiguous, a shuffled neighborhood still changes which suites warmed
-# the process before the block — the contiguity of the block is pinned
-# by tests/test_perf_truth.py::test_perf_block_stays_contiguous.
-# ---------------------------------------------------------------------------
-@pytest.hookimpl(hookwrapper=True)
-def pytest_collection_modifyitems(config, items):
-    yield  # let every other plugin (randomization included) reorder first
-    perf = [it for it in items if it.get_closest_marker("perf")]
-    if not perf or len(perf) == len(items):
-        return  # nothing to shield (or a pure `-m perf` run)
-    first = next(
-        i for i, it in enumerate(items) if it.get_closest_marker("perf"))
-    rest = [it for it in items if not it.get_closest_marker("perf")]
-    pos = min(first, len(rest))
-    items[:] = rest[:pos] + perf + rest[pos:]
-
-
-@pytest.fixture(autouse=True)
-def _perf_load_shield(request):
-    """Perf-marked tests start on a quiet box: bounded wait for framework
-    threads (fleet servers, pumps, stagers) from earlier tests to exit."""
-    if request.node.get_closest_marker("perf") is None:
-        yield
-        return
-    deadline = time.monotonic() + 10.0
-    while time.monotonic() < deadline and _live_framework_threads():
-        time.sleep(0.05)
-    yield
-
-
-# ---------------------------------------------------------------------------
 # Leak guard (zero-downtime operations contract): drain/swap/rolling-restart
 # must not strand worker threads or sockets.  The lifecycle/e2e test modules
 # autouse this module-scoped fixture, so the check runs inside tier-1

@@ -4,7 +4,7 @@
 (gsttensor_converter.c: frames-per-tensor property batches N media frames
 into one tensor buffer).  TPU-first rationale: per-frame Python ingest and
 per-frame stacking cap pipeline throughput far below the chip's rate; a
-block pays those costs once per micro-batch (bench.py BENCH_INGEST=block).
+block pays those costs once per micro-batch.
 """
 
 import numpy as np

@@ -30,9 +30,34 @@ def _out_dir(tmp_path, monkeypatch):
     monkeypatch.setattr(chip_smoke, "OUT_DIR", str(tmp_path / "smoke"))
 
 
-def test_stream_phase_tiny():
+def test_stream_phase_tiny(monkeypatch):
+    tail = 3
+
+    def tail_apart(pipe, frames, timeout_s):
+        """``chip_smoke._run_labeling`` with the ragged tail pushed once the
+        full batches are out: on the chip the model's pace fills the
+        batches; here the scheduler may cut 19 frames 6 + 7 + 6, all of
+        one bucket, and the phase then (rightly) refuses the run."""
+        import time
+
+        head = len(frames) - tail
+        for f in frames[:head]:
+            pipe["src"].push(f)
+        deadline = time.monotonic() + timeout_s
+        while len(pipe["out"].frames) < head and time.monotonic() < deadline:
+            time.sleep(0.005)
+        for f in frames[head:]:
+            pipe["src"].push(f)
+        pipe["src"].end_of_stream()
+        pipe.wait(timeout=timeout_s)
+        out = pipe["out"].frames
+        assert len(out) == len(frames)
+        return [(int(np.asarray(fr.tensors[0]).reshape(-1)[0]),
+                 float(fr.meta["label_score"])) for fr in out]
+
+    monkeypatch.setattr(chip_smoke, "_run_labeling", tail_apart)
     r = chip_smoke.phase_stream(
-        platform="cpu", full_batches=2, tail=3, sample=4, **TINY_STREAM)
+        platform="cpu", full_batches=2, tail=tail, sample=4, **TINY_STREAM)
     assert r["frames"] == 19 and r["dtype"] == "float32"
     assert 8 in r["buckets_compiled"] and r["invokes"] >= 3
 

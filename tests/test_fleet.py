@@ -1006,11 +1006,6 @@ class TestFleetChaos:
         v = h.verdict()
         assert v["lost"] == 0 and v["duplicated"] == 0, v
         assert v["breaker_trips"] == 0, v
-        # bounded per-tenant p50 skew (loose CI bound: paced busy
-        # retries inflate the hot tenant, but never unboundedly)
-        p50 = v["p50_ms"]
-        if p50["A"] > 0 and p50["B"] > 0:
-            assert p50["B"] <= 30 * max(p50["A"], 1.0), p50
         # per-tenant ledgers stayed internally consistent fleet-wide
         tenants = v["tenants"]
         assert tenants["A"]["shed"] == 0

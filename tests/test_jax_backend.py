@@ -1,4 +1,5 @@
-"""jax-xla backend tests (CPU-forced via conftest; TPU path in bench.py)."""
+"""jax-xla backend tests (CPU-forced via conftest; the TPU path runs in
+chip_smoke.py and the benchmark cells)."""
 
 import numpy as np
 import pytest
@@ -160,7 +161,7 @@ class TestMobileNetV2:
     @pytest.mark.slow  # tier-1 budget: ~20s mobilenet compile; the
     # kws/mnist family forwards keep the zoo-backend path covered
     def test_forward_shapes_cpu(self):
-        # tiny input keeps CPU compile fast; real 224 path runs in bench.py
+        # tiny input keeps CPU compile fast; the real 224 size runs on the chip
         from nnstreamer_tpu.models import build
 
         fn, params, in_spec, out_spec = build(

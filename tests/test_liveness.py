@@ -188,9 +188,7 @@ class TestLatencyFaults:
         t0 = time.monotonic()
         FAULTS.check("t.delay")  # must NOT raise
         assert time.monotonic() - t0 >= 0.07
-        t0 = time.monotonic()
-        FAULTS.check("t.delay")  # times=1: second call is free
-        assert time.monotonic() - t0 < 0.05
+        FAULTS.check("t.delay")  # times=1: the second call fires nothing
         assert FAULTS.stats("t.delay") == {"calls": 2, "fired": 1}
 
     def test_hang_fault_interrupted_raises_stall(self):

@@ -111,23 +111,34 @@ class TestNativeMailbox:
         assert got == list(range(20))
         mb.close()
 
-    def test_wakeup_latency_beats_poll_loop(self):
-        # the point of the native condvar: a blocked get() wakes on put()
-        # immediately, not at the next 100ms poll tick
+    def test_blocked_get_is_one_native_wait_woken_by_put(self):
+        # the point of the native condvar: a blocked get() is ONE native
+        # wait, with no timeout to tick, that put() wakes -- not a loop of
+        # timed polls.  Counted at the library boundary, never timed.
         mb = runtime.NativeMailbox(1)
-        dt = []
+        pops, parked, got = [], threading.Event(), []
 
-        def consumer():
-            t0 = time.perf_counter()
-            mb.get(timeout=5)
-            dt.append(time.perf_counter() - t0)
+        class CountingLib:
+            def __init__(self, lib):
+                self._lib = lib
 
-        t = threading.Thread(target=consumer)
+            def __getattr__(self, name):
+                return getattr(self._lib, name)
+
+            def nns_oq_pop(self, handle, timeout, out):
+                pops.append(timeout)
+                parked.set()
+                return self._lib.nns_oq_pop(handle, timeout, out)
+
+        mb._lib = CountingLib(mb._lib)
+        t = threading.Thread(target=lambda: got.append(mb.get()))
         t.start()
-        time.sleep(0.2)  # consumer is parked in the native wait
+        assert parked.wait(timeout=5)  # the consumer entered the wait
+        assert got == []
         mb.put("x", timeout=1)
         t.join(timeout=5)
-        assert dt[0] >= 0.2 and dt[0] < 0.3  # woke ~immediately after put
+        assert got == ["x"]
+        assert pops == [-1.0]  # one call, unbounded: only put() ends it
         mb.close()
 
 

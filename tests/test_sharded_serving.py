@@ -138,7 +138,7 @@ _setup_module_guard()
 
 # ---------------------------------------------------------------------------
 # mesh= config grammar (parallel/mesh.py — the ONE grammar every surface
-# shares: filter/generator props, jax-xla backend, bench BENCH_MESH)
+# shares: filter/generator props, jax-xla backend)
 # ---------------------------------------------------------------------------
 class TestMeshSpecGrammar:
     def test_parse_valid(self):

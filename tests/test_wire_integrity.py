@@ -929,20 +929,22 @@ def test_fuzz_wire_fixed_seed_smoke():
     assert fuzz_wire.main(["--seed", "7", "--iterations", "10000", "-q"]) == 0
 
 
-@pytest.mark.perf
 def test_wire_checksum_overhead_is_measured():
-    """The integrity tax is measured, not guessed: the bench row exists,
-    and CRC verification sustains a sane floor (very generous bound —
-    zlib.crc32 does >1 GB/s on any modern core)."""
+    """The integrity tax is measured, not guessed: tools/bench_wire.py
+    produces one row per payload size with every documented key
+    (Documentation/wire-protocol.md "Cost"), and both envelope versions
+    completed their round trips."""
     sys.path.insert(0, str(Path(__file__).parent.parent / "tools"))
     try:
         import bench_wire
     finally:
         sys.path.pop(0)
     (row,) = bench_wire.run([65536], 200)
+    assert set(row) == {
+        "payload_bytes", "iters", "v1_rps", "v2_rps", "v2_noverify_rps",
+        "integrity_tax_pct", "verify_crc_mb_s"}
+    assert row["iters"] == 200 and row["payload_bytes"] > 65536
     assert row["v1_rps"] > 0 and row["v2_rps"] > 0
-    assert "integrity_tax_pct" in row
-    assert row["verify_crc_mb_s"] is None or row["verify_crc_mb_s"] >= 50
 
 
 def test_fuzz_marker_registered():

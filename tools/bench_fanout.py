@@ -21,8 +21,8 @@ measurement modes bound the story on localhost
             chip rate (>=1000 fps) for the transport to never be the pod
             bottleneck.
 
-Prints one JSON line per row and writes them all to BENCH_FANOUT.json
-(or argv[1]).
+Prints one JSON line per row; with a path as argv[1], also writes them
+all there as one JSON list.
 
 Env knobs:
   FANOUT_MODES     comma list of modes (default "sleepy,real,echo")
@@ -208,7 +208,7 @@ def main() -> int:
     jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
-    out_path = sys.argv[1] if len(sys.argv) > 1 else "BENCH_FANOUT.json"
+    out_path = sys.argv[1] if len(sys.argv) > 1 else None
     modes = [
         m.strip()
         for m in os.environ.get("FANOUT_MODES", "sleepy,real,echo").split(",")
@@ -230,8 +230,9 @@ def main() -> int:
         rows.append(row)
         # incremental write: a timeout/crash in a later (slower) mode
         # must not discard completed measurements
-        with open(out_path, "w") as f:
-            json.dump(rows, f, indent=2)
+        if out_path:
+            with open(out_path, "w") as f:
+                json.dump(rows, f, indent=2)
 
     for mode in modes:
         if mode == "echo":
@@ -299,7 +300,8 @@ def main() -> int:
                         f"{ncores}-core host: servers share cores, "
                         "efficiency is contention not transport")
             emit(row)
-    print(f"[bench_fanout] wrote {out_path}", file=sys.stderr)
+    if out_path:
+        print(f"[bench_fanout] wrote {out_path}", file=sys.stderr)
     return 0
 
 

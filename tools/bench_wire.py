@@ -76,17 +76,6 @@ def run(sizes, n_frames) -> list:
     return rows
 
 
-def measure_crc_bandwidth(size: int = 1 << 20, n_frames: int = 400) -> float:
-    """Effective verify-pass CRC bandwidth in MB/s at one payload size
-    (default 1 MiB, where the checksum pass dominates the zero-copy
-    decode).  Shared by the wire-integrity bench rows and the perf-truth
-    baseline (tools/perf_truth.py), so the published number and the
-    regression-gated one measure the SAME harness.  Returns 0.0 when the
-    verify pass is too cheap to resolve."""
-    rows = run([int(size)], n_frames)
-    return float(rows[0]["verify_crc_mb_s"] or 0.0)
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default="", help="write rows as JSON here")
