@@ -624,11 +624,14 @@ def _run_kernel(fn, args, interpret):
 def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
                   classes=1001, norm_shape=(128, 224, 224, 3),
                   attn_shapes=((2, 256, 4, 32), (2, 197, 3, 64)),
-                  attn_stream_shape=(2, 577, 16, 64)):
+                  attn_stream_shape=(2, 577, 16, 64),
+                  decode_shapes=((16, 1024, 20, 20, 64), (8, 4096, 32, 2, 128))):
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from nnstreamer_tpu.models.transformer import kv_attend_write
+    from nnstreamer_tpu.ops.decode_attention import decode_attention
     from nnstreamer_tpu.ops.flash_attention import (
         flash_attention, flash_attention_grad)
     from nnstreamer_tpu.ops.labeling import top1
@@ -677,6 +680,29 @@ def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
                 raise AssertionError(
                     f"flash {shape} causal={causal}: max err {err}")
             checked.append(f"flash{shape} causal={causal} err={err:.1e}")
+
+    # the per-token read of a slotted KV cache, bounded by fill: slots (B,
+    # max_seq S, H query heads on J KV heads of Dh) at fills from none to
+    # all, one idle, against the jnp form it stands in for
+    for B, S, H, J, Dh in decode_shapes:
+        mk = lambda *shape: jnp.asarray(
+            rng.normal(size=shape).astype(np.float32)).astype(jnp.bfloat16)
+        ck, cv = mk(B, S, J * Dh), mk(B, S, J * Dh)
+        q, k, v = mk(B, 1, H * Dh), mk(B, 1, J * Dh), mk(B, 1, J * Dh)
+        pos = jnp.asarray(rng.integers(0, S + 1, B), jnp.int32).at[0].set(S)
+        active = jnp.ones((B,), jnp.int32).at[1].set(0)
+        out = _run_kernel(
+            lambda ck, cv, q, k, v, n, interpret, H=H: decode_attention(
+                ck, cv, q, k, v, n, n_heads=H, interpret=interpret),
+            (ck, cv, q, k, v, jnp.where(active > 0, pos, 0)), interpret)
+        ref = jax.jit(lambda *a, H=H, J=J: kv_attend_write(
+            *a, H, n_kv_heads=J, single_device=False)[2])(ck, cv, q, k, v, pos)
+        live = np.asarray(active) > 0
+        err = float(np.max(np.abs(
+            np.asarray(out, np.float32) - np.asarray(ref, np.float32))[live]))
+        if not err < tol:
+            raise AssertionError(f"decode_attention {(B, S, H, J, Dh)}: max err {err}")
+        checked.append(f"decode_attention{(B, S, H, J, Dh)} err={err:.1e}")
 
     def loss_kernel(q, k, v, interpret):
         o = flash_attention_grad(q, k, v, True, 128, 128, interpret)

@@ -390,14 +390,16 @@ def mamba_mix(p, x, conv, ssm, cfg: HybridConfig, keep=None):
         return _mm(y, p["out_proj"]["kernel"], cfg.dtype), new_conv, new_ssm
 
 
-def attn_mix(p, x, ck, cv, pos, cfg: HybridConfig):
+def attn_mix(p, x, ck, cv, pos, cfg: HybridConfig, active=None):
     """Returns ``(out, ck, cv)``: grouped-query attention over the slot's
-    K/V rows plus the new rows, no positional encoding."""
+    K/V rows plus the new rows, no positional encoding.  ``active`` (B,):
+    a row with 0 reads none of its K/V rows in the per-token step."""
     with jax.named_scope("nns.attn"):
         q, k, v = (_mm(x, p[n]["kernel"], ck.dtype)
                    for n in ("q_proj", "k_proj", "v_proj"))
         ck, cv, attn = kv_attend_write(
-            ck, cv, q, k, v, pos, cfg.n_heads, n_kv_heads=cfg.n_kv_heads)
+            ck, cv, q, k, v, pos, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            active=active)
         return _mm(attn.astype(cfg.dtype), p["o_proj"]["kernel"], cfg.dtype), ck, cv
 
 
@@ -477,7 +479,8 @@ def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
             y, conv, ssm = mamba_mix(blk["mixer"], h, st["conv"], st["ssm"], cfg, keep)
             layers[str(i)] = {"conv": conv, "ssm": ssm}
         elif kind == "*":
-            y, ck, cv = attn_mix(blk["mixer"], h, st["k"], st["v"], rows["pos"], cfg)
+            y, ck, cv = attn_mix(
+                blk["mixer"], h, st["k"], st["v"], rows["pos"], cfg, active)
             layers[str(i)] = {"k": ck, "v": cv}
         else:
             y, c = moe_mix(blk["mixer"], h, cfg, live)

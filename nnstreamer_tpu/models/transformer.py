@@ -26,6 +26,7 @@ from jax.sharding import Mesh
 
 from ..core.types import FORMAT_STATIC, StreamSpec, TensorSpec
 from ._init_util import host_init
+from ..ops import decode_attention
 from ..parallel.ring_attention import reference_attention
 
 
@@ -48,7 +49,8 @@ class TransformerConfig:
     quant: bool = False
 
 
-def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None):
+def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None,
+                    active=None, single_device=True):
     """The ONE decode-cache step every generation path shares: attend
     over the cache leaves as they lie plus the new rows, then write the
     new rows into the leaves.
@@ -92,6 +94,22 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None):
     float32 copy of a leaf is ever made.  ``T > 1`` (prefill) splits the
     rows it reads into heads, which copies them once: small beside the
     chunk's matmuls, and without the H x.
+
+    **The per-token read is bounded by fill** where it can be: for ``T ==
+    1`` the rows to read are ``n[b] = min(pos[b], max_seq)`` for a live row
+    and 0 for one with ``active[b] == 0`` (``active`` (B,), None: every row
+    is live; such a row attends to its new row alone, and nothing reads its
+    output), and ``ops/decode_attention.py`` (device operations
+    ``nns_decode_attention``) copies ``ceil(n[b] / block)`` row blocks of
+    each leaf and no more, the same mathematics in one kernel.  It is taken
+    when the program is lowered for a TPU (``lax.platform_dependent``: the
+    choice follows the device the program is compiled for), compiled for
+    one device (``single_device=False``: the caller compiles for a mesh,
+    where a Mosaic call cannot be partitioned) and the leaves are whole
+    lane tiles wide with ``max_seq`` a multiple of the block
+    (``decode_attention.block_rows``).  Anything else, and ``T > 1`` (a
+    prefill chunk reads one slot's rows), is the jnp form below on every
+    platform; nothing declines the kernel quietly and no property chooses.
     """
     B, T, D = q.shape
     S = ck.shape[1]
@@ -101,19 +119,34 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None):
         preferred_element_type=jnp.float32,
     )
     scale = 1.0 / np.sqrt(Dh)
-    if (n_kv_heads or H) != H:
-        s_old, mix_old, s_new, mix_new = _gqa_scores(
-            ck, cv, q, k, v, H, n_kv_heads, dot)
+
+    def attend(ck, cv, q, k, v, pos):
+        if (n_kv_heads or H) != H:
+            s_old, mix_old, s_new, mix_new = _gqa_scores(
+                ck, cv, q, k, v, H, n_kv_heads, dot)
+        else:
+            s_old, mix_old, s_new, mix_new = _mha_scores(ck, cv, q, k, v, H, dot)
+        older = jnp.arange(S)[None, :] < pos[:, None]  # (B, S)
+        s_old = jnp.where(older[:, None, None], s_old * scale, -1e30)
+        s_new = jnp.where(jnp.tri(T, dtype=bool), s_new * scale, -1e30)
+        top = jnp.maximum(s_old.max(axis=-1), s_new.max(axis=-1))[..., None]
+        e_old, e_new = jnp.exp(s_old - top), jnp.exp(s_new - top)
+        total = e_old.sum(axis=-1) + e_new.sum(axis=-1)  # (B, H, T)
+        mix = mix_old(e_old) + mix_new(e_new)
+        attn = jnp.moveaxis(mix / total[..., None], 1, 2)  # (B, T, H, Dh)
+        return attn.reshape(B, T, D).astype(q.dtype)
+
+    if T == 1:
+        def bounded(ck, cv, q, k, v, pos):
+            return decode_attention.decode_attention(
+                ck, cv, q, k, v, decode_attention.live_rows(pos, active, S),
+                n_heads=H, interpret=decode_attention.INTERPRET)
+
+        attn = decode_attention.fill_bounded(
+            bounded, attend, ck, cv, q, k, v, pos,
+            leaf=ck, single_device=single_device)
     else:
-        s_old, mix_old, s_new, mix_new = _mha_scores(ck, cv, q, k, v, H, dot)
-    older = jnp.arange(S)[None, :] < pos[:, None]  # (B, S)
-    s_old = jnp.where(older[:, None, None], s_old * scale, -1e30)
-    s_new = jnp.where(jnp.tri(T, dtype=bool), s_new * scale, -1e30)
-    top = jnp.maximum(s_old.max(axis=-1), s_new.max(axis=-1))[..., None]
-    e_old, e_new = jnp.exp(s_old - top), jnp.exp(s_new - top)
-    total = e_old.sum(axis=-1) + e_new.sum(axis=-1)  # (B, H, T)
-    mix = mix_old(e_old) + mix_new(e_new)
-    attn = jnp.moveaxis(mix / total[..., None], 1, 2)  # (B, T, H, Dh)
+        attn = attend(ck, cv, q, k, v, pos)
 
     slot = jnp.arange(B)[:, None]
     rows = pos[:, None] + jnp.arange(T)[None, :]  # (B, T)
@@ -124,7 +157,7 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None):
             unique_indices=True,
         )
 
-    return write(ck, k), write(cv, v), attn.reshape(B, T, D).astype(q.dtype)
+    return write(ck, k), write(cv, v), attn
 
 
 def _mha_scores(ck, cv, q, k, v, H, dot):
@@ -212,6 +245,9 @@ class Block(nn.Module):
     # without touching neighbors) and the causal mask is per slot, so the
     # jitted step stays shape-stable as streams churn.
     slotted: bool = False
+    # False: compiled for a mesh, so the per-token read keeps the jnp form
+    # (kv_attend_write); whoever compiles the program says so
+    single_device: bool = True
 
     def _dense(self, features, name):
         from ._quant_flax import dense_or_quant
@@ -245,6 +281,7 @@ class Block(nn.Module):
             ck.value, cv.value, attn = kv_attend_write(
                 ck.value, cv.value, q, k, v,
                 jnp.broadcast_to(pos, (B,)), H,
+                active=active, single_device=self.single_device,
             )
             # idle slots (active=0) keep writing harmlessly into their
             # frozen position but never advance
@@ -281,6 +318,7 @@ class TransformerLM(nn.Module):
     seq_axis: str = "sp"
     decode: bool = False
     slotted: bool = False  # per-slot cache positions (continuous batching)
+    single_device: bool = True  # False: compiled for a mesh (see Block)
 
     @nn.compact
     def __call__(self, tokens, active=None):  # (B, T) int32
@@ -311,7 +349,8 @@ class TransformerLM(nn.Module):
         for i in range(cfg.n_layers):
             x = Block(
                 cfg, self.mesh, self.seq_axis, decode=self.decode,
-                slotted=self.slotted, name=f"block{i}",
+                slotted=self.slotted, single_device=self.single_device,
+                name=f"block{i}",
             )(x, active)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         logits = nn.Dense(cfg.vocab, use_bias=False, dtype=jnp.float32, name="lm_head")(
@@ -360,9 +399,11 @@ def make_generate(
     temperature: float = 0.0,
     top_k: int = 0,
     seed: int = 0,
+    single_device: bool = True,
 ):
     """KV-cache generation: ``gen(params, prompt (B,Tp)) ->
-    (B, Tp+max_new)``.
+    (B, Tp+max_new)``.  ``single_device=False``: the program is compiled
+    for a mesh (:func:`kv_attend_write` then keeps its jnp form).
 
     ``temperature=0`` (default) is greedy argmax decoding;
     ``temperature>0`` samples from softmax(logits/temperature),
@@ -381,7 +422,8 @@ def make_generate(
     compiled program.
     """
     prefill, decode_chunk = make_stream_generate(
-        cfg, temperature=temperature, top_k=top_k, seed=seed
+        cfg, temperature=temperature, top_k=top_k, seed=seed,
+        single_device=single_device,
     )
 
     def gen(params, prompt):  # (B, Tp) int32
@@ -444,6 +486,7 @@ def make_stream_generate(
     temperature: float = 0.0,
     top_k: int = 0,
     seed: int = 0,
+    single_device: bool = True,
 ):
     """Chunked KV-cache decoding for STREAMING serving: unlike
     :func:`make_generate` (whole completion in one traced program), this
@@ -462,7 +505,7 @@ def make_stream_generate(
     IDENTICAL to make_generate — the streamed token sequence is
     bit-equal to the one-shot path for the same seed.
     """
-    model_dec = TransformerLM(cfg, decode=True)
+    model_dec = TransformerLM(cfg, decode=True, single_device=single_device)
     pick = _make_pick(temperature, top_k)
     key0 = jax.random.PRNGKey(seed)
 
@@ -578,8 +621,11 @@ class SlotModel:
     left for jit to place by default.
     """
 
-    #: no counters of its own ride the decode read-back
-    counter_names = ()
+    #: ride the decode read-back: cache rows the dispatch's per-token reads
+    #: covered in one leaf, and the rows that leaf holds, summed over its
+    #: steps (``ops/decode_attention.py``; equal where the read is not
+    #: bounded by fill)
+    counter_names = ("gen_kv_rows_read", "gen_kv_rows_held")
     #: K/V rows below a position are immutable: a prefix can be cut out
     supports_prefix = True
 
@@ -591,7 +637,8 @@ class SlotModel:
             raise ValueError(f"slots must be >= 1, got {slots}")
         self.cfg = cfg
         self.slots = int(slots)
-        self._model = TransformerLM(cfg, decode=True, slotted=True)
+        self._model = TransformerLM(
+            cfg, decode=True, slotted=True, single_device=mesh is None)
         self._pick = _make_pick(temperature, top_k)
         self._temperature = temperature
         self._key0 = jax.random.PRNGKey(seed)
@@ -773,8 +820,15 @@ class SlotModel:
         return pick_slots(self._pick, self._key0, self._temperature, lg, gen)
 
     def _decode_scan(self, k, params, cache, tok, gen, active):
+        cfg = self.cfg
+        leaf = jax.ShapeDtypeStruct(
+            (self.slots, cfg.max_seq, cfg.d_model), cfg.dtype)
+
         def step(carry, _i):
-            cache, tok, gen = carry
+            cache, tok, gen, rows = carry
+            rows = rows + decode_attention.rows_read(
+                decode_attention.live_rows(cache["step"], active, cfg.max_seq),
+                leaf, single_device=self._model.single_device)
             logits, upd = self._model.apply(
                 {"params": params["params"], "cache": cache},
                 tok[:, None], mutable=["cache"], active=active,
@@ -784,17 +838,19 @@ class SlotModel:
             # scan is bit-transparent for every occupied row
             tok = jnp.where(active > 0, nxt, tok)
             gen = gen + active
-            return (upd["cache"], tok, gen), nxt
+            return (upd["cache"], tok, gen, rows), nxt
 
-        (cache, tok, gen), toks = jax.lax.scan(
-            step, (cache, tok, gen), jnp.arange(k)
+        (cache, tok, gen, rows), toks = jax.lax.scan(
+            step, (cache, tok, gen, jnp.int32(0)), jnp.arange(k)
         )
-        return cache, tok, gen, jnp.moveaxis(toks, 0, 1)  # (S, k)
+        counts = jnp.stack([rows, jnp.int32(k * self.slots * cfg.max_seq)])
+        return cache, tok, gen, jnp.moveaxis(toks, 0, 1), counts  # (S, k)
 
     def decode_fn(self, k: int):
         """One jitted decode bucket: ``k`` tokens for every active slot
         per dispatch (caller caches/bounds these alongside the prefill
-        buckets).  Returns ``(cache, tok, gen, toks (S, k))``."""
+        buckets).  Returns ``(cache, tok, gen, toks (S, k), counts)``;
+        ``counts`` follows :attr:`counter_names`."""
 
         def traced(params, cache, tok, gen, active):
             self.decode_compiles += 1  # trace-time only
@@ -838,7 +894,9 @@ def build(custom_props=None):
     """Zoo entry: fn(params, [tokens (B,T) or (T,)]) -> [logits].
 
     With custom prop ``generate:<N>`` the entry serves greedy KV-cache
-    generation instead: tokens in -> prompt+N completion tokens out.
+    generation instead: tokens in -> prompt+N completion tokens out; that
+    fn takes ``single_device`` from whoever compiles it (``models.
+    takes_single_device``), as the ViT does.
     """
     props = custom_props or {}
     cfg = _cfg_from_props(props)
@@ -852,20 +910,24 @@ def build(custom_props=None):
     in_spec = StreamSpec((TensorSpec((None,), np.int32, "tokens"),), FORMAT_STATIC)
 
     if max_new > 0:
-        gen = make_generate(
-            cfg,
-            max_new,
-            temperature=float(props.get("temperature", "0")),
-            top_k=int(props.get("top_k", "0")),
-            seed=int(props.get("gen_seed", "0")),
-        )
+        gens = {
+            one: make_generate(
+                cfg,
+                max_new,
+                temperature=float(props.get("temperature", "0")),
+                top_k=int(props.get("top_k", "0")),
+                seed=int(props.get("gen_seed", "0")),
+                single_device=one,
+            )
+            for one in (True, False)
+        }
 
-        def fn(p, inputs):
+        def fn(p, inputs, single_device: bool = True):
             toks = inputs[0]
             single = toks.ndim == 1
             if single:
                 toks = toks[None]
-            out = gen(p, toks)
+            out = gens[bool(single_device)](p, toks)
             return [out[0] if single else out]
 
         out_spec = StreamSpec(

@@ -84,9 +84,11 @@ def test_kernels_phase_tiny_runs_every_pallas_kernel_interpreted():
     r = chip_smoke.phase_kernels(
         platform="cpu", interpret=True, top1_batches=(1, 2, 8), classes=17,
         norm_shape=(2, 8, 8, 3), attn_shapes=((1, 32, 2, 8), (1, 21, 3, 8)),
-        attn_stream_shape=(1, 37, 4, 8))
+        attn_stream_shape=(1, 37, 4, 8),
+        decode_shapes=((3, 256, 4, 4, 32), (3, 128, 4, 2, 64)))
     names = " ".join(r["kernels"])
-    for kernel in ("top1", "normalize_u8", "flash(", "flash_grad"):
+    for kernel in ("top1", "normalize_u8", "flash(", "flash_grad",
+                   "decode_attention(3, 256", "decode_attention(3, 128"):
         assert kernel in names
 
 
@@ -110,7 +112,7 @@ def test_a_failing_phase_is_fatal():
         chip_smoke.phase_kernels(
             platform="tpu", interpret=True, top1_batches=(2,), classes=17,
             norm_shape=(1, 8, 8, 3), attn_shapes=((1, 16, 1, 8),),
-            attn_stream_shape=(1, 16, 1, 8))
+            attn_stream_shape=(1, 16, 1, 8), decode_shapes=())
 
 
 def test_main_refuses_to_run_without_a_tpu(capsys):

@@ -104,7 +104,7 @@ class TestSlotModelParity:
         gen = jnp.zeros((4,), jnp.int32).at[2].set(1)
         active = jnp.zeros((4,), jnp.int32).at[2].set(1)
         for k in (5, 4, 3):  # mixed scan buckets, 12 decode tokens
-            cache, tok, gen, toks = model.decode_fn(k)(
+            cache, tok, gen, toks, _counts = model.decode_fn(k)(
                 params, cache, tok, gen, active)
             got.append(np.asarray(toks)[2:3, :])
         np.testing.assert_array_equal(
@@ -134,7 +134,7 @@ class TestSlotModelParity:
         tok = jnp.zeros((2,), jnp.int32).at[0].set(t1[0])
         gen = jnp.zeros((2,), jnp.int32).at[0].set(1)
         active = jnp.zeros((2,), jnp.int32).at[0].set(1)
-        cache, tok, gen, toks = model.decode_fn(n - 1)(
+        cache, tok, gen, toks, _counts = model.decode_fn(n - 1)(
             params, cache, tok, gen, active)
         got.append(np.asarray(toks)[0:1])
         np.testing.assert_array_equal(np.concatenate(got, axis=1), want)

@@ -940,6 +940,79 @@ def test_slot_engine_dispatch_ledger(slots, streams, steps):
 
 
 # ---------------------------------------------------------------------------
+# The per-token read of the KV cache, bounded by fill
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("layers", [2, 3])
+def test_decode_scan_reads_the_cache_through_one_bounded_call_a_layer(
+        layers, monkeypatch):
+    """With the fill-bounded kernel forced (ops/decode_attention.py, as a
+    program lowered for one TPU takes it): the scan's body holds exactly
+    one ``nns_decode_attention`` call a layer, and no ``dot_general``
+    anywhere has a whole cache leaf for an operand: the leaves are read by
+    those calls and by nothing else."""
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.models.transformer import build_slot_stream
+    from nnstreamer_tpu.ops import decode_attention
+    from test_slot_cache_path import _eqns
+
+    monkeypatch.setattr(decode_attention, "INTERPRET", True)
+    model, params, _ = build_slot_stream(
+        {"dtype": "bfloat16", "vocab": "61", "d_model": "128", "heads": "2",
+         "layers": str(layers), "d_ff": "64", "seq": "384", "seed": "11"}, 4)
+    vec = jnp.ones((4,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: model._decode_scan(3, *a))(
+        params, model.init_cache(), vec, vec, vec)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["nns_decode_attention"] * layers
+    leaf = 4 * 384 * 128
+    whole = [e for e in eqns if e.primitive.name == "dot_general"
+             and any(getattr(v.aval, "size", 0) >= leaf for v in e.invars)]
+    assert not whole, whole
+
+
+def test_kv_rows_read_counts_whole_blocks_of_live_slots_only(monkeypatch):
+    """One stream of 8 prompt tokens and 9 new ones through 4 slots of 384
+    positions, kernel forced: its 8 decode steps (one dispatch) read the
+    one 128-row block the stream has filled, 8 x 128 rows, of the 8 x 4 x
+    384 the leaf holds; the three idle slots read nothing.  Without the
+    kernel (this CPU, not forced) every step reads every row."""
+    from nnstreamer_tpu.core.slots import SlotEngine
+    from nnstreamer_tpu.models.transformer import build_slot_stream
+    from nnstreamer_tpu.ops import decode_attention
+
+    def serve():
+        model, params, max_seq = build_slot_stream(
+            {"dtype": "float32", "vocab": "61", "d_model": "128", "heads": "2",
+             "layers": "2", "d_ff": "64", "seq": "384", "seed": "11"}, 4)
+        eng = SlotEngine(model, params, max_seq=max_seq, chunk=8, name="kvrows")
+        prompt = np.arange(8, dtype=np.int32)[None] % 61
+        eng.submit(TensorFrame([prompt]), prompt, max_new=9, chunk=8)
+        eng.start()
+        outs = []
+        try:
+            def final():
+                outs.extend(f for _pad, f in eng.pop_ready())
+                return any(f.meta["final"] for f in outs)
+
+            assert _until(final, timeout=120)
+            return eng.snapshot(), _tokens_by_stream(outs)
+        finally:
+            eng.stop()
+
+    plain, want = serve()
+    assert plain["gen_decode_steps"] == 1 and plain["gen_tokens"] == 9
+    assert plain["gen_kv_rows_read"] == plain["gen_kv_rows_held"] == 8 * 4 * 384
+    monkeypatch.setattr(decode_attention, "INTERPRET", True)
+    bounded, got = serve()
+    assert got == want
+    assert bounded["gen_kv_rows_held"] == 8 * 4 * 384
+    assert bounded["gen_kv_rows_read"] == 8 * 128
+
+
+# ---------------------------------------------------------------------------
 # The shared-prefix cache's ledger
 # ---------------------------------------------------------------------------
 def test_prefix_cache_ledger_cold_then_warm():

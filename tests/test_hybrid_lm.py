@@ -421,8 +421,104 @@ def test_the_cells_decode_program_compiles_for_a_v5e_and_fits_its_memory(one_chi
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 7
     assert "ragged" not in text
+    # the two attention layers read their K/V rows through the fill-bounded
+    # kernel, and nothing but the in-place row writes makes a leaf
+    assert len(set(re.findall(r"%nns_decode_attention[.\d]* =", text))) == 2
+    assert _leaf_makers(text, "bf16[32,4096,256]") == {"scatter": 4}
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13 << 30
     assert mem.temp_size_in_bytes < 256 << 20   # no whole-stack copy of the experts
+
+
+# ---------------------------------------------------------------------------
+# the dense cell's decode step through the chip's compiler (models/
+# transformer.py, ops/decode_attention.py; here for the v5e fixture)
+# ---------------------------------------------------------------------------
+def _leaf_makers(text, leaf):
+    """Instructions of a compiled program whose output is a cache leaf, by
+    what they are: ``{"scatter": n}`` when only the row writes make one
+    (parameters, tuple plumbing and the loop itself aside)."""
+    made = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if not m or not m.group(1).startswith(leaf):
+            continue
+        op = m.group(2)
+        if op in ("parameter", "get-tuple-element", "bitcast", "scatter"):
+            continue  # a bare scatter is the body of a fusion counted below
+        if op == "fusion" and re.search(r'op_name="[^"]*/scatter"', line):
+            op = "scatter"
+        made[op] = made.get(op, 0) + 1
+    return made
+
+
+@pytest.mark.parametrize("leaf,heads", [((16, 1024, 1280), 20), ((32, 4096, 256), 32)],
+                         ids=["gpt2_large", "nemotron3_nano"])
+def test_the_decode_attention_kernel_compiles_for_a_v5e_at_both_cells_leaves(
+        one_chip, leaf, heads):
+    """Mosaic takes the kernel at the dense cell's leaves (20 heads of 64,
+    multi-head) and at the hybrid cell's (32 query heads on 2 KV heads of
+    128), and no temporary the size of a leaf stands beside it."""
+    from nnstreamer_tpu.ops.decode_attention import decode_attention
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, _, W = leaf
+    head_dim = 64 if heads == 20 else 128
+    compiled = decode_attention.lower(
+        arg(leaf), arg(leaf), arg((B, 1, heads * head_dim)), arg((B, 1, W)),
+        arg((B, 1, W)), arg((B,), jnp.int32), n_heads=heads).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def _dense_decode_program(sharding_of, layers=2, slots=16, **model_kw):
+    """The dense family's ``k = 8`` decode scan at GPT-2-large's widths,
+    cut to ``layers``, lowered on shapes placed by ``sharding_of``."""
+    from nnstreamer_tpu.models.transformer import (
+        SlotModel, TransformerConfig, TransformerLM)
+
+    cfg = TransformerConfig(vocab=50257, d_model=1280, n_heads=20,
+                            n_layers=layers, d_ff=5120, max_seq=1024)
+    model = SlotModel(cfg, slots, donate=True, **model_kw)
+
+    def placed(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding_of), tree)
+
+    params = placed(jax.eval_shape(
+        TransformerLM(cfg).init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    cache = placed(jax.eval_shape(lambda: model._model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32))["cache"]))
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=sharding_of)
+    return model.decode_fn(8).lower(params, cache, vec, vec, vec)
+
+
+def test_the_dense_cells_decode_step_reads_its_leaves_through_the_kernel(one_chip):
+    """``gpt2l_chat_closed16``'s decode program at its full depth: one
+    ``nns_decode_attention`` call a layer inside the scan, and nothing but
+    the two in-place row writes a layer has an output the size of a leaf.
+    The leaf is the scan's donated carry, and a custom call's operand: XLA
+    could copy it to write beside the read, and its memory-space assignment
+    did stage 8 of the 72 whole through VMEM before the call until the call
+    reserved the room (``decode_attention._vmem_limit``); which leaves it
+    picks depends on the whole schedule, hence all 36 layers."""
+    text = _dense_decode_program(
+        one_chip, layers=36, device=one_chip._device).compile().as_text()
+    assert len(set(re.findall(r"%nns_decode_attention[.\d]* =", text))) == 36
+    assert _leaf_makers(text, "bf16[16,1024,1280]") == {"scatter": 72}
+
+
+def test_the_dense_decode_step_under_a_mesh_holds_no_custom_call(v5e):
+    """``SlotModel(mesh=...)``: a Mosaic call cannot be partitioned, so the
+    per-token read keeps its jnp form and the program compiles for four
+    chips with no custom call in it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(v5e.devices).reshape(4), ("tp",))
+    text = _dense_decode_program(
+        NamedSharding(mesh, P()), slots=4, mesh=mesh).compile().as_text()
+    assert "tpu_custom_call" not in text and "nns_decode_attention" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -588,4 +684,5 @@ def test_the_three_slot_models_satisfy_the_one_protocol(served):
     for model in (served[0], dense, SimSlotModel(2)):
         assert isinstance(model, SlotModelProtocol), type(model)
     assert served[0].counter_names == H.COUNTER_NAMES and not served[0].supports_prefix
-    assert dense.counter_names == () and dense.supports_prefix
+    assert dense.counter_names == ("gen_kv_rows_read", "gen_kv_rows_held")
+    assert dense.supports_prefix

@@ -27,6 +27,7 @@ from nnstreamer_tpu.models.transformer import (
     build_slot_stream,
     kv_attend_write,
 )
+from nnstreamer_tpu.ops import decode_attention
 
 PROPS = {
     "dtype": "float32", "vocab": 61, "d_model": 32, "heads": 2,
@@ -84,11 +85,15 @@ class TestAttendWriteHelper:
             np.testing.assert_array_equal(
                 np.asarray(new, np.float32), want_leaf)
 
-    def test_probabilities_are_not_rounded(self, rng):
+    @pytest.mark.parametrize("form", ["xla", "kernel"])
+    def test_probabilities_are_not_rounded(self, rng, form, monkeypatch):
         """bf16 leaves, float32 softmax: against the dense float32 oracle
         BEFORE the output is rounded the error is float32-sized, far under
-        what rounding p to bf16 would leave (4e-3 of a value)."""
-        B, S, H, D = 2, 40, 4, 32
+        what rounding p to bf16 would leave (4e-3 of a value).  The jnp
+        form, and the fill-bounded kernel (ops/decode_attention.py) forced
+        and interpreted at a shape it takes: one bound for both."""
+        B, S, H, D = (2, 40, 4, 32) if form == "xla" else (2, 256, 4, 128)
+        monkeypatch.setattr(decode_attention, "INTERPRET", form == "kernel")
         mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.bfloat16)
         ck, cv, q, k, v = mk(B, S, D), mk(B, S, D), mk(B, 1, D), mk(B, 1, D), mk(B, 1, D)
         pos = np.asarray([S - 1, 17], np.int32)
@@ -131,7 +136,7 @@ class TestSlottedScan:
         tok = jnp.asarray([s[-1] for s in seqs], jnp.int32)
         gen = jnp.ones((4,), jnp.int32)
         active = jnp.ones((4,), jnp.int32).at[idle].set(0)
-        scan_cache, *_, scan_toks = model.decode_fn(3)(
+        scan_cache, _tok, _gen, scan_toks, _counts = model.decode_fn(3)(
             params, jax.tree.map(jnp.copy, cache), tok, gen, active)
         before = _leaves(cache)
         for step in range(3):
@@ -181,15 +186,22 @@ def _eqns(jaxpr):
 
 
 class TestProgramStructure:
+    @pytest.mark.parametrize("form", ["xla", "kernel"])
     @pytest.mark.parametrize("layers", [2, 3])
-    def test_decode_step_touches_a_leaf_by_scatter_alone(self, layers):
+    def test_decode_step_touches_a_leaf_by_scatter_alone(
+            self, layers, form, monkeypatch):
         """In one decode step nothing but the row scatter has an output
         the size of a cache leaf (no select over the cache, no float32 or
-        transposed copy), and there are exactly two scatters a layer."""
-        model, params, _ = build_slot_stream(
-            {**SPROPS, "layers": str(layers), "dtype": "bfloat16"}, 4)
+        transposed copy), and there are exactly two scatters a layer: with
+        the jnp read, and with the fill-bounded kernel forced (leaves it
+        takes: 128 lanes, 128 rows), whose one call a layer reads the
+        leaves and returns a row a slot."""
+        shape = {} if form == "xla" else {"d_model": "128", "seq": "128"}
+        monkeypatch.setattr(decode_attention, "INTERPRET", form == "kernel")
+        props = {**SPROPS, **shape, "layers": str(layers), "dtype": "bfloat16"}
+        model, params, _ = build_slot_stream(props, 4)
         cache = model.init_cache()
-        leaf = (4, MAX_SEQ, PROPS["d_model"])
+        leaf = (4, int(props["seq"]), int(props["d_model"]))
         assert {c.shape for c in jax.tree.leaves(cache) if c.ndim > 1} == {leaf}
 
         def step(params, cache, tok, active):
@@ -207,6 +219,10 @@ class TestProgramStructure:
             and e.primitive.name not in ("pjit", "closed_call", "core_call")
         ]
         assert writers == ["scatter"] * (2 * layers), writers
+        kernels = [e for e in _eqns(jaxpr.jaxpr)
+                   if e.primitive.name == "pallas_call"]
+        assert len(kernels) == (layers if form == "kernel" else 0)
+        assert {e.params["name"] for e in kernels} <= {"nns_decode_attention"}
 
     @pytest.mark.parametrize("n", [4, 32])
     def test_prefill_chunk_holds_no_loop(self, n):
