@@ -625,13 +625,18 @@ def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
                   classes=1001, norm_shape=(128, 224, 224, 3),
                   attn_shapes=((2, 256, 4, 32), (2, 197, 3, 64)),
                   attn_stream_shape=(2, 577, 16, 64),
-                  decode_shapes=((16, 1024, 20, 20, 64), (8, 4096, 32, 2, 128))):
+                  decode_shapes=((16, 1024, 20, 20, 64), (8, 4096, 32, 2, 128)),
+                  ring_shapes=((16, 4096, 128, 8, 128),),
+                  chunk_shapes=((False, 1024, 16384, 128, 8, 128), (True, 1024, 4096, 128, 8, 128)),
+                  expert_shapes=((16, 4096, 4096, 16), (1024, 4096, 4096, 16))):
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from nnstreamer_tpu.models.transformer import kv_attend_write
-    from nnstreamer_tpu.ops.decode_attention import decode_attention
+    from nnstreamer_tpu.models.transformer import _attend_blocked, kv_attend_write
+    from nnstreamer_tpu.ops.chunk_attention import chunk_attention
+    from nnstreamer_tpu.ops.decode_attention import decode_attention, ring_skip
+    from nnstreamer_tpu.ops.expert_ffn import touched_experts_ffn
     from nnstreamer_tpu.ops.flash_attention import (
         flash_attention, flash_attention_grad)
     from nnstreamer_tpu.ops.labeling import top1
@@ -684,25 +689,80 @@ def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
     # the per-token read of a slotted KV cache, bounded by fill: slots (B,
     # max_seq S, H query heads on J KV heads of Dh) at fills from none to
     # all, one idle, against the jnp form it stands in for
-    for B, S, H, J, Dh in decode_shapes:
-        mk = lambda *shape: jnp.asarray(
-            rng.normal(size=shape).astype(np.float32)).astype(jnp.bfloat16)
+    # ``ring``: a window layer's leaf, written round (its S rows are the
+    # window): positions run past S, and a full leaf's step leaves out the
+    # row the new token overwrites
+    mk = lambda *shape: jnp.asarray(
+        rng.normal(size=shape).astype(np.float32)).astype(jnp.bfloat16)
+    for ring, (B, S, H, J, Dh) in [(False, s) for s in decode_shapes] + [
+            (True, s) for s in ring_shapes]:
         ck, cv = mk(B, S, J * Dh), mk(B, S, J * Dh)
         q, k, v = mk(B, 1, H * Dh), mk(B, 1, J * Dh), mk(B, 1, J * Dh)
-        pos = jnp.asarray(rng.integers(0, S + 1, B), jnp.int32).at[0].set(S)
+        pos = jnp.asarray(rng.integers(0, (3 if ring else 1) * S + 1, B),
+                          jnp.int32).at[0].set(S)
         active = jnp.ones((B,), jnp.int32).at[1].set(0)
+        n = jnp.where(active > 0, jnp.minimum(pos, S), 0)
         out = _run_kernel(
-            lambda ck, cv, q, k, v, n, interpret, H=H: decode_attention(
-                ck, cv, q, k, v, n, n_heads=H, interpret=interpret),
-            (ck, cv, q, k, v, jnp.where(active > 0, pos, 0)), interpret)
-        ref = jax.jit(lambda *a, H=H, J=J: kv_attend_write(
-            *a, H, n_kv_heads=J, single_device=False)[2])(ck, cv, q, k, v, pos)
+            lambda ck, cv, q, k, v, n, pos, interpret, H=H, ring=ring: decode_attention(
+                ck, cv, q, k, v, n, n_heads=H, interpret=interpret,
+                skip=ring_skip(pos, ck.shape[1]) if ring else None),
+            (ck, cv, q, k, v, n, pos), interpret)
+        ref = jax.jit(lambda *a, H=H, J=J, ring=ring: kv_attend_write(
+            *a, H, n_kv_heads=J, single_device=False, ring=ring)[2])(ck, cv, q, k, v, pos)
         live = np.asarray(active) > 0
         err = float(np.max(np.abs(
             np.asarray(out, np.float32) - np.asarray(ref, np.float32))[live]))
+        name = f"decode_attention{(B, S, H, J, Dh)}" + (" ring" if ring else "")
         if not err < tol:
-            raise AssertionError(f"decode_attention {(B, S, H, J, Dh)}: max err {err}")
-        checked.append(f"decode_attention{(B, S, H, J, Dh)} err={err:.1e}")
+            raise AssertionError(f"{name}: max err {err}")
+        checked.append(f"{name} err={err:.1e}")
+
+    # a prefill chunk's attention over one slot's rows of a global leaf and
+    # of a round window leaf, bounded by fill, against the blocked jnp form
+    for ring, T, S, H, J, Dh in chunk_shapes:
+        ck, cv = mk(2, S, J * Dh), mk(2, S, J * Dh)
+        q, k, v = mk(2, T, H * Dh), mk(2, T, J * Dh), mk(2, T, J * Dh)
+        pos = jnp.asarray([S // 4 + 3, (3 if ring else 1) * S - T], jnp.int32)
+        out = _run_kernel(
+            lambda ck, cv, q, k, v, pos, interpret, H=H, ring=ring: chunk_attention(
+                ck, cv, q, k, v, pos, n_heads=H, ring=ring, interpret=interpret),
+            (ck, cv, q, k, v, pos), interpret)
+        ref = jax.jit(functools.partial(_attend_blocked, H=H, J=J, ring=ring))(
+            ck, cv, q, k, v, pos)
+        err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))
+        name = f"chunk_attention{(T, S, H, J, Dh)}" + (" ring" if ring else "")
+        if not err < tol:
+            raise AssertionError(f"{name}: max err {err}")
+        checked.append(f"{name} err={err:.1e}")
+
+    # the small-batch expert kernel in its gated form (three matrices an
+    # expert): a decode step's rows and a prefill chunk past one call's
+    # rows, top-2 of the held experts, against a loop over the experts
+    for M, D, F, held in expert_shapes:
+        x = mk(M, D)
+        wg, wu, wd = (mk(held, *shape) * (shape[0] ** -0.5)
+                      for shape in ((D, F), (D, F), (F, D)))
+        chosen = jnp.asarray(np.stack([rng.permutation(held)[:2] for _ in range(M)]))
+        gates = jnp.sum(jnp.where(
+            chosen[:, :, None] == jnp.arange(held)[None, None, :], 0.5, 0.0), axis=1)
+        out = _run_kernel(
+            lambda x, g, up, down, gate_w, interpret: touched_experts_ffn(
+                x, g, up, down, gate_w, interpret=interpret),
+            (x, gates, wu, wd, wg), interpret)
+
+        def loop(x, gates, wu, wd, wg):
+            def one(acc, e):
+                mm = functools.partial(jnp.matmul, preferred_element_type=jnp.float32)
+                hid = (jax.nn.silu(mm(x, wg[e])) * mm(x, wu[e])).astype(x.dtype)
+                return acc + gates[:, e, None] * mm(hid, wd[e]), None
+
+            return jax.lax.scan(one, jnp.zeros((M, D), jnp.float32), jnp.arange(held))[0]
+
+        ref = jax.jit(loop)(x, gates, wu, wd, wg)
+        err = float(jnp.max(jnp.abs(out - ref)))
+        if not err < tol:
+            raise AssertionError(f"touched_experts_ffn gated {(M, D, F, held)}: max err {err}")
+        checked.append(f"touched_experts_ffn gated{(M, D, F, held)} err={err:.1e}")
 
     def loss_kernel(q, k, v, interpret):
         o = flash_attention_grad(q, k, v, True, 128, 128, interpret)

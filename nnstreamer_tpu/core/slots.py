@@ -371,6 +371,11 @@ class SlotModelProtocol(Protocol):
       integer per name, summed since the last dispatch took them: the
       engine adds them to always-on counters of those names in
       :meth:`SlotEngine.snapshot`, on the read-back it makes anyway;
+    * ``prefill_counts(pos, n) -> {name: int}`` — what a chunk of ``n``
+      tokens at position ``pos`` adds to those of ``counter_names`` that
+      follow from the position alone (Python integers, no device read:
+      such a count grows with the square of the context); ``{}`` where
+      there is none;
     * ``decode_compiles`` / ``prefill_compiles`` — trace counts (the
       shape-stability contract is observable);
     * ``place_params(params)`` — stage a parameter tree where the model
@@ -391,6 +396,8 @@ class SlotModelProtocol(Protocol):
     def reset_slot(self, cache, slot) -> Any: ...
 
     def prefill_fn(self, n: int) -> Callable[..., Any]: ...
+
+    def prefill_counts(self, pos: int, n: int) -> Dict[str, int]: ...
 
     def pick_first(self, logits) -> Any: ...
 
@@ -501,6 +508,10 @@ class SimSlotModel:
         cache["pos"][int(slot)] = np.int64(n)
         self._prefill_carry[int(slot)] = int(pages_list[-1]["carry"])
         return cache
+
+    @staticmethod
+    def prefill_counts(pos: int, n: int):
+        return {}
 
     def prefill_fn(self, n: int):
         np = self._np
@@ -680,6 +691,7 @@ class SlotEngine:
         self.cancellations = 0
         self.decode_steps = 0
         self.prefill_chunks = 0
+        self.prefill_tokens = 0     # prompt tokens those chunks held
         self.tokens_total = 0
         self.tokens_per_step = 0.0  # EWMA of active slots per decode step
         self.resumes = 0            # streams joined via a RESUME request
@@ -835,7 +847,8 @@ class SlotEngine:
     #: never moving backwards
     _LEDGER_ATTRS = (
         "joins", "completions", "evictions", "cancellations",
-        "decode_steps", "prefill_chunks", "tokens_total", "resumes",
+        "decode_steps", "prefill_chunks", "prefill_tokens", "tokens_total",
+        "resumes",
         "goaway_evicted", "oom_retries", "oom_sheds", "device_lost",
         "device_lost_evicted", "remeshes", "admit_wait_s", "first_tokens",
         "lane_wait_s", "pump_host_s",
@@ -916,6 +929,7 @@ class SlotEngine:
                 "gen_tokens": self.tokens_total,
                 "gen_decode_steps": self.decode_steps,
                 "gen_prefill_chunks": self.prefill_chunks,
+                "gen_prefill_tokens": self.prefill_tokens,
                 "gen_tokens_per_step": round(self.tokens_per_step, 3),
                 "gen_jit_buckets": (
                     len(self._prefill_lru) + len(self._decode_lru)),
@@ -1560,6 +1574,10 @@ class SlotEngine:
                 self._publish_prefix(s, int(s.slot))
             with self._lock:
                 self.prefill_chunks += 1
+                self.prefill_tokens += n
+                for name, c in self.model.prefill_counts(
+                        s.prefill_pos - n, n).items():
+                    self.model_counts[name] += c
             if s.prefill_pos < tp:
                 return
             if s.resume_gen:

@@ -203,6 +203,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "nns.gen.tokens": ("counter", "tokens decoded across all slots"),
     "nns.gen.decode_steps": ("counter", "slot-batch decode steps"),
     "nns.gen.prefill_chunks": ("counter", "chunked-prefill pieces interleaved"),
+    "nns.gen.prefill_tokens": ("counter", "prompt tokens those pieces held"),
     "nns.gen.tokens_per_step": ("gauge", "EWMA active slots per decode step"),
     "nns.gen.jit_buckets": ("gauge", "live decode/prefill compile buckets (LRU-bounded)"),
     "nns.gen.decode_compiles": ("counter", "slotted decode-step retraces (shape churn)"),
@@ -253,6 +254,10 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "nns.gen.moe_layer_steps": ("counter", "(expert layer, step) pairs counted: decode steps and prefill chunks"),
     "nns.gen.moe_prefill_local": ("counter", "the prefill chunks' part of moe_local"),
     "nns.gen.moe_prefill_reads": ("counter", "the prefill chunks' part of moe_expert_reads"),
+    "nns.gen.kv_rows_need": ("counter", "K/V cache rows the decode steps needed by position and window, over attention layers and live slots"),
+    "nns.gen.kv_rows_read": ("counter", "K/V cache rows the decode steps' reads covered"),
+    "nns.gen.kv_rows_held": ("counter", "K/V cache rows the leaves held, summed over the same steps"),
+    "nns.gen.kv_prefill_rows_need": ("counter", "keys the prefill chunks' queries saw by position and window, their own counted"),
 
     # -- memory-pressure watermarks (core/liveness.py monitor) -------------
     "nns.mem.bytes_in_use": ("gauge", "device HBM bytes in use (most-loaded chip)"),
@@ -445,6 +450,7 @@ HEALTH_KEY_METRICS: Dict[str, str] = {
     "gen_tokens": "nns.gen.tokens",
     "gen_decode_steps": "nns.gen.decode_steps",
     "gen_prefill_chunks": "nns.gen.prefill_chunks",
+    "gen_prefill_tokens": "nns.gen.prefill_tokens",
     "gen_tokens_per_step": "nns.gen.tokens_per_step",
     "gen_jit_buckets": "nns.gen.jit_buckets",
     "gen_decode_compiles": "nns.gen.decode_compiles",
@@ -491,6 +497,14 @@ HEALTH_KEY_METRICS: Dict[str, str] = {
     "gen_moe_layer_steps": "nns.gen.moe_layer_steps",
     "gen_moe_prefill_local": "nns.gen.moe_prefill_local",
     "gen_moe_prefill_reads": "nns.gen.moe_prefill_reads",
+    # K/V cache rows, handed over the same way: rows a decode step needs by
+    # position and window, rows its reads covered, rows held, keys the
+    # prefill chunks' queries saw (models/hybrid_lm.py KV_COUNTER_NAMES; the
+    # dense model hands over read and held)
+    "gen_kv_rows_need": "nns.gen.kv_rows_need",
+    "gen_kv_rows_read": "nns.gen.kv_rows_read",
+    "gen_kv_rows_held": "nns.gen.kv_rows_held",
+    "gen_kv_prefill_rows_need": "nns.gen.kv_prefill_rows_need",
     # memory-pressure watermarks (serversrc health row)
     "mem_bytes_in_use": "nns.mem.bytes_in_use",
     "mem_bytes_limit": "nns.mem.bytes_limit",

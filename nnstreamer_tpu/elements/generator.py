@@ -80,8 +80,9 @@ class TensorGenerator(Element):
             str, "",
             "zoo-transformer dialect: vocab:N,d_model:N,heads:N,layers:N,"
             "d_ff:N,seq:N,seed:N[,temperature:F,top_k:N,gen_seed:N]; "
-            "arch:nemotron_h selects the hybrid family (layers:<pattern "
-            "of M, E, *> and its widths: Documentation/examples.md)",
+            "arch:nemotron_h or arch:cohere2_moe selects the hybrid family "
+            "(layers:<pattern of M, E, *, W, parallel blocks in parentheses> "
+            "and its widths: Documentation/examples.md)",
         ),
         "max-new": Property(int, 32, "tokens to generate per prompt"),
         "chunk": Property(int, 8, "tokens per streamed chunk frame"),
@@ -293,7 +294,7 @@ class TensorGenerator(Element):
                     raise ElementError(
                         f"{self.name}: prefix-cache=on is not served for "
                         f"arch:{family}: a recurrent state cannot be cut "
-                        "by position")
+                        "by position, nor a window leaf written round")
             if sim and mesh is not None:
                 raise ElementError(
                     f"{self.name}: mesh= needs the real transformer "
@@ -674,16 +675,14 @@ class TensorGenerator(Element):
     @staticmethod
     def _zoo_family(props):
         """(family name, module) of the model the ``custom=`` dialect
-        names: ``arch:nemotron_h`` is the hybrid family
-        (models/hybrid_lm.py); anything else is the dense transformer.
-        The ONE place a family is chosen; a module gives
-        ``build_slot_stream`` and ``resume_fields``."""
-        if props.get("arch") == "nemotron_h":
-            from ..models import hybrid_lm
+        names: ``arch:nemotron_h`` and ``arch:cohere2_moe`` are the hybrid
+        family (models/hybrid_lm.py, ``FAMILIES``); anything else is the
+        dense transformer.  The ONE place a family is chosen; a module
+        gives ``build_slot_stream`` and ``resume_fields``."""
+        from ..models import hybrid_lm, transformer
 
-            return hybrid_lm.FAMILY, hybrid_lm
-        from ..models import transformer
-
+        if props.get("arch") in hybrid_lm.FAMILIES:
+            return props["arch"], hybrid_lm
         return "zoo", transformer
 
     def _build_zoo_slot_model(self, props, slots: int, mesh):

@@ -1,8 +1,12 @@
-"""Hybrid decoder LM for the slotted generation path: one mixer per block,
-chosen by a pattern string (the ``nemotron_h`` family).
+"""Hybrid decoder LM for the slotted generation path: the mixers of every
+block chosen by a pattern string (the ``nemotron_h`` and ``cohere2_moe``
+families; :data:`FAMILIES` holds what differs between them as config fields).
 
-Every block is ``x <- x + Mixer(RMSNorm(x))``; the pattern names the mixer
-of each block by one letter:
+A block is ``x <- x + Mixer(Norm(x))``, or, for letters in parentheses, a
+PARALLEL block ``h = Norm(x); x <- x + sum of Mixer_j(h)``: one norm, every
+mixer of the group reads the same ``h``, one residual add (``(WE)(WE)(WE)(*E)``
+is one period of ``cohere2_moe``).  ``Norm`` is RMSNorm or the mean-centred,
+weight-only LayerNorm (``norm``).  The pattern names each mixer by one letter:
 
 * ``M`` — Mamba-2: ``[z | xBC | dt] = x W_in``; a causal depthwise
   convolution and ``silu`` over ``xBC``; ``h_t = exp(dt_t A) h_{t-1} + dt_t
@@ -13,19 +17,28 @@ of each block by one letter:
   scan.
 * ``*`` — grouped-query attention without positional encoding, through
   :func:`~nnstreamer_tpu.models.transformer.kv_attend_write` (the one cache
-  step every generation path shares).
+  step every generation path shares): the GLOBAL layer, its K/V leaves hold
+  ``max_seq`` rows by position.
+* ``W`` — the same attention as a WINDOW layer: rotary positions on ``q`` and
+  ``k`` before the cache write (interleaved pairs, angles in float32 from the
+  absolute position, computed in the program), a query at position ``p``
+  sees ``p - window + 1 .. p``, and its leaves hold ``window`` rows written
+  round (position ``p`` at row ``p mod window``).
 * ``E`` — routed experts: sigmoid router in float32 over ALL ``experts``,
-  top-``top_k`` of score + bias, weights normalised over the chosen and
-  scaled; an expert is ``relu(x W_up)^2 W_down``; one shared expert runs for
-  every token.  The layer is TOLD which experts it holds
+  top-``top_k`` of score (+ bias where the family has one), weights
+  normalised over the chosen and scaled; an expert is ``relu(x W_up)^2
+  W_down`` or, ``expert_act = silu_gated``, ``(silu(x W_gate) * (x W_up))
+  W_down``; ``shared_experts`` shared experts of width ``d_shared`` run for
+  every token, summed or averaged (kept side by side as ONE FFN).  The layer is TOLD which experts it holds
   (``[expert_offset, expert_offset + experts_held)``): it routes over all of
   them and computes its own experts' part — tokens sorted by expert, one
   grouped product over the held experts, no capacity limit, no token a held
   expert was chosen for ever dropped.  What absent experts would add is left
   out (on one chip the layer runs without its exchange).
 
-A slot owns state of two kinds: K/V rows by position per attention layer,
-and per Mamba-2 layer a conv window and a scan state with NO position axis.
+A slot owns state of three kinds: K/V rows by position per global layer, a
+window of K/V rows written round per window layer, and per Mamba-2 layer a
+conv window and a scan state with NO position axis.
 :class:`HybridSlotModel` implements what ``core/slots.py`` calls on a slot
 model (``core.slots.SlotModelProtocol``).
 
@@ -42,6 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import types
 from typing import Any, Dict, Optional
 
 import flax.linen as nn
@@ -49,12 +63,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import decode_attention
 from ..ops.expert_ffn import touched_experts_ffn
 from .transformer import (
     _make_pick, config_resume_fields, kv_attend_write, pick_slots,
 )
 
 FAMILY = "nemotron_h"
+#: the ``arch:`` names this module serves: the stem of each one's program
+#: names (``jit_nns_<stem>_decode``) and the config fields in which it
+#: differs from :class:`HybridConfig`'s defaults.  ``norm``, ``tied_head``,
+#: ``expert_act``, ``router_bias`` and ``shared_combine`` are the family's
+#: alone; a ``custom=`` key may set the others.
+FAMILIES = {
+    FAMILY: dict(stem="hybrid", fields={}),
+    "cohere2_moe": dict(stem="cohere2_moe", fields=dict(
+        pattern="(WE)(WE)(WE)(*E)", norm="layer", expert_act="silu_gated",
+        shared_combine="average", router_bias=False, routed_scale=1.0,
+        tied_head=True)),
+}
 #: always-on counters the decode scan and the prefill chunks sum over their
 #: steps and ``E`` layers (the engine adds them to ``snapshot()``): choices
 #: that fell on a held expert, distinct held experts with a token, tokens on
@@ -63,6 +90,15 @@ FAMILY = "nemotron_h"
 COUNTER_NAMES = ("gen_moe_local", "gen_moe_expert_reads", "gen_moe_max_load",
                  "gen_moe_layer_steps", "gen_moe_prefill_local",
                  "gen_moe_prefill_reads")
+#: and, for a pattern with a window layer, summed the same way over attention
+#: layers and live slots: cache rows a decode step NEEDS by position and
+#: window, rows its reads covered, rows the leaves hold; and the keys a
+#: prefill chunk's queries see (their own row counted), which follow from the
+#: chunk's position alone and grow with its square: the engine is told them
+#: in Python integers as each chunk is dispatched
+#: (:meth:`HybridSlotModel.prefill_counts`), no program sums them
+KV_COUNTER_NAMES = ("gen_kv_rows_need", "gen_kv_rows_read", "gen_kv_rows_held",
+                    "gen_kv_prefill_rows_need")
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
 
@@ -72,6 +108,8 @@ class HybridConfig:
     pattern: str = "MEM*EME"
     vocab: int = 256
     d_model: int = 64
+    norm: str = "rms"            # rms | layer (mean-centred, weight only)
+    tied_head: bool = False      # the embedding read as the head
     # Mamba-2
     ssm_heads: int = 4
     ssm_head_dim: int = 16
@@ -83,23 +121,37 @@ class HybridConfig:
     n_heads: int = 4
     n_kv_heads: int = 2
     head_dim: int = 16
+    # window layers: rows a query sees (its own counted), rotary base
+    window: int = 0
+    rope_theta: float = 10000.0
     # routed experts: the router's width, the share held here, experts per token
     experts: int = 8
     experts_held: int = 8
     expert_offset: int = 0
     top_k: int = 2
     d_expert: int = 32
-    d_shared: int = 64
+    expert_act: str = "relu2"    # relu2 | silu_gated
+    router_bias: bool = True
     routed_scale: float = 2.5
+    # shared experts: how many, the width of one, sum | average
+    shared_experts: int = 1
+    d_shared: int = 64
+    shared_combine: str = "sum"
     norm_eps: float = 1e-5
     max_seq: int = 256
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
-        bad = set(self.pattern) - set("ME*")
-        if bad or not self.pattern:
+        parse_pattern(self.pattern)  # raises by name
+        for field, known in (("norm", ("rms", "layer")),
+                             ("expert_act", ("relu2", "silu_gated")),
+                             ("shared_combine", ("sum", "average"))):
+            if getattr(self, field) not in known:
+                raise ValueError(f"{field}:{getattr(self, field)}: one of {known}")
+        if "W" in self.pattern and (self.window < 1 or self.head_dim % 2):
             raise ValueError(
-                f"layers pattern {self.pattern!r}: one of M, E, * per block")
+                f"a window layer needs window >= 1 (got {self.window}) and an "
+                "even head_dim (rotary pairs)")
         if self.ssm_heads % self.ssm_groups or self.n_heads % self.n_kv_heads:
             raise ValueError("heads must divide by their groups")
         if not (0 <= self.expert_offset
@@ -111,6 +163,24 @@ class HybridConfig:
                 f"top {self.top_k}: not a share of the router's width")
 
     @property
+    def groups(self):
+        """The pattern by block: ``("WE", "WE", "WE", "*E")``."""
+        return parse_pattern(self.pattern)
+
+    @property
+    def blocks(self):
+        """Per block its mixers as ``(name in the block, kind, state key)``;
+        the state key numbers the mixers through the whole pattern."""
+        keys = iter(range(len(self.pattern)))
+        return tuple(tuple((name, kind, str(next(keys)))
+                           for name, kind in zip(mixer_names(group), group))
+                     for group in self.groups)
+
+    def kv_rows(self, kind: str) -> int:
+        """Rows of one slot's K and V leaves in a layer of ``kind``."""
+        return min(self.window, self.max_seq) if kind == "W" else self.max_seq
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
 
@@ -119,10 +189,45 @@ class HybridConfig:
         return self.d_inner + 2 * self.ssm_groups * self.ssm_state
 
 
+@functools.lru_cache(maxsize=None)
+def parse_pattern(pattern: str):
+    """``"(WE)M*"`` -> ``("WE", "M", "*")``: a letter is a block of one
+    mixer, letters in parentheses one parallel block."""
+    out, inside = [], None
+    for ch in pattern:
+        if ch == "(" and inside is None:
+            inside = ""
+        elif ch == ")" and inside:
+            out.append(inside)
+            inside = None
+        elif ch in "ME*W" and inside is None:
+            out.append(ch)
+        elif ch in "ME*W":
+            inside += ch
+        else:
+            out = None
+            break
+    if not out or inside is not None:
+        raise ValueError(
+            f"layers pattern {pattern!r}: one of M, E, *, W per mixer, the "
+            "mixers of a parallel block in one pair of parentheses")
+    return tuple(out)
+
+
+def mixer_names(group: str):
+    """The parameter names of a block's mixers: ``mixer``, or ``mixer0``,
+    ``mixer1``, ... in a parallel block."""
+    return ("mixer",) if len(group) == 1 else tuple(
+        f"mixer{j}" for j in range(len(group)))
+
+
 def cfg_from_props(props: Dict[str, str]) -> HybridConfig:
-    """The ``custom=`` dialect of this family (Documentation/examples.md):
-    ``layers`` is the pattern string; every other key is a number."""
-    d = HybridConfig()
+    """The ``custom=`` dialect of these families (Documentation/examples.md):
+    ``arch`` gives the family's defaults and what only the family decides,
+    ``layers`` is the pattern string, every other key is a number."""
+    d = types.SimpleNamespace(**{
+        **{f.name: f.default for f in dataclasses.fields(HybridConfig)},
+        **FAMILIES[props.get("arch", FAMILY)]["fields"]})
 
     def num(key, default, cast=int):
         return cast(props.get(key, default))
@@ -131,6 +236,8 @@ def cfg_from_props(props: Dict[str, str]) -> HybridConfig:
         pattern=props.get("layers", d.pattern),
         vocab=num("vocab", d.vocab),
         d_model=num("d_model", d.d_model),
+        norm=d.norm,
+        tied_head=d.tied_head,
         ssm_heads=num("ssm_heads", d.ssm_heads),
         ssm_head_dim=num("ssm_head_dim", d.ssm_head_dim),
         ssm_groups=num("ssm_groups", d.ssm_groups),
@@ -140,13 +247,19 @@ def cfg_from_props(props: Dict[str, str]) -> HybridConfig:
         n_heads=num("heads", d.n_heads),
         n_kv_heads=num("kv_heads", d.n_kv_heads),
         head_dim=num("head_dim", d.head_dim),
+        window=num("window", d.window),
+        rope_theta=num("rope_theta", d.rope_theta, float),
         experts=num("experts", d.experts),
         experts_held=num("experts_held", props.get("experts", d.experts_held)),
         expert_offset=num("expert_offset", d.expert_offset),
         top_k=num("experts_per_tok", d.top_k),
         d_expert=num("d_expert", d.d_expert),
-        d_shared=num("d_shared", d.d_shared),
+        expert_act=d.expert_act,
+        router_bias=d.router_bias,
         routed_scale=num("routed_scale", d.routed_scale, float),
+        shared_experts=num("shared_experts", d.shared_experts),
+        d_shared=num("d_shared", d.d_shared),
+        shared_combine=d.shared_combine,
         norm_eps=num("eps", d.norm_eps, float),
         max_seq=num("seq", d.max_seq),
         dtype={"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
@@ -192,6 +305,19 @@ def _expert_stack(offset, true_shape):
     return init
 
 
+def _side_by_side(n, true_shape, axis):
+    """``n`` matrices of ``true_shape`` laid side by side along ``axis``:
+    matrix ``j`` is ``lecun_normal`` from ``fold_in(key, j)`` (the shared
+    experts kept as one FFN)."""
+
+    def init(key, shape):
+        del shape
+        each = [_lecun(jax.random.fold_in(key, j), true_shape) for j in range(n)]
+        return jnp.concatenate(each, axis=axis)
+
+    return init
+
+
 class _Tree(nn.Module):
     """Holds nothing but names: ``spec`` is a tuple of ``(name, (shape, init)
     | nested spec)`` in creation order; flax folds each key by its path."""
@@ -218,7 +344,7 @@ def _dense(d_in, d_out):
     return (("kernel", ((d_in, d_out), _lecun)),)
 
 
-def block_spec(cfg: HybridConfig, kind: str):
+def mixer_spec(cfg: HybridConfig, kind: str):
     d = cfg.d_model
     if kind == "M":
         h, c = cfg.ssm_heads, cfg.d_conv
@@ -232,7 +358,7 @@ def block_spec(cfg: HybridConfig, kind: str):
             ("norm", _norm(cfg.d_inner)),
             ("out_proj", _dense(cfg.d_inner, d)),
         )
-    elif kind == "*":
+    elif kind in "*W":
         q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         mixer = (("q_proj", _dense(d, q)), ("k_proj", _dense(d, kv)),
                  ("v_proj", _dense(d, kv)), ("o_proj", _dense(q, d)))
@@ -245,14 +371,32 @@ def block_spec(cfg: HybridConfig, kind: str):
         fp = -(-f // 128) * 128
         up = _expert_stack(cfg.expert_offset, (d, f))
         down = _expert_stack(cfg.expert_offset, (f, d))
+        gated = cfg.expert_act == "silu_gated"
+        n, fs = cfg.shared_experts, cfg.d_shared
+
+        def shared(d_in, d_out, axis):
+            if n == 1:
+                return _dense(d_in, d_out)
+            wide = (d_in, n * d_out) if axis == 1 else (n * d_in, d_out)
+            return (("kernel", (wide, _side_by_side(n, (d_in, d_out), axis))),)
+
+        bias = (("bias", ((cfg.experts,), nn.initializers.normal(0.02))),)
         mixer = (
-            ("router", (("kernel", ((d, cfg.experts), _lecun)),
-                        ("bias", ((cfg.experts,), nn.initializers.normal(0.02))))),
-            ("experts", (("up", ((held, d, fp), up)), ("down", ((held, fp, d), down)))),
-            ("shared_up", _dense(d, cfg.d_shared)),
-            ("shared_down", _dense(cfg.d_shared, d)),
+            ("router", (("kernel", ((d, cfg.experts), _lecun)),)
+             + (bias if cfg.router_bias else ())),
+            ("experts", ((("gate", ((held, d, fp), up)),) if gated else ())
+             + (("up", ((held, d, fp), up)), ("down", ((held, fp, d), down)))),
+        ) + ((("shared_gate", shared(d, fs, 1)),) if gated else ()) + (
+            ("shared_up", shared(d, fs, 1)),
+            ("shared_down", shared(fs, d, 0)),
         )
-    return (("norm", _norm(d)), ("mixer", mixer))
+    return mixer
+
+
+def block_spec(cfg: HybridConfig, group: str):
+    """One block's parameters: its norm and its mixers."""
+    return (("norm", _norm(cfg.d_model)),) + tuple(
+        (name, mixer_spec(cfg, kind)) for name, kind in zip(mixer_names(group), group))
 
 
 def _cast(tree, dtype):
@@ -266,7 +410,7 @@ def _cast(tree, dtype):
 def init_params(cfg: HybridConfig, seed: int, device=None):
     """The parameter tree, one block at a time: each float32 block is cast to
     ``cfg.dtype`` inside its init program and freed before the next."""
-    n = len(cfg.pattern)
+    n = len(cfg.groups)
     programs = {}
     place = None if device is None else jax.sharding.SingleDeviceSharding(device)
 
@@ -280,15 +424,17 @@ def init_params(cfg: HybridConfig, seed: int, device=None):
                 out_shardings=place)
         return programs[spec_key](np.int32(seed), np.int32(index))
 
-    blocks = [born(kind, block_spec(cfg, kind), i) for i, kind in enumerate(cfg.pattern)]
+    blocks = [born(group, block_spec(cfg, group), i) for i, group in enumerate(cfg.groups)]
     embed = (("embedding", ((cfg.vocab, cfg.d_model), nn.initializers.variance_scaling(
         1.0, "fan_in", "normal", out_axis=0))),)
-    return {
+    params = {
         "embed": born("embed", embed, n),
         "blocks": blocks,
         "norm_f": born("norm_f", _norm(cfg.d_model), n),
-        "lm_head": born("lm_head", _dense(cfg.d_model, cfg.vocab), n + 1),
     }
+    if not cfg.tied_head:
+        params["lm_head"] = born("lm_head", _dense(cfg.d_model, cfg.vocab), n + 1)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -303,6 +449,37 @@ def _rms(x, scale, eps, groups=1):
     g = x.reshape(x.shape[:-1] + (groups, x.shape[-1] // groups))
     g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
     return g.reshape(x.shape) * scale
+
+
+def _layer_norm(x, scale, eps):
+    """Mean-centred, weight only, no bias, float32."""
+    x = x.astype(_F32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+def _normed(x, scale, cfg):
+    norm = _rms if cfg.norm == "rms" else _layer_norm
+    return norm(x, scale, cfg.norm_eps).astype(cfg.dtype)
+
+
+def rotary(x, pos, n_heads: int, theta: float):
+    """Rotary positions on ``x`` (B, T, heads x head_dim), the interleaved
+    form: the pair ``(x[2i], x[2i+1])`` of every head turned by ``p x
+    theta^(-2i / head_dim)``, ``p = pos[b] + t`` the row's absolute
+    position.  Angles, sines and the rotation are float32, made here from
+    ``pos`` (no table is baked into the program)."""
+    B, T, D = x.shape
+    dh = D // n_heads
+    lane = jnp.arange(dh)
+    inv = jnp.asarray(theta, _F32) ** (-(lane // 2 * 2).astype(_F32) / dh)
+    p = (pos[:, None] + jnp.arange(T)[None, :]).astype(_F32)
+    ang = p[:, :, None, None] * inv  # (B, T, 1, dh)
+    xf = x.astype(_F32).reshape(B, T, n_heads, dh)
+    # the pair's other element, signed: out[2i] = x[2i] cos - x[2i+1] sin,
+    # out[2i+1] = x[2i+1] cos + x[2i] sin
+    other = jnp.where(lane % 2 == 0, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
+    return (xf * jnp.cos(ang) + other * jnp.sin(ang)).reshape(B, T, D).astype(x.dtype)
 
 
 def ssm_step(h, u, dt, a, bm, cm):
@@ -390,31 +567,68 @@ def mamba_mix(p, x, conv, ssm, cfg: HybridConfig, keep=None):
         return _mm(y, p["out_proj"]["kernel"], cfg.dtype), new_conv, new_ssm
 
 
-def attn_mix(p, x, ck, cv, pos, cfg: HybridConfig, active=None):
+def attn_mix(p, x, ck, cv, pos, cfg: HybridConfig, active=None, window=False):
     """Returns ``(out, ck, cv)``: grouped-query attention over the slot's
-    K/V rows plus the new rows, no positional encoding.  ``active`` (B,):
-    a row with 0 reads none of its K/V rows in the per-token step."""
-    with jax.named_scope("nns.attn"):
+    K/V rows plus the new rows.  A global layer applies no positional
+    encoding and its leaves hold every position; a ``window`` layer turns
+    ``q`` and ``k`` by their positions before the cache write and its leaves
+    are written round.  ``active`` (B,): a row with 0 reads none of its K/V
+    rows in the per-token step."""
+    with jax.named_scope("nns.attn.window" if window else "nns.attn.global"):
         q, k, v = (_mm(x, p[n]["kernel"], ck.dtype)
                    for n in ("q_proj", "k_proj", "v_proj"))
+        if window:
+            q = rotary(q, pos, cfg.n_heads, cfg.rope_theta)
+            k = rotary(k, pos, cfg.n_kv_heads, cfg.rope_theta)
         ck, cv, attn = kv_attend_write(
             ck, cv, q, k, v, pos, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            active=active)
+            active=active, ring=window)
         return _mm(attn.astype(cfg.dtype), p["o_proj"]["kernel"], cfg.dtype), ck, cv
+
+
+def kv_counts(leaf, pos, active, window: bool):
+    """One attention layer's part of a decode step in the first three of
+    :data:`KV_COUNTER_NAMES`, (3,) int32: the older rows each live slot needs
+    by position and window (a window counts the query's own position, so a
+    full one needs ``rows - 1``), the rows its read covers (whole blocks up
+    to the fill where the kernel is taken, every row where not), the rows
+    held."""
+    B, S, _ = leaf.shape
+    n = decode_attention.live_rows(pos, active, S)
+    need = jnp.minimum(n, S - 1) if window else n
+    return jnp.stack([jnp.sum(need), decode_attention.rows_read(n, leaf),
+                      B * S]).astype(jnp.int32)
+
+
+def keys_seen(pos: int, n: int, rows: int) -> int:
+    """The keys the ``n`` queries of a chunk at position ``pos`` see, their
+    own counted, where one query sees at most ``rows``: the sum of ``min(p +
+    1, rows)`` over ``p = pos .. pos + n - 1``, in Python integers."""
+    m = max(0, min(pos + n, rows) - pos)  # queries that see fewer than ``rows``
+    return m * pos + m * (m + 1) // 2 + (n - m) * rows
 
 
 def route(p, xt, cfg: HybridConfig):
     """Router over ALL experts, float32: ``(ids (M, k), weights (M, k))``."""
     s = jax.nn.sigmoid(jnp.matmul(
         xt.astype(_F32), p["router"]["kernel"], precision=_HI))
-    _, ids = jax.lax.top_k(s + p["router"]["bias"], cfg.top_k)
+    _, ids = jax.lax.top_k(
+        s + p["router"]["bias"] if cfg.router_bias else s, cfg.top_k)
     w = jnp.take_along_axis(s, ids, axis=-1)
     return ids, w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scale
 
 
+def _expert_act(cfg: HybridConfig, up, gate=None):
+    """The experts' activation on float32 products: ``relu(up)^2``, or
+    ``silu(gate) * up`` where the experts are gated."""
+    if cfg.expert_act == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
+
+
 def moe_mix(p, x, cfg: HybridConfig, live=None):
     """Returns ``(out, counts (4,) int32)``: the held experts' part for the
-    tokens routed to them plus the shared expert.  ``live`` (B,) bool: rows
+    tokens routed to them plus the shared experts.  ``live`` (B,) bool: rows
     that carry a token (an idle slot's row routes nowhere and counts
     nothing).  ``counts``: the first four of :data:`COUNTER_NAMES`."""
     B, T, D = x.shape
@@ -429,15 +643,18 @@ def moe_mix(p, x, cfg: HybridConfig, live=None):
         sizes = jnp.zeros((held + 1,), jnp.int32).at[lid.reshape(-1)].add(1)[:held]
         gate = jnp.where(local, w, 0.0)
         up, down = p["experts"]["up"], p["experts"]["down"]
+        wg = p["experts"].get("gate")  # None: the experts are not gated
 
         def grouped(xt, lid, gate):
             """Choices sorted by held expert (those of absent experts go
             last), one grouped product over the held experts."""
             order = jnp.argsort(lid.reshape(-1), stable=True)
             tok = order // k
-            hid = jax.lax.ragged_dot(jnp.take(xt, tok, axis=0), up, sizes,
-                                     preferred_element_type=_F32)
-            hid = jnp.square(jax.nn.relu(hid)).astype(cfg.dtype)
+            rows = jnp.take(xt, tok, axis=0)
+            hid = jax.lax.ragged_dot(rows, up, sizes, preferred_element_type=_F32)
+            gate_h = None if wg is None else jax.lax.ragged_dot(
+                rows, wg, sizes, preferred_element_type=_F32)
+            hid = _expert_act(cfg, hid, gate_h).astype(cfg.dtype)
             part = jax.lax.ragged_dot(hid, down, sizes, preferred_element_type=_F32)
             g = gate.reshape(-1)[order]
             # rows past the held experts' groups belong to no group
@@ -449,13 +666,16 @@ def moe_mix(p, x, cfg: HybridConfig, live=None):
             token through each (ops/expert_ffn.py)."""
             one_hot = lid[:, :, None] == jnp.arange(held)[None, None, :]
             gates = jnp.sum(jnp.where(one_hot, gate[:, :, None], 0.0), axis=1)
-            return touched_experts_ffn(xt, gates, up, down)
+            return touched_experts_ffn(xt, gates, up, down, wg)
 
         routed = jax.lax.platform_dependent(
             xt, lid, gate, tpu=touched, default=grouped)
         sh = _mm(xt, p["shared_up"]["kernel"], _F32)
-        sh = jnp.square(jax.nn.relu(sh)).astype(cfg.dtype)
+        sh = _expert_act(cfg, sh, None if wg is None else _mm(
+            xt, p["shared_gate"]["kernel"], _F32)).astype(cfg.dtype)
         sh = jnp.matmul(sh, p["shared_down"]["kernel"], preferred_element_type=_F32)
+        if cfg.shared_combine == "average" and cfg.shared_experts > 1:
+            sh = sh * (1.0 / cfg.shared_experts)
         counts = jnp.stack([jnp.sum(local), jnp.sum(sizes > 0), jnp.max(sizes),
                             jnp.int32(1)]).astype(jnp.int32)
         return (routed + sh).astype(cfg.dtype).reshape(B, T, D), counts
@@ -465,36 +685,49 @@ def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
     """Run ``tokens`` (B, T) through every block against the state ``rows``
     (the cache's leaves for these B rows).  ``active`` (B,) int: rows with 0
     keep their recurrent state and their position.  Returns ``(hidden (B, T,
-    D), rows, counts)``."""
+    D), rows, counts, kv)``: ``counts`` (4,) the expert layers' sums, ``kv``
+    (3,) the attention layers' in a decode step (:func:`kv_counts`; None for
+    a prefill chunk, ``active`` None, and for a pattern without a window
+    layer, whose need is its fill)."""
     x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+    T = tokens.shape[1]
     keep = None if active is None else active == 0
     live = None if active is None else active > 0
     counts = jnp.zeros((4,), jnp.int32)
+    counted = active is not None and "W" in cfg.pattern
+    kv = jnp.zeros((3,), jnp.int32) if counted else None
     layers = {}
-    for i, kind in enumerate(cfg.pattern):
-        blk = params["blocks"][i]
-        h = _rms(x, blk["norm"]["scale"], cfg.norm_eps).astype(cfg.dtype)
-        st = rows["layers"].get(str(i))
-        if kind == "M":
-            y, conv, ssm = mamba_mix(blk["mixer"], h, st["conv"], st["ssm"], cfg, keep)
-            layers[str(i)] = {"conv": conv, "ssm": ssm}
-        elif kind == "*":
-            y, ck, cv = attn_mix(
-                blk["mixer"], h, st["k"], st["v"], rows["pos"], cfg, active)
-            layers[str(i)] = {"k": ck, "v": cv}
-        else:
-            y, c = moe_mix(blk["mixer"], h, cfg, live)
-            counts = counts + c
-        x = x + y
-    T = tokens.shape[1]
+    for blk, mixers in zip(params["blocks"], cfg.blocks):
+        # one norm, every mixer of the block reads the same h, one residual add
+        h = _normed(x, blk["norm"]["scale"], cfg)
+        outs = []
+        for name, kind, key in mixers:
+            st = rows["layers"].get(key)
+            if kind == "M":
+                out, conv, ssm = mamba_mix(blk[name], h, st["conv"], st["ssm"], cfg, keep)
+                layers[key] = {"conv": conv, "ssm": ssm}
+            elif kind == "E":
+                out, c = moe_mix(blk[name], h, cfg, live)
+                counts = counts + c
+            else:
+                if kv is not None:
+                    kv = kv + kv_counts(st["k"], rows["pos"], active, kind == "W")
+                out, ck, cv = attn_mix(
+                    blk[name], h, st["k"], st["v"], rows["pos"], cfg, active, kind == "W")
+                layers[key] = {"k": ck, "v": cv}
+            outs.append(out)
+        x = x + functools.reduce(jnp.add, outs)
     adv = T if active is None else T * active.astype(jnp.int32)
-    return x, {"pos": rows["pos"] + adv, "layers": layers}, counts
+    return x, {"pos": rows["pos"] + adv, "layers": layers}, counts, kv
 
 
 def head(params, x, cfg: HybridConfig):
     """float32 logits of hidden rows ``x`` (..., D)."""
-    h = _rms(x, params["norm_f"]["scale"], cfg.norm_eps).astype(cfg.dtype)
-    return jnp.matmul(h, params["lm_head"]["kernel"], preferred_element_type=_F32)
+    h = _normed(x, params["norm_f"]["scale"], cfg)
+    if not cfg.tied_head:
+        return jnp.matmul(h, params["lm_head"]["kernel"], preferred_element_type=_F32)
+    return jnp.einsum("...d,vd->...v", h, params["embed"]["embedding"],
+                      preferred_element_type=_F32)
 
 
 class HybridSlotModel:
@@ -503,24 +736,29 @@ class HybridSlotModel:
     (``core.slots.SlotModelProtocol``), the same pick and seed semantics.
 
     The cache: ``pos`` (slots,), per attention layer ``k``/``v`` (slots,
-    max_seq, n_kv_heads x head_dim) in the model dtype (lane-dense), per
-    Mamba-2 layer ``conv`` (slots, K-1, C) and ``ssm`` (slots, H, P, N)
-    float32, and ``counts``: the expert counters the prefill chunks have
-    summed since the last decode dispatch took them."""
+    rows, n_kv_heads x head_dim) in the model dtype (lane-dense), ``rows``
+    being ``max_seq`` for a global layer and ``window`` for a window layer
+    (written round), per Mamba-2 layer ``conv`` (slots, K-1, C) and ``ssm``
+    (slots, H, P, N) float32, and ``counts``: the counters the prefill
+    chunks have summed since the last decode dispatch took them."""
 
+    #: a pattern with a window layer adds :data:`KV_COUNTER_NAMES`
     counter_names = COUNTER_NAMES
-    #: a recurrent state cannot be cut by position
+    #: neither a recurrent state nor a leaf written round can be cut by position
     supports_prefix = False
 
     def __init__(self, cfg: HybridConfig, slots: int, temperature: float = 0.0,
                  top_k: int = 0, seed: int = 0, donate: Optional[bool] = None,
-                 device=None):
+                 device=None, family: str = FAMILY):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
         from ..core.hw import default_device
 
         self.cfg = cfg
+        self.family = family  # for program names and messages alone
         self.slots = int(slots)
+        if "W" in cfg.pattern:
+            self.counter_names = COUNTER_NAMES + KV_COUNTER_NAMES
         self.device = device if device is not None else default_device()
         self._pick = _make_pick(temperature, top_k)
         self._temperature = temperature
@@ -542,15 +780,15 @@ class HybridSlotModel:
     # -- cache lifecycle ----------------------------------------------------
     def _layer_shapes(self):
         c, s = self.cfg, self.slots
-        kv = (s, c.max_seq, c.n_kv_heads * c.head_dim)
         out = {}
-        for i, kind in enumerate(c.pattern):
+        for _, kind, key in (m for mixers in c.blocks for m in mixers):
             if kind == "M":
-                out[str(i)] = {
+                out[key] = {
                     "conv": ((s, c.conv_kernel - 1, c.d_conv), c.dtype),
                     "ssm": ((s, c.ssm_heads, c.ssm_head_dim, c.ssm_state), _F32)}
-            elif kind == "*":
-                out[str(i)] = {"k": (kv, c.dtype), "v": (kv, c.dtype)}
+            elif kind in "*W":
+                kv = (s, c.kv_rows(kind), c.n_kv_heads * c.head_dim)
+                out[key] = {"k": (kv, c.dtype), "v": (kv, c.dtype)}
         return out
 
     def init_cache(self):
@@ -559,7 +797,7 @@ class HybridSlotModel:
 
         return {
             "pos": zeros((self.slots,), jnp.int32),
-            "counts": zeros((len(COUNTER_NAMES),), jnp.int32),
+            "counts": zeros((len(self.counter_names),), jnp.int32),
             "layers": {i: {n: zeros(*sd) for n, sd in leaves.items()}
                        for i, leaves in self._layer_shapes().items()},
         }
@@ -595,34 +833,59 @@ class HybridSlotModel:
 
     def export_prefix(self, cache, slot, start, stop):
         raise NotImplementedError(
-            f"{FAMILY}: a recurrent state cannot be cut by position")
+            f"{self.family}: a recurrent state cannot be cut by position, "
+            "nor a leaf written round")
 
     attach_prefix = export_prefix
 
     # -- prefill (chunked, one slot at a time) ------------------------------
+    def _tally(self, moe, kv=None):
+        """One program's sums in the order of :attr:`counter_names`; what
+        the program does not count stays 0."""
+        out = moe if kv is None else jnp.concatenate([moe, kv])
+        rest = len(self.counter_names) - out.shape[0]
+        return jnp.pad(out, (0, rest)) if rest else out
+
     def _prefill_chunk(self, params, cache, toks, slot):
-        x, rows, counts = forward_rows(
+        x, rows, counts, _ = forward_rows(
             params, self._rows(cache, slot), toks, self.cfg)
         cache = self._put_rows(
             cache, rows, slot,
-            cache["counts"] + jnp.concatenate([counts, counts[:2]]))
+            cache["counts"] + self._tally(jnp.concatenate([counts, counts[:2]])))
         return cache, head(params, x[:, -1], self.cfg)
 
+    def prefill_counts(self, pos: int, n: int) -> Dict[str, int]:
+        """What a chunk of ``n`` tokens at position ``pos`` adds to the
+        counters that follow from position alone."""
+        if "W" not in self.cfg.pattern:
+            return {}
+        return {KV_COUNTER_NAMES[3]: sum(
+            keys_seen(pos, n, self.cfg.kv_rows(kind))
+            for mixers in self.cfg.blocks for _, kind, _ in mixers if kind in "*W")}
+
+    def _named(self, fn, phase: str):
+        """``fn`` under the program name of this family and ``phase``
+        (``jit_nns_hybrid_decode``, ``jit_nns_cohere2_moe_prefill``, ...):
+        what a trace's module line shows."""
+        stem = FAMILIES[self.family]["stem"]
+        fn.__name__ = fn.__qualname__ = f"nns_{stem}_{phase}"
+        return jax.jit(fn, donate_argnums=self._donate)
+
     def prefill_fn(self, n: int):
-        def nns_hybrid_prefill(params, cache, toks, slot):
+        def program(params, cache, toks, slot):
             self.prefill_compiles += 1  # trace-time only
             return self._prefill_chunk(params, cache, toks, slot)
 
         del n  # bucketing key only; the shape specializes the jit
-        return jax.jit(nns_hybrid_prefill, donate_argnums=self._donate)
+        return self._named(program, "prefill")
 
     # -- decode (whole slot batch, k tokens per dispatch) -------------------
     def step_logits(self, params, cache, tok, active):
         """One token step of every slot: ``(cache, logits (S, V))``; the
         step's expert counters are added to the cache's."""
-        x, rows, counts = forward_rows(
+        x, rows, counts, kv = forward_rows(
             params, self._slotted(cache), tok[:, None], self.cfg, active)
-        rows["counts"] = cache["counts"] + jnp.pad(counts, (0, 2))
+        rows["counts"] = cache["counts"] + self._tally(jnp.pad(counts, (0, 2)), kv)
         return rows, head(params, x[:, 0], self.cfg)
 
     def _decode_scan(self, k, params, cache, tok, gen, active):
@@ -644,28 +907,28 @@ class HybridSlotModel:
         """``(params, cache, tok, gen, active) -> (cache, tok, gen, toks (S,
         k), counts)``; ``counts`` follows :attr:`counter_names`."""
 
-        def nns_hybrid_decode(params, cache, tok, gen, active):
+        def program(params, cache, tok, gen, active):
             self.decode_compiles += 1  # trace-time only
             return self._decode_scan(k, params, cache, tok, gen, active)
 
-        return jax.jit(nns_hybrid_decode, donate_argnums=self._donate)
+        return self._named(program, "decode")
 
 
 def build_slot_stream(props: Dict[str, str], slots: int,
                       donate: Optional[bool] = None, mesh=None, device=None):
-    """Factory of the continuous-batching path for this family: the twin of
-    ``models.transformer.build_slot_stream`` (``seed`` = parameters,
+    """Factory of the continuous-batching path for these families: the twin
+    of ``models.transformer.build_slot_stream`` (``seed`` = parameters,
     ``gen_seed`` = sampling).  Returns ``(model, params, max_seq)``."""
+    cfg, family = cfg_from_props(props), props.get("arch", FAMILY)
     if mesh is not None:
         raise ValueError(
-            f"arch:{FAMILY} does not shard over mesh=: the experts' ep axis "
+            f"arch:{family} does not shard over mesh=: the experts' ep axis "
             "and its exchange do not exist yet (one chip holds one share)")
-    cfg = cfg_from_props(props)
     model = HybridSlotModel(
         cfg, slots,
         temperature=float(props.get("temperature", "0")),
         top_k=int(props.get("top_k", "0")),
         seed=int(props.get("gen_seed", "0")),
-        donate=donate, device=device)
+        donate=donate, device=device, family=family)
     params = init_params(cfg, int(props.get("seed", "0")), device=model.device)
     return model, model.place_params(params), cfg.max_seq

@@ -26,7 +26,7 @@ from jax.sharding import Mesh
 
 from ..core.types import FORMAT_STATIC, StreamSpec, TensorSpec
 from ._init_util import host_init
-from ..ops import decode_attention
+from ..ops import chunk_attention, decode_attention
 from ..parallel.ring_attention import reference_attention
 
 
@@ -49,8 +49,14 @@ class TransformerConfig:
     quant: bool = False
 
 
+#: a prefill chunk whose float32 scores over the whole leaf would pass this
+#: many bytes attends in row blocks bounded by the slot's fill
+#: (:func:`_attend_blocked`); so does every chunk on a leaf written round
+_SCORES_BYTES = 256 << 20
+
+
 def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None,
-                    active=None, single_device=True):
+                    active=None, single_device=True, ring=False):
     """The ONE decode-cache step every generation path shares: attend
     over the cache leaves as they lie plus the new rows, then write the
     new rows into the leaves.
@@ -110,6 +116,27 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None,
     (``decode_attention.block_rows``).  Anything else, and ``T > 1`` (a
     prefill chunk reads one slot's rows), is the jnp form below on every
     platform; nothing declines the kernel quietly and no property chooses.
+
+    ``ring``: the leaves are written ROUND and their ``S`` rows are the
+    layer's window (the query's own position counted): position ``p`` lies at
+    row ``p mod S``, a query at position ``p`` sees positions ``p-S+1 .. p``,
+    and every mask is by POSITION, not by row index.  The per-token step
+    reads ``min(pos, S)`` rows and leaves out the one row of a full leaf that
+    holds position ``p - S`` (the row the new token overwrites:
+    ``decode_attention.ring_skip``); a chunk must have ``T <= S`` rows, so
+    that the read before the write still finds every position it may see;
+    a row with ``active[b] == 0`` writes nothing.
+
+    **A long prefill chunk is bounded by fill too.**  Where the ``(B, H, T,
+    S + T)`` float32 scores of ``T > 1`` would pass :data:`_SCORES_BYTES`,
+    and on every round leaf, the chunk attends in blocks of leaf rows up to
+    the fill (a loop whose trip count is the data's), then over its own rows
+    in blocks, one running softmax over all of them
+    (:func:`_attend_blocked`): no array grows with ``S``, the same on every
+    platform; lowered for one TPU at a shape it takes, the same mathematics
+    is ``ops/chunk_attention.py`` (device operations ``nns_chunk_attention``),
+    whose scores never leave VMEM.  Smaller chunks keep the whole-leaf form
+    they had, operation for operation.
     """
     B, T, D = q.shape
     S = ck.shape[1]
@@ -127,6 +154,8 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None,
         else:
             s_old, mix_old, s_new, mix_new = _mha_scores(ck, cv, q, k, v, H, dot)
         older = jnp.arange(S)[None, :] < pos[:, None]  # (B, S)
+        if ring:
+            older &= jnp.arange(S)[None, :] != decode_attention.ring_skip(pos, S)[:, None]
         s_old = jnp.where(older[:, None, None], s_old * scale, -1e30)
         s_new = jnp.where(jnp.tri(T, dtype=bool), s_new * scale, -1e30)
         top = jnp.maximum(s_old.max(axis=-1), s_new.max(axis=-1))[..., None]
@@ -140,24 +169,111 @@ def kv_attend_write(ck, cv, q, k, v, pos, n_heads, n_kv_heads=None,
         def bounded(ck, cv, q, k, v, pos):
             return decode_attention.decode_attention(
                 ck, cv, q, k, v, decode_attention.live_rows(pos, active, S),
-                n_heads=H, interpret=decode_attention.INTERPRET)
+                n_heads=H, interpret=decode_attention.INTERPRET,
+                skip=decode_attention.ring_skip(pos, S) if ring else None)
 
         attn = decode_attention.fill_bounded(
             bounded, attend, ck, cv, q, k, v, pos,
             leaf=ck, single_device=single_device)
+    elif ring or 4 * B * H * T * (S + T) > _SCORES_BYTES:
+        J = n_kv_heads or H
+
+        def in_vmem(ck, cv, q, k, v, pos):
+            return chunk_attention.chunk_attention(
+                ck, cv, q, k, v, pos, n_heads=H, ring=ring,
+                interpret=decode_attention.INTERPRET)
+
+        attn = decode_attention.fill_bounded(
+            in_vmem, functools.partial(_attend_blocked, H=H, J=J, ring=ring),
+            ck, cv, q, k, v, pos, single_device=single_device,
+            takes=chunk_attention.blocks(T, S, ck.shape[2], J) is not None)
     else:
         attn = attend(ck, cv, q, k, v, pos)
 
     slot = jnp.arange(B)[:, None]
     rows = pos[:, None] + jnp.arange(T)[None, :]  # (B, T)
+    if ring:
+        if T > S:
+            raise ValueError(f"a chunk of {T} rows on a round leaf of {S}")
+        rows = rows % S
+        if active is not None:  # an idle row's leaf comes out bit-equal
+            rows = jnp.where(active[:, None] > 0, rows, S)
 
     def write(c, new):
         return c.at[slot, rows].set(
-            new.astype(c.dtype), mode="drop", indices_are_sorted=True,
+            new.astype(c.dtype), mode="drop", indices_are_sorted=not ring,
             unique_indices=True,
         )
 
     return write(ck, k), write(cv, v), attn
+
+
+def _attend_blocked(ck, cv, q, k, v, pos, H, J, ring):
+    """A prefill chunk's attention in blocks, bounded by fill and window:
+    ``ceil(min(pos, S) / block)`` blocks of leaf rows (the loop's trip count
+    follows the data: rows above the fill are never read), then the chunk's
+    own rows in blocks, all under one running softmax (max, sum and
+    accumulator float32; K and V read in the dtype they are stored in; the
+    probabilities never rounded).  Every mask is by position: leaf row ``r``
+    holds position ``r`` below the fill, or on a round leaf the newest
+    position ``< pos`` that is ``r mod S``; a query at ``pos + i`` sees the
+    positions ``<= pos + i`` and, on a round leaf, ``> pos + i - S``.  Peak
+    temporaries are a few ``(B, H, T, block)`` float32 arrays, whatever
+    ``S``."""
+    B, T, D = q.shape
+    S = ck.shape[1]
+    Dh, G = D // H, H // J
+    dot = functools.partial(
+        jnp.einsum, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    scale = 1.0 / np.sqrt(Dh)
+    # a block's scores are (B, H, T, block) float32: 128 MB at the most
+    block = int(np.clip((128 << 20) // (4 * B * H * T), 128, 512))
+    block = 1 << (block.bit_length() - 1)
+    q5 = q.reshape(B, T, J, G, Dh)
+    qpos = pos[:, None] + jnp.arange(T)[None, :]  # (B, T)
+
+    def one(carry, kb, vb, kpos):
+        """``kb``/``vb`` (B, n, J x Dh), ``kpos`` (B, n): the rows'
+        positions, negative where a row holds none."""
+        m, l, acc = carry
+        n = kb.shape[1]
+        see = (kpos[:, None, :] >= 0) & (kpos[:, None, :] <= qpos[:, :, None])
+        if ring:
+            see &= kpos[:, None, :] > qpos[:, :, None] - S
+        s = dot("btjgd,bsjd->bjgts", q5, kb.reshape(B, n, J, Dh)) * scale
+        s = jnp.where(see[:, None, None], s, -1e30)
+        top = jnp.maximum(m, s.max(axis=-1))
+        # a query that has seen nothing yet carries sums its first real
+        # maximum wipes (exp(-1e30 - top) == 0): its own row is always seen
+        a, e = jnp.exp(m - top), jnp.exp(s - top[..., None])
+        acc = a[..., None] * acc + dot("bjgts,bsjd->bjgtd", e, vb.reshape(B, n, J, Dh))
+        return top, a * l + e.sum(axis=-1), acc
+
+    carry = (jnp.full((B, J, G, T), -1e30, jnp.float32),
+             jnp.zeros((B, J, G, T), jnp.float32),
+             jnp.zeros((B, J, G, T, Dh), jnp.float32))
+    n = min(block, S)
+    fill = jnp.minimum(pos, S)
+
+    def old_block(i, carry):
+        start = jnp.minimum(i * n, S - n)  # the last block of a ragged leaf overlaps
+        rows = start + jnp.arange(n)[None, :]  # (1, n)
+        if ring:
+            newest = pos[:, None] - 1
+            kpos = newest - (newest - rows) % S
+        else:
+            kpos = jnp.where(rows < pos[:, None], rows, -1)
+        kpos = jnp.where((rows >= i * n) & (rows < fill[:, None]), kpos, -1)
+        kb, vb = (jax.lax.dynamic_slice_in_dim(c, start, n, axis=1) for c in (ck, cv))
+        return one(carry, kb, vb, kpos)
+
+    carry = jax.lax.fori_loop(0, (jnp.max(fill) + n - 1) // n, old_block, carry)
+    for a in range(0, T, block):
+        carry = one(carry, k[:, a:a + block], v[:, a:a + block], qpos[:, a:a + block])
+    _, l, acc = carry
+    attn = jnp.moveaxis(acc / l[..., None], 3, 1)  # (B, T, J, G, Dh)
+    return attn.reshape(B, T, D).astype(q.dtype)
 
 
 def _mha_scores(ck, cv, q, k, v, H, dot):
@@ -799,6 +915,11 @@ class SlotModel:
             cache, upd["cache"],
         )
         return cache, logits[:, -1, :]
+
+    @staticmethod
+    def prefill_counts(pos: int, n: int):
+        """Nothing this model counts follows from a chunk's position."""
+        return {}
 
     def prefill_fn(self, n: int):
         """One jitted prefill bucket for chunk length ``n`` (caller

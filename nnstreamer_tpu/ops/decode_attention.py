@@ -30,6 +30,13 @@ new row joins the same softmax inside the kernel, which also finishes the
 division.  Rows of a block past ``n[b]`` are masked out of the scores and
 zeroed in V, so nothing above a slot's fill reaches its output.
 
+A leaf written ROUND (a window layer's: position ``p`` at row ``p mod S``,
+its ``S`` rows the window) is read the same way: ``n[b] = min(pos, S)`` rows,
+and one more scalar operand names the row of a full leaf to leave out of the
+softmax, the one the new token is about to overwrite (:func:`ring_skip`).  A
+softmax does not care in which order its keys come, so nothing else changes;
+without that operand the call is the one it was.
+
 Taken when the program is lowered for a TPU (``lax.platform_dependent``,
 as ``ops/expert_ffn.py``), compiled for one device, and the shape fits
 (:func:`block_rows`); the caller's own jnp form everywhere else
@@ -100,15 +107,29 @@ def live_rows(pos, active, S: int):
     return n if active is None else jnp.where(active > 0, n, 0)
 
 
-def fill_bounded(kernel, default, *operands, leaf, single_device: bool = True):
-    """``kernel(*operands)`` where the fill-bounded read is taken,
+def ring_skip(pos, S: int):
+    """On a leaf written round (position ``p`` at row ``p mod S``; its ``S``
+    rows are the window, the query's own position counted): the row a full
+    leaf's step must not see, ``(B,)`` int32.  Row ``pos mod S`` holds
+    position ``pos - S``, one past the window, and is the row the new token
+    will overwrite; ``-1`` while the leaf is not full."""
+    return jnp.where(pos >= S, pos % S, -1).astype(jnp.int32)
+
+
+def fill_bounded(kernel, default, *operands, leaf=None, takes=None,
+                 single_device: bool = True):
+    """``kernel(*operands)`` where a fill-bounded kernel is taken,
     ``default(*operands)`` everywhere else: the one place the choice is
     made, by what the code can see.  Taken when the program is lowered for
     a TPU, compiled for one device (a Mosaic call cannot sit in a program
-    partitioned over a mesh) and the ``leaf`` (a ``(B, S, W)`` array or
-    shape struct) is one :func:`block_rows` takes."""
-    _, S, W = leaf.shape
-    if block_rows(S, W, leaf.dtype.itemsize) is None:
+    partitioned over a mesh) and the shape is one the kernel takes: the
+    ``leaf`` (a ``(B, S, W)`` array or shape struct) one :func:`block_rows`
+    takes, or ``takes`` as another kernel's own rule says
+    (``chunk_attention.blocks``)."""
+    if takes is None:
+        _, S, W = leaf.shape
+        takes = block_rows(S, W, leaf.dtype.itemsize) is not None
+    if not takes:
         return default(*operands)
     if INTERPRET:
         return kernel(*operands)
@@ -147,15 +168,18 @@ def _exact_dot(a, b, dims):
     return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
 
 
-def _kernel(n_ref, own_ref, lay_ref, q_ref, new_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sem, nxt_ref, m_ref, l_ref, acc_ref, *,
-            block: int, groups: int, scale: float):
-    """All slots in one program.  ``n_ref`` (B,) SMEM; ``own_ref`` (Hp, W)
+def _kernel(n_ref, *refs, block: int, groups: int, scale: float, ring: bool):
+    """All slots in one program.  ``n_ref`` (B,) SMEM; with ``ring`` a
+    second (B,) SMEM operand follows it, the row of each slot that is masked
+    out of the scores (:func:`ring_skip`; -1: none); ``own_ref`` (Hp, W)
     int32: ``g + 1`` where row ``h = j * groups + g`` owns the lane (the
     lanes of KV head ``j``), else 0; ``lay_ref`` (Dh, W) 0/1: lane ``w``
     takes element ``w % Dh`` of a head; ``q_ref`` (B, Hp, Dh) the queries by
     head; ``new_ref`` (B, 1, 2W) the new K row beside the new V row;
     ``k_hbm``/``v_hbm`` the leaves; ``o_ref`` (B, groups, W)."""
+    skip_ref, refs = (refs[0], refs[1:]) if ring else (None, refs)
+    (own_ref, lay_ref, q_ref, new_ref, k_hbm, v_hbm, o_ref,
+     kbuf, vbuf, sem, nxt_ref, m_ref, l_ref, acc_ref) = refs
     B, W = q_ref.shape[0], own_ref.shape[1]
     nt = (((1,), (1,)), ((), ()))   # (rows, W) x (block, W) -> (rows, block)
     nn = (((1,), (0,)), ((), ()))   # (rows, block) x (block, W) -> (rows, W)
@@ -210,7 +234,10 @@ def _kernel(n_ref, own_ref, lay_ref, q_ref, new_ref, k_hbm, v_hbm, o_ref,
                 k_copy.wait()
                 s = _exact_dot(qd, kbuf[buf], nt) * scale  # (Hp, block)
                 col = i * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                s = jnp.where(col < n, s, _NEG_INF)
+                seen = col < n
+                if ring:  # the row the new token will overwrite
+                    seen = seen & (col != skip_ref[b])
+                s = jnp.where(seen, s, _NEG_INF)
                 m = jnp.maximum(m_ref[...], s.max(axis=1, keepdims=True))
                 a = jnp.exp(m_ref[...] - m)
                 p = jnp.exp(s - m)
@@ -247,11 +274,15 @@ def _kernel(n_ref, own_ref, lay_ref, q_ref, new_ref, k_hbm, v_hbm, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("n_heads", "interpret"))
-def decode_attention(ck, cv, q, k, v, n, n_heads: int, interpret: bool = False):
+def decode_attention(ck, cv, q, k, v, n, n_heads: int, interpret: bool = False,
+                     skip=None):
     """``ck``/``cv`` (B, S, W) leaves; ``q`` (B, 1, H x Dh), ``k``/``v`` (B,
     1, W) the step's new rows; ``n`` (B,) int32 the cache rows each slot
-    reads (``<= S``; 0: none).  Returns the attention over those rows and
-    the new row, ``(B, 1, H x Dh)`` in ``q``'s dtype.  The shape must be one
+    reads (``<= S``; 0: none); ``skip`` (B,) int32, for a leaf written round:
+    one row of each slot left out of the softmax (:func:`ring_skip`), None
+    for a leaf written by position (the call is then the one it was,
+    operand for operand).  Returns the attention over those rows and the
+    new row, ``(B, 1, H x Dh)`` in ``q``'s dtype.  The shape must be one
     :func:`block_rows` takes."""
     B, S, W = ck.shape
     H = n_heads
@@ -262,6 +293,7 @@ def decode_attention(ck, cv, q, k, v, n, n_heads: int, interpret: bool = False):
     if block is None:
         raise ValueError(f"decode_attention does not take leaves {ck.shape}")
     Hp = -(-H // _ROWS) * _ROWS
+    ring = skip is not None
     # own[h, w] = g + 1 where lane w lies in the KV head of row h = j*G + g
     head, lane = np.arange(Hp)[:, None], np.arange(W)[None, :]
     own = np.where((head < H) & (head // G == lane // Dh), head % G + 1, 0)
@@ -269,9 +301,9 @@ def decode_attention(ck, cv, q, k, v, n, n_heads: int, interpret: bool = False):
     f32 = jnp.float32
     out = pl.pallas_call(
         functools.partial(_kernel, block=block, groups=G,
-                          scale=float(1.0 / np.sqrt(Dh))),
+                          scale=float(1.0 / np.sqrt(Dh)), ring=ring),
         out_shape=jax.ShapeDtypeStruct((B, G, W), q.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * (1 + ring)
         + [pl.BlockSpec(memory_space=pltpu.VMEM)] * 4
         + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -288,7 +320,8 @@ def decode_attention(ck, cv, q, k, v, n, n_heads: int, interpret: bool = False):
             vmem_limit_bytes=_vmem_limit(B * S * W * ck.dtype.itemsize)),
         interpret=interpret,
         name="nns_decode_attention",
-    )(n.astype(jnp.int32), jnp.asarray(own, jnp.int32),
+    )(n.astype(jnp.int32), *([skip.astype(jnp.int32)] if ring else []),
+      jnp.asarray(own, jnp.int32),
       jnp.asarray(lay, jnp.bfloat16),
       jnp.pad(q.reshape(B, H, Dh), ((0, 0), (0, Hp - H), (0, 0))),
       jnp.concatenate([k, v], axis=-1), ck, cv)

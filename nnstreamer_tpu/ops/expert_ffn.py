@@ -1,6 +1,8 @@
 """The held experts' part of a routed-expert layer for a SMALL token batch
 (a decode step's slots, one prefill chunk): ``out[m] = sum_e gate[m, e] *
-relu(x[m] W_up[e])^2 W_down[e]`` over the experts that have a token.
+relu(x[m] W_up[e])^2 W_down[e]`` over the experts that have a token, or,
+given a third matrix per expert (``gate_w``), ``silu(x[m]
+W_gate[e]) * (x[m] W_up[e])`` in the square's place.
 
 With a few tokens per expert the layer is bound by reading expert weights,
 so the kernel walks the TOUCHED experts only (a compacted id list, scalar-
@@ -37,8 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 MAX_TOKENS = 256
 _ROWS = 16      # token rows are padded to whole bf16 sublane tiles
 _LANES = 128
-#: a weight tile's bytes (one of ``up`` or ``down``); two arrays, double
-#: buffered, stay under the scoped VMEM limit set below
+#: a weight tile's bytes (one of ``up``, ``down`` or ``gate``); two or three
+#: arrays, double buffered, stay under the scoped VMEM limit set below
 _TILE_BYTES = 4 << 20
 _VMEM_LIMIT = 48 << 20
 
@@ -52,7 +54,10 @@ def _tile(d, f, itemsize):
     return max(fits, default=1) * _LANES
 
 
-def _kernel(ids_ref, n_ref, x_ref, gate_ref, up_ref, down_ref, out_ref):
+def _kernel(ids_ref, n_ref, x_ref, gate_ref, up_ref, down_ref, *refs):
+    """``refs``: the output, after the experts' ``gate`` matrices' tile
+    where the activation is gated."""
+    wg_ref, out_ref = refs if len(refs) == 2 else (None, refs[0])
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((i == 0) & (j == 0))
@@ -63,7 +68,11 @@ def _kernel(ids_ref, n_ref, x_ref, gate_ref, up_ref, down_ref, out_ref):
     def _():
         x = x_ref[...]
         hid = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
-        hid = jnp.square(jnp.maximum(hid, 0.0)).astype(x.dtype)
+        if wg_ref is None:
+            hid = jnp.square(jnp.maximum(hid, 0.0)).astype(x.dtype)
+        else:
+            hid = (jax.nn.silu(jnp.dot(
+                x, wg_ref[0], preferred_element_type=jnp.float32)) * hid).astype(x.dtype)
         part = jnp.dot(hid, down_ref[0], preferred_element_type=jnp.float32)
         gates = gate_ref[...]  # (M, held): this expert's column, by a mask
         col = jax.lax.broadcasted_iota(jnp.int32, gates.shape, 1)
@@ -73,16 +82,18 @@ def _kernel(ids_ref, n_ref, x_ref, gate_ref, up_ref, down_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def touched_experts_ffn(x, gates, up, down, interpret: bool = False):
+def touched_experts_ffn(x, gates, up, down, gate_w=None, interpret: bool = False):
     """``x`` (M, D), ``gates`` (M, held) float32 (0 where a token did not
     choose the expert), ``up`` (held, D, F), ``down`` (held, F, D) with F
-    whole lane tiles -> (M, D) float32."""
+    whole lane tiles -> (M, D) float32.  ``gate_w`` (held, D, F): the
+    experts are gated (``silu(x W_gate) * (x W_up)``); None: ``relu(x
+    W_up)^2``, the call it was, operand for operand."""
     M, D = x.shape
     held, _, F = up.shape
     if M > MAX_TOKENS:  # unrolled: inside a loop XLA fuses the call and drops its VMEM limit
         return jnp.concatenate([
             touched_experts_ffn(x[i:i + MAX_TOKENS], gates[i:i + MAX_TOKENS], up, down,
-                                interpret=interpret)
+                                gate_w, interpret=interpret)
             for i in range(0, M, MAX_TOKENS)])
     pad = (-M) % _ROWS
     if pad:
@@ -111,7 +122,9 @@ def touched_experts_ffn(x, gates, up, down, interpret: bool = False):
                              lambda i, j, ids, n: (ids[i], 0, tile(i, j, ids, n))),
                 pl.BlockSpec((1, tf, D),
                              lambda i, j, ids, n: (ids[i], tile(i, j, ids, n), 0)),
-            ],
+            ] + ([] if gate_w is None else [
+                pl.BlockSpec((1, D, tf),
+                             lambda i, j, ids, n: (ids[i], 0, tile(i, j, ids, n)))]),
             out_specs=pl.BlockSpec((M + pad, D), lambda i, j, ids, n: (0, 0)),
         ),
         compiler_params=pltpu.CompilerParams(
@@ -119,5 +132,5 @@ def touched_experts_ffn(x, gates, up, down, interpret: bool = False):
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="nns_touched_experts_ffn",
-    )(ids, n[None], x, gates, up, down)
+    )(ids, n[None], x, gates, up, down, *([] if gate_w is None else [gate_w]))
     return out[:M]

@@ -85,10 +85,15 @@ def test_kernels_phase_tiny_runs_every_pallas_kernel_interpreted():
         platform="cpu", interpret=True, top1_batches=(1, 2, 8), classes=17,
         norm_shape=(2, 8, 8, 3), attn_shapes=((1, 32, 2, 8), (1, 21, 3, 8)),
         attn_stream_shape=(1, 37, 4, 8),
-        decode_shapes=((3, 256, 4, 4, 32), (3, 128, 4, 2, 64)))
+        decode_shapes=((3, 256, 4, 4, 32), (3, 128, 4, 2, 64)),
+        ring_shapes=((3, 128, 4, 2, 64),),
+        chunk_shapes=((False, 128, 256, 2, 1, 128), (True, 128, 128, 2, 1, 128)), expert_shapes=((5, 32, 128, 4), (40, 32, 128, 4)))
     names = " ".join(r["kernels"])
     for kernel in ("top1", "normalize_u8", "flash(", "flash_grad",
-                   "decode_attention(3, 256", "decode_attention(3, 128"):
+                   "decode_attention(3, 256", "decode_attention(3, 128",
+                   "decode_attention(3, 128, 4, 2, 64) ring",
+                   "chunk_attention(128, 256, 2, 1, 128)", "chunk_attention(128, 128, 2, 1, 128) ring",
+                   "touched_experts_ffn gated(5,", "touched_experts_ffn gated(40,"):
         assert kernel in names
 
 
@@ -112,7 +117,8 @@ def test_a_failing_phase_is_fatal():
         chip_smoke.phase_kernels(
             platform="tpu", interpret=True, top1_batches=(2,), classes=17,
             norm_shape=(1, 8, 8, 3), attn_shapes=((1, 16, 1, 8),),
-            attn_stream_shape=(1, 16, 1, 8), decode_shapes=())
+            attn_stream_shape=(1, 16, 1, 8), decode_shapes=(), ring_shapes=(), chunk_shapes=(),
+            expert_shapes=())
 
 
 def test_main_refuses_to_run_without_a_tpu(capsys):

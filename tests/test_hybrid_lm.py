@@ -386,17 +386,18 @@ def test_the_small_batch_kernel_compiles_for_a_v5e_at_the_published_widths(one_c
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-def test_the_cells_decode_program_compiles_for_a_v5e_and_fits_its_memory(one_chip):
-    """The benchmark cell's decode scan (16 blocks at the published widths,
-    32 slots, 4096 positions) through the chip's compiler: the kernel stands
-    in every expert layer, XLA's grouped product in none, and parameters,
-    slot state and temporaries fit one chip's 16 GiB."""
-    cfg = H.HybridConfig(
-        pattern="MEMEM*EMEMEM*EME", vocab=65536, d_model=2688, ssm_heads=64,
-        ssm_head_dim=64, ssm_groups=8, ssm_state=128, n_heads=32, n_kv_heads=2,
-        head_dim=128, experts=128, experts_held=64, top_k=6, d_expert=1856,
-        d_shared=3712, max_seq=4096)
-    model = H.HybridSlotModel(cfg, 32, device=one_chip._device, donate=True)
+def _instructions(text):
+    """Instructions of a compiled program, every computation counted: the
+    number the structure tests pin (the parent's, from an ahead-of-time v5e
+    compile of the same program: a change that adds, drops or splits a
+    device operation of a program it should not touch moves it)."""
+    return len(re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = \S+ [\w\-]+\(", text, re.M))
+
+
+def _hybrid_programs(cfg, slots, chunk, one_chip):
+    """A hybrid cell's decode scan (``k = 8``) and prefill chunk lowered on
+    shapes placed on the described chip: ``(decode, prefill)``."""
+    model = H.HybridSlotModel(cfg, slots, device=one_chip._device, donate=True)
 
     def on_chip(tree):
         return jax.tree.map(
@@ -406,18 +407,41 @@ def test_the_cells_decode_program_compiles_for_a_v5e_and_fits_its_memory(one_chi
         return jax.eval_shape(lambda: H._cast(
             H._Tree(spec).init(jax.random.PRNGKey(0))["params"], cfg.dtype))
 
-    params = on_chip({
+    params = {
         "embed": born((("embedding", ((cfg.vocab, cfg.d_model), jax.nn.initializers.zeros)),)),
-        "blocks": [born(H.block_spec(cfg, kind)) for kind in cfg.pattern],
-        "norm_f": born(H._norm(cfg.d_model)),
-        "lm_head": born(H._dense(cfg.d_model, cfg.vocab))})
+        "blocks": [born(H.block_spec(cfg, group)) for group in cfg.groups],
+        "norm_f": born(H._norm(cfg.d_model))}
+    if not cfg.tied_head:
+        params["lm_head"] = born(H._dense(cfg.d_model, cfg.vocab))
+    params = on_chip(params)
     cache = on_chip(jax.eval_shape(lambda: {
-        "pos": jnp.zeros((32,), jnp.int32),
-        "counts": jnp.zeros((len(H.COUNTER_NAMES),), jnp.int32),
+        "pos": jnp.zeros((slots,), jnp.int32),
+        "counts": jnp.zeros((len(model.counter_names),), jnp.int32),
         "layers": {i: {n: jnp.zeros(*sd) for n, sd in leaves.items()}
                    for i, leaves in model._layer_shapes().items()}}))
-    vec = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
-    compiled = model.decode_fn(8).lower(params, cache, vec, vec, vec).compile()
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    toks = jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one_chip)
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return (model.decode_fn(8).lower(params, cache, vec, vec, vec),
+            model.prefill_fn(chunk).lower(params, cache, toks, slot))
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(one_chip):
+    cfg = H.HybridConfig(
+        pattern="MEMEM*EMEMEM*EME", vocab=65536, d_model=2688, ssm_heads=64,
+        ssm_head_dim=64, ssm_groups=8, ssm_state=128, n_heads=32, n_kv_heads=2,
+        head_dim=128, experts=128, experts_held=64, top_k=6, d_expert=1856,
+        d_shared=3712, max_seq=4096)
+    return _hybrid_programs(cfg, 32, 128, one_chip)
+
+
+def test_the_cells_decode_program_compiles_for_a_v5e_and_fits_its_memory(nemotron_programs):
+    """The benchmark cell's decode scan (16 blocks at the published widths,
+    32 slots, 4096 positions) through the chip's compiler: the kernel stands
+    in every expert layer, XLA's grouped product in none, and parameters,
+    slot state and temporaries fit one chip's 16 GiB."""
+    compiled = nemotron_programs[0].compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 7
     assert "ragged" not in text
@@ -427,6 +451,99 @@ def test_the_cells_decode_program_compiles_for_a_v5e_and_fits_its_memory(one_chi
     assert _leaf_makers(text, "bf16[32,4096,256]") == {"scatter": 4}
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13 << 30
     assert mem.temp_size_in_bytes < 256 << 20   # no whole-stack copy of the experts
+    # the program PR 32 left, operation for operation (PR 33 grew the family
+    # a window layer, a parallel block and gated experts around it)
+    assert _instructions(text) == 5766 and mem.temp_size_in_bytes == 19143680
+
+
+def test_the_cells_prefill_program_is_the_one_it_was(nemotron_programs):
+    """``nemotron3n_ep2_chat_closed32``'s 128-token chunk: its attention
+    keeps the whole-leaf form (67 MB of scores), its experts the relu2 call."""
+    compiled = nemotron_programs[1].compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 7
+    assert _instructions(text) == 6333
+    assert compiled.memory_analysis().temp_size_in_bytes == 36683264
+
+
+@pytest.fixture(scope="module")
+def cmdaplus_programs(one_chip):
+    """``cmdaplus_ep8_docs_closed16``: one period of command-a-plus at the
+    published widths, 16 of 128 experts, 16 slots, 16384 positions, chunks of
+    1024."""
+    cfg = H.HybridConfig(**{**H.FAMILIES["cohere2_moe"]["fields"], **dict(
+        vocab=32768, d_model=4096, n_heads=128, n_kv_heads=8, head_dim=128, window=4096,
+        rope_theta=50000.0, experts=128, experts_held=16, top_k=8, d_expert=4096,
+        shared_experts=4, d_shared=4096, max_seq=16384)})
+    return _hybrid_programs(cfg, 16, 1024, one_chip)
+
+
+def test_the_window_cells_decode_program_reads_both_kinds_of_leaf_through_the_kernel(
+        cmdaplus_programs):
+    compiled = cmdaplus_programs[0].compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 4
+    assert len(set(re.findall(r"%nns_decode_attention[.\d]* =", text))) == 4
+    assert "ragged" not in text
+    # a window layer's leaves hold the window, the global layer's the context,
+    # and nothing but the in-place row writes makes either
+    assert _leaf_makers(text, "bf16[16,4096,1024]") == {"scatter": 6}
+    assert _leaf_makers(text, "bf16[16,16384,1024]") == {"scatter": 2}
+    # 9.47 GB of parameters and 1.88 GB of slot state (268 MB a slot would
+    # be 4.3 GB with the context reserved for all four layers)
+    assert 11.3e9 < mem.argument_size_in_bytes < 11.4e9
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_the_window_cells_prefill_chunk_is_bounded_by_fill_and_window(cmdaplus_programs):
+    """A 1024-token chunk at 16384 positions: every layer's attention is one
+    ``nns_chunk_attention`` call bounded by fill and window, its scores in
+    VMEM (no (128, 1024, 16384) scores: 8.6 GB; nor the blocked jnp loop's
+    (128, 1024, 256) a block), the experts go through the kernel in blocks
+    of MAX_TOKENS rows, and the chunk's temporaries stay under 1 GB."""
+    compiled = cmdaplus_programs[1].compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 16
+    assert "ragged" not in text and "f32[1,8,16,1024,16384]" not in text
+    assert len(set(re.findall(r"%nns_chunk_attention[.\d]* =", text))) == 4
+    assert "f32[1,8,16,1024,128]" not in text       # the jnp loop's accumulator
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("ring,rows", [(False, 16384), (True, 4096)], ids=["global", "window"])
+def test_the_chunk_attention_kernel_compiles_for_a_v5e_at_the_window_cells_leaves(
+        one_chip, ring, rows):
+    """A 1024-row chunk of 128 query heads on 8 KV heads of 128 against one
+    slot's rows of a global leaf and of a round window leaf: Mosaic takes it
+    and nothing the size of the scores stands beside it."""
+    from nnstreamer_tpu.ops.chunk_attention import chunk_attention
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaf, new = arg((1, rows, 1024)), arg((1, 1024, 1024))
+    compiled = chunk_attention.lower(
+        leaf, leaf, arg((1, 1024, 16384)), new, new, arg((1,), jnp.int32),
+        n_heads=128, ring=ring).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("tokens", [16, 1024])
+def test_the_gated_kernel_compiles_for_a_v5e_at_the_window_cells_widths(one_chip, tokens):
+    """Three matrices an expert (16 held experts of 4096 x 4096 in bf16), a
+    decode step's rows and a 1024-token chunk; the relu2 call above lowers
+    as it did (two weight operands)."""
+    from nnstreamer_tpu.ops.expert_ffn import touched_experts_ffn
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = arg((16, 4096, 4096))
+    compiled = touched_experts_ffn.lower(
+        arg((tokens, 4096)), arg((tokens, 16), jnp.float32), w, w, w).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -451,13 +568,16 @@ def _leaf_makers(text, leaf):
     return made
 
 
-@pytest.mark.parametrize("leaf,heads", [((16, 1024, 1280), 20), ((32, 4096, 256), 32)],
-                         ids=["gpt2_large", "nemotron3_nano"])
+@pytest.mark.parametrize("leaf,heads", [((16, 1024, 1280), 20), ((32, 4096, 256), 32),
+                                        ((16, 4096, 1024), 128)],
+                         ids=["gpt2_large", "nemotron3_nano", "command_a_plus_window"])
 def test_the_decode_attention_kernel_compiles_for_a_v5e_at_both_cells_leaves(
         one_chip, leaf, heads):
     """Mosaic takes the kernel at the dense cell's leaves (20 heads of 64,
-    multi-head) and at the hybrid cell's (32 query heads on 2 KV heads of
-    128), and no temporary the size of a leaf stands beside it."""
+    multi-head), at the hybrid cell's (32 query heads on 2 KV heads of 128)
+    and at a window layer's round leaf (128 query heads on 8 KV heads, the
+    row to leave out as a second scalar operand), and no temporary the size
+    of a leaf stands beside it."""
     from nnstreamer_tpu.ops.decode_attention import decode_attention
 
     def arg(shape, dtype=jnp.bfloat16):
@@ -465,16 +585,18 @@ def test_the_decode_attention_kernel_compiles_for_a_v5e_at_both_cells_leaves(
 
     B, _, W = leaf
     head_dim = 64 if heads == 20 else 128
+    skip = arg((B,), jnp.int32) if heads == 128 else None
     compiled = decode_attention.lower(
         arg(leaf), arg(leaf), arg((B, 1, heads * head_dim)), arg((B, 1, W)),
-        arg((B, 1, W)), arg((B,), jnp.int32), n_heads=heads).compile()
+        arg((B, 1, W)), arg((B,), jnp.int32), n_heads=heads, skip=skip).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
-def _dense_decode_program(sharding_of, layers=2, slots=16, **model_kw):
+def _dense_decode_program(sharding_of, layers=2, slots=16, prefill=0, **model_kw):
     """The dense family's ``k = 8`` decode scan at GPT-2-large's widths,
-    cut to ``layers``, lowered on shapes placed by ``sharding_of``."""
+    cut to ``layers``, lowered on shapes placed by ``sharding_of``; or, with
+    ``prefill`` rows, its prefill chunk."""
     from nnstreamer_tpu.models.transformer import (
         SlotModel, TransformerConfig, TransformerLM)
 
@@ -490,6 +612,10 @@ def _dense_decode_program(sharding_of, layers=2, slots=16, **model_kw):
         TransformerLM(cfg).init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
     cache = placed(jax.eval_shape(lambda: model._model.init(
         jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32))["cache"]))
+    if prefill:
+        return model.prefill_fn(prefill).lower(
+            params, cache, jax.ShapeDtypeStruct((1, prefill), jnp.int32, sharding=sharding_of),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding_of))
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=sharding_of)
     return model.decode_fn(8).lower(params, cache, vec, vec, vec)
 
@@ -507,6 +633,16 @@ def test_the_dense_cells_decode_step_reads_its_leaves_through_the_kernel(one_chi
         one_chip, layers=36, device=one_chip._device).compile().as_text()
     assert len(set(re.findall(r"%nns_decode_attention[.\d]* =", text))) == 36
     assert _leaf_makers(text, "bf16[16,1024,1280]") == {"scatter": 72}
+    assert _instructions(text) == 13361     # PR 32's program, operation for operation
+
+
+def test_the_dense_cells_prefill_chunk_is_the_program_it_was(one_chip):
+    """``gpt2l_chat_closed16``'s 128-token chunk at its full depth keeps the
+    whole-leaf attention (10 MB of scores: far under the blocked form's
+    threshold) and the instructions PR 32 left."""
+    text = _dense_decode_program(
+        one_chip, layers=36, prefill=128, device=one_chip._device).compile().as_text()
+    assert _instructions(text) == 16359 and not re.findall(r"%while[.\d]* = ", text)
 
 
 def test_the_dense_decode_step_under_a_mesh_holds_no_custom_call(v5e):
