@@ -435,6 +435,33 @@ def _wait_idle(server, timeout_s=30.0):
         time.sleep(0.01)
 
 
+def _join_in_place(engine, donate):
+    """One join on the served model's own cache, its requests behind it:
+    slot 0 reads zero afterwards and slot 1 what it read before, and the
+    leaves that were passed in are gone exactly where the model donates
+    (the join then wrote its rows in place; else it made a second cache)."""
+    import jax
+    import numpy as np
+
+    old = jax.tree.leaves(engine._cache)
+    if not any(np.asarray(c[0]).any() for c in old):
+        raise AssertionError("slot 0 holds nothing a join could zero")
+    beside = [np.asarray(c[1]) for c in old]
+    engine._cache = engine.model.reset_slot(engine._cache, np.int32(0))
+    deleted = sum(c.is_deleted() for c in old)
+    if deleted != (len(old) if donate else 0):
+        raise AssertionError(
+            f"join with donate={donate}: {deleted} of {len(old)} cache "
+            "leaves it was passed are deleted")
+    for c, b in zip(jax.tree.leaves(engine._cache), beside):
+        if np.asarray(c[0]).any():
+            raise AssertionError("join left rows of its slot standing")
+        if not np.array_equal(np.asarray(c[1]), b):
+            raise AssertionError("join moved a neighbour's rows")
+    return {"leaves": len(old), "deleted": deleted, "slot_zero": True,
+            "neighbour_equal": True}
+
+
 def phase_generate(*, platform, custom="", vocab=256, slots=4, max_new=32,
                    chunk=8, prompt_lens=(5, 12, 33, 70, 5, 12, 33, 70),
                    prefix_len=70, mesh="", sid=910, timeout_s=600.0):
@@ -463,6 +490,7 @@ def phase_generate(*, platform, custom="", vocab=256, slots=4, max_new=32,
             raise AssertionError(f"generator state moved: {before} -> {after}")
         health = server.health()["gen"]
         _assert_healthy(server)
+        join = _join_in_place(engine, donate)
     finally:
         server.stop()
     for i, toks in enumerate(got):
@@ -503,6 +531,7 @@ def phase_generate(*, platform, custom="", vocab=256, slots=4, max_new=32,
         "streams": len(prompts), "tokens": int(sum(g.size for g in got)),
         "equal_to_one_shot": exact, "ties_by_margin": ties,
         "params_on": after[0], "cache_on": after[1], "donate": donate,
+        "join": join,
         "decode_steps": health["gen_decode_steps"],
         "tokens_per_step": health["gen_tokens_per_step"],
         "decode_compiles": health["gen_decode_compiles"],

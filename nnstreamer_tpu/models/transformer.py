@@ -712,7 +712,10 @@ class SlotModel:
       a ``(…, heads, head_dim)`` leaf is laid out sequence-minor on the
       device and every row write re-lays the whole cache out;
     * ``reset_slot(cache, slot)`` — zero ONE slot's pages + positions (a
-      join touches only its own slot; jitted once, slot is traced);
+      join touches only its own slot; jitted once, slot is traced).  The
+      cache argument is DONATED when the model's devices are not CPU,
+      exactly as ``decode_fn``'s is below: the rows are zeroed in place
+      and a caller must not touch the cache it passed in;
     * ``prefill_chunk(params, cache, toks (1,n), slot)`` — slice the
       slot's pages to a B=1 view, run one causal chunk (the chunked
       prefill that interleaves with decode), scatter back; returns
@@ -797,7 +800,8 @@ class SlotModel:
         #: join/leave churn)
         self.decode_compiles = 0
         self.prefill_compiles = 0
-        self.reset_slot = jax.jit(self._reset_slot)
+        self.reset_slot = jax.jit(
+            self._reset_slot, donate_argnums=(0,) if donate else ())
         self.pick_first = jax.jit(self._pick_first)
 
     def place_params(self, params):

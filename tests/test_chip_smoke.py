@@ -67,7 +67,10 @@ def test_generate_phase_tiny():
     assert r["streams"] == 4 and r["tokens"] == 32
     assert r["prefix"]["prefix_hits"] == 1
     assert r["prefix"]["prefix_hit_tokens"] == 64
-    assert r["donate"] is False  # XLA:CPU ignores donation
+    assert r["donate"] is False  # a CPU model asks for no donation
+    # so its join copies: nothing it was passed is deleted
+    assert r["join"] == {"leaves": 4, "deleted": 0, "slot_zero": True,
+                         "neighbour_equal": True}
 
 
 def test_train_phase_tiny():

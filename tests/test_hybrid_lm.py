@@ -190,8 +190,27 @@ def _serve(eng, prompts, max_new, gap_s=0.0, timeout=120.0):
     return [np.asarray(out[k], np.int32) for k in sorted(out)]
 
 
-def test_streams_under_churn_get_the_tokens_they_get_alone(rng):
-    model, params, max_seq = H.build_slot_stream(props(), 4)
+#: the dense family at a size the churn below serves in seconds
+DENSE = {k: str(v) for k, v in {
+    "dtype": "float32", "vocab": VOCAB, "d_model": 32, "heads": 2, "layers": 2,
+    "d_ff": 64, "seq": 96, "seed": SEED}.items()}
+
+
+@pytest.mark.parametrize("family,donate", [
+    ("nemotron_h", None), ("dense", None), ("dense", True)],
+    ids=["nemotron_h", "dense", "dense-donated"])
+def test_streams_under_churn_get_the_tokens_they_get_alone(rng, family, donate):
+    """Both families through one engine; ``dense-donated``: every program
+    that takes the cache, the join too, consumes the one it was passed (this
+    jax deletes a donated buffer on the CPU as on the chip), so an engine
+    that held an old cache across a join or a step would fail here, and the
+    tokens are those of the model that copies."""
+    def build(**kw):
+        if family == "dense":
+            return build_dense(DENSE, 4, **kw)
+        return H.build_slot_stream(props(), 4, **kw)
+
+    model, params, max_seq = build(donate=donate)
     prompts = [rng.integers(0, VOCAB, (1, n)).astype(np.int32)
                for n in (5, 17, 9, 24, 3, 12, 20, 8, 16, 7)]
     lens = [6, 13, 9, 13, 6, 9, 13, 6, 9, 13]
@@ -211,13 +230,25 @@ def test_streams_under_churn_get_the_tokens_they_get_alone(rng):
         assert model.decode_compiles == buckets <= 4
         snap = eng.snapshot()
         assert snap["gen_decode_compiles"] == buckets
-        # the routing counters are always on, and add up
-        assert snap["gen_moe_layer_steps"] > 0
-        assert 0 < snap["gen_moe_prefill_local"] < snap["gen_moe_local"]
-        assert snap["gen_moe_expert_reads"] <= 8 * snap["gen_moe_layer_steps"]
-        assert snap["gen_moe_max_load"] >= snap["gen_moe_layer_steps"]
+        assert snap["gen_oom_sheds"] == 0     # no cache died under the engine
+        if family == "nemotron_h":
+            # the routing counters are always on, and add up
+            assert snap["gen_moe_layer_steps"] > 0
+            assert 0 < snap["gen_moe_prefill_local"] < snap["gen_moe_local"]
+            assert snap["gen_moe_expert_reads"] <= 8 * snap["gen_moe_layer_steps"]
+            assert snap["gen_moe_max_load"] >= snap["gen_moe_layer_steps"]
     finally:
         eng.stop()
+    if donate:
+        plain, plain_params, _ = build(donate=False)
+        eng = _engine(plain, plain_params, max_seq)
+        try:
+            for p, n, got in zip(prompts, lens, alone):
+                np.testing.assert_array_equal(_serve(eng, [p], n)[0], got)
+        finally:
+            eng.stop()
+    if family != "nemotron_h":
+        return
     # and each stream is what the reference's full forward would pick
     handle = ref.make_params(REF, SEED)
     for p, got in list(zip(prompts, alone))[:3]:
@@ -593,29 +624,33 @@ def test_the_decode_attention_kernel_compiles_for_a_v5e_at_both_cells_leaves(
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
-def _dense_decode_program(sharding_of, layers=2, slots=16, prefill=0, **model_kw):
+def _dense_decode_program(sharding_of, layers=2, slots=16, prefill=0, join=False,
+                          **model_kw):
     """The dense family's ``k = 8`` decode scan at GPT-2-large's widths,
     cut to ``layers``, lowered on shapes placed by ``sharding_of``; or, with
-    ``prefill`` rows, its prefill chunk."""
+    ``prefill`` rows, its prefill chunk; or its ``join``."""
     from nnstreamer_tpu.models.transformer import (
         SlotModel, TransformerConfig, TransformerLM)
 
     cfg = TransformerConfig(vocab=50257, d_model=1280, n_heads=20,
                             n_layers=layers, d_ff=5120, max_seq=1024)
-    model = SlotModel(cfg, slots, donate=True, **model_kw)
+    model = SlotModel(cfg, slots, **{"donate": True, **model_kw})
 
     def placed(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=sharding_of), tree)
 
-    params = placed(jax.eval_shape(
-        TransformerLM(cfg).init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
     cache = placed(jax.eval_shape(lambda: model._model.init(
         jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32))["cache"]))
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding_of)
+    if join:
+        return model.reset_slot.lower(cache, slot)
+    params = placed(jax.eval_shape(
+        TransformerLM(cfg).init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
     if prefill:
         return model.prefill_fn(prefill).lower(
             params, cache, jax.ShapeDtypeStruct((1, prefill), jnp.int32, sharding=sharding_of),
-            jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding_of))
+            slot)
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=sharding_of)
     return model.decode_fn(8).lower(params, cache, vec, vec, vec)
 
@@ -643,6 +678,35 @@ def test_the_dense_cells_prefill_chunk_is_the_program_it_was(one_chip):
     text = _dense_decode_program(
         one_chip, layers=36, prefill=128, device=one_chip._device).compile().as_text()
     assert _instructions(text) == 16359 and not re.findall(r"%while[.\d]* = ", text)
+
+
+def test_the_dense_cells_join_zeroes_its_slot_in_place(one_chip):
+    """``gpt2l_chat_closed16``'s join at its leaves (36 layers x K and V of
+    ``(16,1024,1280)`` bf16 and the positions: 3.02 GB), donated as the
+    decode and prefill programs take it: every leaf is aliased to its
+    result, the only instructions that make a leaf are the row writes, each
+    in place on its operand, and nothing the size of a leaf (42 MB) stands
+    beside them.  Not donated, the same program copies every leaf: a
+    second cache at each join."""
+    leaf, leaf_bytes = "bf16[16,1024,1280]", 16 * 1024 * 1280 * 2
+
+    def join(donate):
+        compiled = _dense_decode_program(
+            one_chip, layers=36, join=True, donate=donate,
+            device=one_chip._device).compile()
+        return compiled.as_text(), compiled.memory_analysis()
+
+    text, mem = join(True)
+    assert mem.alias_size_in_bytes >= 72 * leaf_bytes
+    assert mem.temp_size_in_bytes < leaf_bytes
+    assert _leaf_makers(text, leaf) == {"dynamic-update-slice": 72, "fusion": 72}
+    writes = [line for line in text.splitlines()
+              if re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = {re.escape(leaf)}\S* fusion\(", line)]
+    assert len(writes) == 72 and all(
+        'dynamic_update_slice"' in w and '"aliasing_operands"' in w for w in writes)
+    text, mem = join(False)
+    assert mem.alias_size_in_bytes == 0
+    assert _leaf_makers(text, leaf).get("copy", 0) >= 36
 
 
 def test_the_dense_decode_step_under_a_mesh_holds_no_custom_call(v5e):

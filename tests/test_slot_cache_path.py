@@ -224,6 +224,37 @@ class TestProgramStructure:
         assert len(kernels) == (layers if form == "kernel" else 0)
         assert {e.params["name"] for e in kernels} <= {"nns_decode_attention"}
 
+    @pytest.mark.parametrize("donate", [True, False, None])
+    @pytest.mark.parametrize("family", ["dense", "nemotron_h"])
+    def test_a_join_takes_the_cache_as_the_step_programs_do(
+            self, family, donate):
+        """``reset_slot`` follows the model's ``donate`` like the prefill
+        and decode programs: donated, every cache leaf is a donor of the
+        lowered join and aliases its own result; not donated (``None`` on
+        a CPU), none is.  A lowering needs no device that honours it."""
+        if family == "dense":
+            model, _, _ = build_slot_stream(SPROPS, 4, donate=donate)
+        else:
+            from nnstreamer_tpu.models import hybrid_lm
+
+            model = hybrid_lm.HybridSlotModel(hybrid_lm.cfg_from_props({
+                "layers": "M*E", "vocab": "61", "d_model": "32",
+                "ssm_heads": "2", "ssm_head_dim": "16", "ssm_groups": "1",
+                "ssm_state": "8", "heads": "2", "kv_heads": "1",
+                "head_dim": "16", "experts": "4", "experts_held": "4",
+                "experts_per_tok": "2", "d_expert": "16", "d_shared": "16",
+                "seq": "64", "dtype": "float32"}), 4, donate=donate)
+        cache = model.init_cache()
+        lowered = model.reset_slot.lower(cache, np.int32(1))
+        (cache_info, slot_info), _ = lowered.args_info
+        n = len(jax.tree.leaves(cache))
+        assert [a.donated for a in jax.tree.leaves(cache_info)] == (
+            [bool(donate)] * n)
+        assert not slot_info.donated
+        assert lowered.as_text().count("tf.aliasing_output") == (
+            n if donate else 0)
+        assert bool(model._donate) == bool(donate)  # one flag, three programs
+
     @pytest.mark.parametrize("n", [4, 32])
     def test_prefill_chunk_holds_no_loop(self, n):
         """The benchmark tells the decode program from the prefill programs
