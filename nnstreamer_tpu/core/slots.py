@@ -606,7 +606,8 @@ class SlotEngine:
                  resume_sig: Optional[str] = None,
                  on_device_lost: Optional[Callable[..., Any]] = None,
                  slo=None,
-                 prefix_cache: Optional[PrefixCache] = None):
+                 prefix_cache: Optional[PrefixCache] = None,
+                 on_ready: Optional[Callable[[], None]] = None):
         import numpy as np
 
         self._np = np
@@ -651,6 +652,16 @@ class SlotEngine:
         # place, e.g. the sim twin).  Without a hook a lost device is a
         # sticky engine error (supervision restart rebuilds the element).
         self.on_device_lost = on_device_lost
+        # the consumer's wake-up (pump thread; must not block): called
+        # once per batch of ready outputs, so that a turn's frames leave
+        # when they exist and not at the consumer's next poll.  A batch
+        # is DUE when an output lands in an empty ready list and is
+        # announced right after the pump's next device dispatch (or as
+        # the pump goes idle): the consumer's burst of deliveries then
+        # shares the interpreter with a pump that waits for the device,
+        # not with the dispatch the device waits for
+        self.on_ready = on_ready
+        self._wake_due = False
         # per-stream SLO accounting (telemetry.SloTracker, engine side):
         # one TTFT stamp at the first-token pick, one record_n per
         # decode scan, one counter per terminal outcome — all on the
@@ -1004,6 +1015,8 @@ class SlotEngine:
         s.chunk_index += 1
         self._ready.append((0, out))
         self._progress.notify_all()
+        if len(self._ready) == 1:
+            self._wake_due = True
 
     def _emit_boundary(self, s: GenStream) -> None:
         """Emit EXACTLY chunk-sized pieces (lock held) — identical
@@ -1130,7 +1143,17 @@ class SlotEngine:
         raw-runtime-error typing) — the pump's recovery ladder keys on
         types, never on XLA status strings."""
         with self._on_device():
-            return device_call(fn, *args)
+            out = device_call(fn, *args)
+        self._announce_ready()
+        return out
+
+    def _announce_ready(self) -> None:
+        """Tell the consumer that a batch of outputs is ready, if one is
+        due (``on_ready``)."""
+        if self._wake_due:
+            self._wake_due = False
+            if self.on_ready is not None:
+                self.on_ready()
 
     @contextmanager
     def _on_device(self):
@@ -1350,6 +1373,7 @@ class SlotEngine:
                 # the one sleep of the thread that feeds the device:
                 # named as a wait, so an idle chip reads "no request"
                 t0 = self.clock()
+                self._announce_ready()  # no dispatch is coming to do it
                 with span("nns.slots.wait_request"):
                     self._work.wait(0.05)
                 self._idle_s += self.clock() - t0

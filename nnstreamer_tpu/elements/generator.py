@@ -80,9 +80,10 @@ class TensorGenerator(Element):
             str, "",
             "zoo-transformer dialect: vocab:N,d_model:N,heads:N,layers:N,"
             "d_ff:N,seq:N,seed:N[,temperature:F,top_k:N,gen_seed:N]; "
-            "arch:nemotron_h or arch:cohere2_moe selects the hybrid family "
-            "(layers:<pattern of M, E, *, W, parallel blocks in parentheses> "
-            "and its widths: Documentation/examples.md)",
+            "arch:nemotron_h, arch:cohere2_moe or arch:lfm2_moe selects the "
+            "hybrid family (layers:<pattern of M, E, *, W, C, D, parallel "
+            "blocks in parentheses> and its widths: "
+            "Documentation/examples.md)",
         ),
         "max-new": Property(int, 32, "tokens to generate per prompt"),
         "chunk": Property(int, 8, "tokens per streamed chunk frame"),
@@ -343,6 +344,7 @@ class TensorGenerator(Element):
                 on_device_lost=self._rebuild_on_device_loss,
                 slo=self._slo,
                 prefix_cache=self._prefix_pool,
+                on_ready=self.wake_dispatch,
             )
             self._engine.start()
             return
@@ -644,6 +646,7 @@ class TensorGenerator(Element):
             # params, and its counters must stay monotonic for the
             # observatory's exact fleet totals
             prefix_cache=self._prefix_pool,
+            on_ready=self.wake_dispatch,
         )
         # the server's lifetime ledger survives the rebuild — digests
         # and the observatory's exact fleet totals must stay monotonic
@@ -675,7 +678,7 @@ class TensorGenerator(Element):
     @staticmethod
     def _zoo_family(props):
         """(family name, module) of the model the ``custom=`` dialect
-        names: ``arch:nemotron_h`` and ``arch:cohere2_moe`` are the hybrid
+        names: ``arch:nemotron_h``, ``arch:cohere2_moe`` and ``arch:lfm2_moe`` are the hybrid
         family (models/hybrid_lm.py, ``FAMILIES``); anything else is the
         dense transformer.  The ONE place a family is chosen; a module
         gives ``build_slot_stream`` and ``resume_fields``."""

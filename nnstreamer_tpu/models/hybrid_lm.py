@@ -1,6 +1,7 @@
 """Hybrid decoder LM for the slotted generation path: the mixers of every
-block chosen by a pattern string (the ``nemotron_h`` and ``cohere2_moe``
-families; :data:`FAMILIES` holds what differs between them as config fields).
+block chosen by a pattern string (the ``nemotron_h``, ``cohere2_moe`` and
+``lfm2_moe`` families; :data:`FAMILIES` holds what differs between them as
+config fields).
 
 A block is ``x <- x + Mixer(Norm(x))``, or, for letters in parentheses, a
 PARALLEL block ``h = Norm(x); x <- x + sum of Mixer_j(h)``: one norm, every
@@ -15,30 +16,46 @@ weight-only LayerNorm (``norm``).  The pattern names each mixer by one letter:
   chunked scan of the Mamba-2 paper for a prefill chunk (from the state the
   slot's previous chunk left) and the one-step recurrence in the decode
   scan.
-* ``*`` — grouped-query attention without positional encoding, through
+* ``*`` — grouped-query attention through
   :func:`~nnstreamer_tpu.models.transformer.kv_attend_write` (the one cache
   step every generation path shares): the GLOBAL layer, its K/V leaves hold
-  ``max_seq`` rows by position.
+  ``max_seq`` rows by position.  Without positional encoding, or, where the
+  family says so (``rope_global``), ``q`` and ``k`` turned by rotary
+  positions before the cache write; ``qk_norm``: an RMSNorm over
+  ``head_dim`` with a learned weight on every query and key head first.
 * ``W`` — the same attention as a WINDOW layer: rotary positions on ``q`` and
-  ``k`` before the cache write (interleaved pairs, angles in float32 from the
-  absolute position, computed in the program), a query at position ``p``
-  sees ``p - window + 1 .. p``, and its leaves hold ``window`` rows written
-  round (position ``p`` at row ``p mod window``).
+  ``k`` before the cache write (angles in float32 from the absolute
+  position, computed in the program; the pairs are the family's
+  ``rope_pairs``: ``interleaved`` ``(x[2i], x[2i+1])`` or ``half`` ``(x[i],
+  x[i + head_dim/2])``), a query at position ``p`` sees ``p - window + 1 ..
+  p``, and its leaves hold ``window`` rows written round (position ``p`` at
+  row ``p mod window``).
+* ``C`` — the gated short convolution: ``[B | C | u] = x W_in``; ``g = B * u``;
+  a causal depthwise convolution of ``conv`` taps over ``g`` (no bias, no
+  activation); ``(C * conv) W_out``.  Its slot state is the window of the
+  last ``conv - 1`` rows of ``g`` and nothing else: a prefill chunk starts
+  from the window the slot's previous chunk left, and the decode scan's one
+  step is the same sum over ``conv`` rows.
+* ``D`` — a dense gated MLP as a block of its own: ``(silu(x W_gate) * (x
+  W_up)) W_down`` of width ``d_ff``; no state.
 * ``E`` — routed experts: sigmoid router in float32 over ALL ``experts``,
   top-``top_k`` of score (+ bias where the family has one), weights
   normalised over the chosen and scaled; an expert is ``relu(x W_up)^2
   W_down`` or, ``expert_act = silu_gated``, ``(silu(x W_gate) * (x W_up))
   W_down``; ``shared_experts`` shared experts of width ``d_shared`` run for
-  every token, summed or averaged (kept side by side as ONE FFN).  The layer is TOLD which experts it holds
+  every token, summed or averaged (kept side by side as ONE FFN; with
+  ``shared_experts: 0`` the layer has no shared leaves and computes none).
+  The layer is TOLD which experts it holds
   (``[expert_offset, expert_offset + experts_held)``): it routes over all of
   them and computes its own experts' part — tokens sorted by expert, one
   grouped product over the held experts, no capacity limit, no token a held
   expert was chosen for ever dropped.  What absent experts would add is left
   out (on one chip the layer runs without its exchange).
 
-A slot owns state of three kinds: K/V rows by position per global layer, a
-window of K/V rows written round per window layer, and per Mamba-2 layer a
-conv window and a scan state with NO position axis.
+A slot owns state of four kinds: K/V rows by position per global layer, a
+window of K/V rows written round per window layer, per Mamba-2 layer a conv
+window and a scan state with NO position axis, and per short-convolution
+layer a conv window alone.
 :class:`HybridSlotModel` implements what ``core/slots.py`` calls on a slot
 model (``core.slots.SlotModelProtocol``).
 
@@ -73,14 +90,20 @@ FAMILY = "nemotron_h"
 #: the ``arch:`` names this module serves: the stem of each one's program
 #: names (``jit_nns_<stem>_decode``) and the config fields in which it
 #: differs from :class:`HybridConfig`'s defaults.  ``norm``, ``tied_head``,
-#: ``expert_act``, ``router_bias`` and ``shared_combine`` are the family's
-#: alone; a ``custom=`` key may set the others.
+#: ``expert_act``, ``router_bias``, ``shared_combine``, ``rope_global``,
+#: ``rope_pairs``, ``qk_norm``, ``route_eps`` and ``kv_counters`` are the
+#: family's alone; a ``custom=`` key may set the others.
 FAMILIES = {
     FAMILY: dict(stem="hybrid", fields={}),
     "cohere2_moe": dict(stem="cohere2_moe", fields=dict(
         pattern="(WE)(WE)(WE)(*E)", norm="layer", expert_act="silu_gated",
         shared_combine="average", router_bias=False, routed_scale=1.0,
         tied_head=True)),
+    "lfm2_moe": dict(stem="lfm2_moe", fields=dict(
+        pattern="CDCD*ECECECE*ECECECE*ECE", conv_kernel=3, expert_act="silu_gated",
+        routed_scale=1.0, route_eps=1e-6, shared_experts=0, tied_head=True,
+        rope_theta=1e6, rope_global=True, rope_pairs="half", qk_norm=True,
+        kv_counters=True)),
 }
 #: always-on counters the decode scan and the prefill chunks sum over their
 #: steps and ``E`` layers (the engine adds them to ``snapshot()``): choices
@@ -90,7 +113,8 @@ FAMILIES = {
 COUNTER_NAMES = ("gen_moe_local", "gen_moe_expert_reads", "gen_moe_max_load",
                  "gen_moe_layer_steps", "gen_moe_prefill_local",
                  "gen_moe_prefill_reads")
-#: and, for a pattern with a window layer, summed the same way over attention
+#: and, for a pattern with a window layer or a family with ``kv_counters``,
+#: summed the same way over attention
 #: layers and live slots: cache rows a decode step NEEDS by position and
 #: window, rows its reads covered, rows the leaves hold; and the keys a
 #: prefill chunk's queries see (their own row counted), which follow from the
@@ -124,6 +148,13 @@ class HybridConfig:
     # window layers: rows a query sees (its own counted), rotary base
     window: int = 0
     rope_theta: float = 10000.0
+    # rotary on the global layers too; the pairs turned: interleaved | half
+    rope_global: bool = False
+    rope_pairs: str = "interleaved"
+    qk_norm: bool = False        # RMSNorm over head_dim on q and k heads
+    kv_counters: bool = False    # KV_COUNTER_NAMES without a window layer
+    # the dense gated MLP (D): its width
+    d_ff: int = 0
     # routed experts: the router's width, the share held here, experts per token
     experts: int = 8
     experts_held: int = 8
@@ -133,6 +164,7 @@ class HybridConfig:
     expert_act: str = "relu2"    # relu2 | silu_gated
     router_bias: bool = True
     routed_scale: float = 2.5
+    route_eps: float = 0.0       # added to the chosen scores' normalising sum
     # shared experts: how many, the width of one, sum | average
     shared_experts: int = 1
     d_shared: int = 64
@@ -145,13 +177,23 @@ class HybridConfig:
         parse_pattern(self.pattern)  # raises by name
         for field, known in (("norm", ("rms", "layer")),
                              ("expert_act", ("relu2", "silu_gated")),
-                             ("shared_combine", ("sum", "average"))):
+                             ("shared_combine", ("sum", "average")),
+                             ("rope_pairs", ("interleaved", "half"))):
             if getattr(self, field) not in known:
                 raise ValueError(f"{field}:{getattr(self, field)}: one of {known}")
         if "W" in self.pattern and (self.window < 1 or self.head_dim % 2):
             raise ValueError(
                 f"a window layer needs window >= 1 (got {self.window}) and an "
                 "even head_dim (rotary pairs)")
+        if self.rope_global and self.head_dim % 2:
+            raise ValueError("rotary positions need an even head_dim")
+        if "D" in self.pattern and self.d_ff < 1:
+            raise ValueError(f"a dense MLP block (D) needs d_ff >= 1, got {self.d_ff}")
+        if "C" in self.pattern and self.conv_kernel < 2:
+            raise ValueError(
+                f"a short convolution (C) needs conv >= 2 taps, got {self.conv_kernel}")
+        if self.shared_experts < 0:
+            raise ValueError(f"shared_experts:{self.shared_experts}: not a count")
         if self.ssm_heads % self.ssm_groups or self.n_heads % self.n_kv_heads:
             raise ValueError("heads must divide by their groups")
         if not (0 <= self.expert_offset
@@ -176,6 +218,11 @@ class HybridConfig:
                            for name, kind in zip(mixer_names(group), group))
                      for group in self.groups)
 
+    @property
+    def kv_counted(self) -> bool:
+        """The programs sum :data:`KV_COUNTER_NAMES`."""
+        return self.kv_counters or "W" in self.pattern
+
     def kv_rows(self, kind: str) -> int:
         """Rows of one slot's K and V leaves in a layer of ``kind``."""
         return min(self.window, self.max_seq) if kind == "W" else self.max_seq
@@ -189,6 +236,9 @@ class HybridConfig:
         return self.d_inner + 2 * self.ssm_groups * self.ssm_state
 
 
+_LETTERS = "ME*WCD"
+
+
 @functools.lru_cache(maxsize=None)
 def parse_pattern(pattern: str):
     """``"(WE)M*"`` -> ``("WE", "M", "*")``: a letter is a block of one
@@ -200,16 +250,16 @@ def parse_pattern(pattern: str):
         elif ch == ")" and inside:
             out.append(inside)
             inside = None
-        elif ch in "ME*W" and inside is None:
+        elif ch in _LETTERS and inside is None:
             out.append(ch)
-        elif ch in "ME*W":
+        elif ch in _LETTERS:
             inside += ch
         else:
             out = None
             break
     if not out or inside is not None:
         raise ValueError(
-            f"layers pattern {pattern!r}: one of M, E, *, W per mixer, the "
+            f"layers pattern {pattern!r}: one of M, E, *, W, C, D per mixer, the "
             "mixers of a parallel block in one pair of parentheses")
     return tuple(out)
 
@@ -249,6 +299,11 @@ def cfg_from_props(props: Dict[str, str]) -> HybridConfig:
         head_dim=num("head_dim", d.head_dim),
         window=num("window", d.window),
         rope_theta=num("rope_theta", d.rope_theta, float),
+        rope_global=d.rope_global,
+        rope_pairs=d.rope_pairs,
+        qk_norm=d.qk_norm,
+        kv_counters=d.kv_counters,
+        d_ff=num("d_ff", d.d_ff),
         experts=num("experts", d.experts),
         experts_held=num("experts_held", props.get("experts", d.experts_held)),
         expert_offset=num("expert_offset", d.expert_offset),
@@ -257,6 +312,7 @@ def cfg_from_props(props: Dict[str, str]) -> HybridConfig:
         expert_act=d.expert_act,
         router_bias=d.router_bias,
         routed_scale=num("routed_scale", d.routed_scale, float),
+        route_eps=d.route_eps,
         shared_experts=num("shared_experts", d.shared_experts),
         d_shared=num("d_shared", d.d_shared),
         shared_combine=d.shared_combine,
@@ -362,6 +418,15 @@ def mixer_spec(cfg: HybridConfig, kind: str):
         q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         mixer = (("q_proj", _dense(d, q)), ("k_proj", _dense(d, kv)),
                  ("v_proj", _dense(d, kv)), ("o_proj", _dense(q, d)))
+        if cfg.qk_norm:
+            mixer += (("q_norm", _norm(cfg.head_dim)), ("k_norm", _norm(cfg.head_dim)))
+    elif kind == "C":
+        mixer = (("in_proj", _dense(d, 3 * d)),
+                 ("conv", (("kernel", ((cfg.conv_kernel, 1, d), _lecun)),)),
+                 ("out_proj", _dense(d, d)))
+    elif kind == "D":
+        mixer = (("gate_proj", _dense(d, cfg.d_ff)), ("up_proj", _dense(d, cfg.d_ff)),
+                 ("down_proj", _dense(cfg.d_ff, d)))
     else:
         held, f = cfg.experts_held, cfg.d_expert
         # an expert's width is stored padded with zeros to whole 128-lane
@@ -386,10 +451,12 @@ def mixer_spec(cfg: HybridConfig, kind: str):
              + (bias if cfg.router_bias else ())),
             ("experts", ((("gate", ((held, d, fp), up)),) if gated else ())
              + (("up", ((held, d, fp), up)), ("down", ((held, fp, d), down)))),
-        ) + ((("shared_gate", shared(d, fs, 1)),) if gated else ()) + (
-            ("shared_up", shared(d, fs, 1)),
-            ("shared_down", shared(fs, d, 0)),
         )
+        if n:  # no shared expert: no shared leaves
+            mixer += ((("shared_gate", shared(d, fs, 1)),) if gated else ()) + (
+                ("shared_up", shared(d, fs, 1)),
+                ("shared_down", shared(fs, d, 0)),
+            )
     return mixer
 
 
@@ -458,27 +525,41 @@ def _layer_norm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
+def _head_rms(x, scale, n_heads: int, eps):
+    """RMSNorm over ``head_dim`` of every head of ``x`` (B, T, heads x
+    head_dim) with ONE learned weight (head_dim,) for all heads (QK norm)."""
+    return _rms(x, jnp.tile(scale, n_heads), eps, groups=n_heads).astype(x.dtype)
+
+
 def _normed(x, scale, cfg):
     norm = _rms if cfg.norm == "rms" else _layer_norm
     return norm(x, scale, cfg.norm_eps).astype(cfg.dtype)
 
 
-def rotary(x, pos, n_heads: int, theta: float):
-    """Rotary positions on ``x`` (B, T, heads x head_dim), the interleaved
-    form: the pair ``(x[2i], x[2i+1])`` of every head turned by ``p x
-    theta^(-2i / head_dim)``, ``p = pos[b] + t`` the row's absolute
-    position.  Angles, sines and the rotation are float32, made here from
-    ``pos`` (no table is baked into the program)."""
+def rotary(x, pos, n_heads: int, theta: float, pairs: str = "interleaved"):
+    """Rotary positions on ``x`` (B, T, heads x head_dim): pair ``i`` of every
+    head turned by ``p x theta^(-2i / head_dim)``, ``p = pos[b] + t`` the
+    row's absolute position.  ``pairs``: ``interleaved``, the pair ``(x[2i],
+    x[2i+1])``, or ``half``, the pair ``(x[i], x[i + head_dim/2])``
+    (transformers' ``rotate_half``).  Angles, sines and the rotation are
+    float32, made here from ``pos`` (no table is baked into the program)."""
     B, T, D = x.shape
     dh = D // n_heads
     lane = jnp.arange(dh)
-    inv = jnp.asarray(theta, _F32) ** (-(lane // 2 * 2).astype(_F32) / dh)
+    half = pairs == "half"
+    pair = lane % (dh // 2) if half else lane // 2
+    inv = jnp.asarray(theta, _F32) ** (-(pair * 2).astype(_F32) / dh)
     p = (pos[:, None] + jnp.arange(T)[None, :]).astype(_F32)
     ang = p[:, :, None, None] * inv  # (B, T, 1, dh)
     xf = x.astype(_F32).reshape(B, T, n_heads, dh)
-    # the pair's other element, signed: out[2i] = x[2i] cos - x[2i+1] sin,
-    # out[2i+1] = x[2i+1] cos + x[2i] sin
-    other = jnp.where(lane % 2 == 0, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
+    if half:
+        # out[i] = x[i] cos - x[i + dh/2] sin, out[i + dh/2] = x[i + dh/2] cos + x[i] sin
+        turned = jnp.roll(xf, dh // 2, axis=-1)
+        other = jnp.where(lane < dh // 2, -turned, turned)
+    else:
+        # the pair's other element, signed: out[2i] = x[2i] cos - x[2i+1] sin,
+        # out[2i+1] = x[2i+1] cos + x[2i] sin
+        other = jnp.where(lane % 2 == 0, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
     return (xf * jnp.cos(ang) + other * jnp.sin(ang)).reshape(B, T, D).astype(x.dtype)
 
 
@@ -530,6 +611,17 @@ def ssm_chunked(h, u, dt, a, bm, cm, chunk):
     return h, y[:, :T]
 
 
+def _window_conv(conv, new, kernel, taps: int):
+    """The causal depthwise convolution of ``new`` (B, T, C) continuing the
+    window ``conv`` (B, taps-1, C) of the rows before it: ``(out (B, T, C)
+    float32, the window the rows leave)``.  ``T == 1`` is the one-step form."""
+    T = new.shape[1]
+    seq = jnp.concatenate([conv, new], axis=1)  # (B, taps-1+T, C)
+    w = kernel[:, 0].astype(_F32)  # (taps, C)
+    out = sum(seq[:, k:k + T].astype(_F32) * w[k] for k in range(taps))
+    return out, seq[:, T:]
+
+
 def mamba_mix(p, x, conv, ssm, cfg: HybridConfig, keep=None):
     """Returns ``(out, conv, ssm)``.  ``conv`` (B, K-1, C) is the window of
     the last ``K-1`` inputs of the convolution, ``ssm`` (B, H, P, N) float32.
@@ -540,12 +632,8 @@ def mamba_mix(p, x, conv, ssm, cfg: HybridConfig, keep=None):
     with jax.named_scope("nns.ssm"):
         zxd = _mm(x, p["in_proj"]["kernel"], cfg.dtype)
         z, xbc, dt = jnp.split(zxd, [di, di + cfg.d_conv], axis=-1)
-        seq = jnp.concatenate([conv, xbc], axis=1)  # (B, K-1+T, C)
-        taps = p["conv"]["kernel"][:, 0].astype(_F32)  # (K, C)
-        out = sum(seq[:, k:k + T].astype(_F32) * taps[k]
-                  for k in range(cfg.conv_kernel))
+        out, new_conv = _window_conv(conv, xbc, p["conv"]["kernel"], cfg.conv_kernel)
         xbc = jax.nn.silu(out + p["conv"]["bias"].astype(_F32)).astype(cfg.dtype)
-        new_conv = seq[:, T:]
         u, bm, cm = jnp.split(xbc.astype(_F32), [di, di + G * N], axis=-1)
         u = u.reshape(B, T, G, Hg, P)
         bm, cm = bm.reshape(B, T, G, N), cm.reshape(B, T, G, N)
@@ -567,19 +655,49 @@ def mamba_mix(p, x, conv, ssm, cfg: HybridConfig, keep=None):
         return _mm(y, p["out_proj"]["kernel"], cfg.dtype), new_conv, new_ssm
 
 
+def conv_mix(p, x, conv, cfg: HybridConfig, keep=None):
+    """The gated short convolution.  Returns ``(out, conv)``: ``conv`` (B,
+    K-1, D) is the window of the last ``K-1`` rows of the gated input ``B *
+    u``, the layer's whole slot state.  A chunk (``T > 1``) and the one-step
+    form (``T == 1``) are the same sum over ``K`` shifted rows of ``[window |
+    new rows]``, in float32 (:func:`_window_conv`, Mamba-2's too).  ``keep``
+    (B,) bool: rows whose window must come out bit-equal."""
+    with jax.named_scope("nns.conv"):
+        b, c, u = jnp.split(_mm(x, p["in_proj"]["kernel"], cfg.dtype), 3, axis=-1)
+        g = (b.astype(_F32) * u.astype(_F32)).astype(cfg.dtype)
+        out, new_conv = _window_conv(conv, g, p["conv"]["kernel"], cfg.conv_kernel)
+        if keep is not None:
+            new_conv = jnp.where(keep[:, None, None], conv, new_conv)
+        y = (c.astype(_F32) * out).astype(cfg.dtype)
+        return _mm(y, p["out_proj"]["kernel"], cfg.dtype), new_conv
+
+
+def mlp_mix(p, x, cfg: HybridConfig):
+    """The dense gated MLP: ``(silu(x W_gate) * (x W_up)) W_down``."""
+    with jax.named_scope("nns.mlp"):
+        hid = _expert_act(cfg, _mm(x, p["up_proj"]["kernel"], _F32),
+                          _mm(x, p["gate_proj"]["kernel"], _F32)).astype(cfg.dtype)
+        return _mm(hid, p["down_proj"]["kernel"], cfg.dtype)
+
+
 def attn_mix(p, x, ck, cv, pos, cfg: HybridConfig, active=None, window=False):
     """Returns ``(out, ck, cv)``: grouped-query attention over the slot's
     K/V rows plus the new rows.  A global layer applies no positional
-    encoding and its leaves hold every position; a ``window`` layer turns
-    ``q`` and ``k`` by their positions before the cache write and its leaves
-    are written round.  ``active`` (B,): a row with 0 reads none of its K/V
-    rows in the per-token step."""
+    encoding unless the family gives it one (``rope_global``) and its leaves
+    hold every position; a ``window`` layer turns ``q`` and ``k`` by their
+    positions before the cache write and its leaves are written round.
+    ``qk_norm``: every query and key head is RMS-normed over ``head_dim``
+    with a learned weight before the rotation.  ``active`` (B,): a row with
+    0 reads none of its K/V rows in the per-token step."""
     with jax.named_scope("nns.attn.window" if window else "nns.attn.global"):
         q, k, v = (_mm(x, p[n]["kernel"], ck.dtype)
                    for n in ("q_proj", "k_proj", "v_proj"))
-        if window:
-            q = rotary(q, pos, cfg.n_heads, cfg.rope_theta)
-            k = rotary(k, pos, cfg.n_kv_heads, cfg.rope_theta)
+        if cfg.qk_norm:
+            q = _head_rms(q, p["q_norm"]["scale"], cfg.n_heads, cfg.norm_eps)
+            k = _head_rms(k, p["k_norm"]["scale"], cfg.n_kv_heads, cfg.norm_eps)
+        if window or cfg.rope_global:
+            q = rotary(q, pos, cfg.n_heads, cfg.rope_theta, cfg.rope_pairs)
+            k = rotary(k, pos, cfg.n_kv_heads, cfg.rope_theta, cfg.rope_pairs)
         ck, cv, attn = kv_attend_write(
             ck, cv, q, k, v, pos, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             active=active, ring=window)
@@ -615,7 +733,10 @@ def route(p, xt, cfg: HybridConfig):
     _, ids = jax.lax.top_k(
         s + p["router"]["bias"] if cfg.router_bias else s, cfg.top_k)
     w = jnp.take_along_axis(s, ids, axis=-1)
-    return ids, w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scale
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    if cfg.route_eps:
+        total = total + cfg.route_eps
+    return ids, w / total * cfg.routed_scale
 
 
 def _expert_act(cfg: HybridConfig, up, gate=None):
@@ -628,7 +749,7 @@ def _expert_act(cfg: HybridConfig, up, gate=None):
 
 def moe_mix(p, x, cfg: HybridConfig, live=None):
     """Returns ``(out, counts (4,) int32)``: the held experts' part for the
-    tokens routed to them plus the shared experts.  ``live`` (B,) bool: rows
+    tokens routed to them plus the shared experts, where it has any.  ``live`` (B,) bool: rows
     that carry a token (an idle slot's row routes nowhere and counts
     nothing).  ``counts``: the first four of :data:`COUNTER_NAMES`."""
     B, T, D = x.shape
@@ -670,15 +791,18 @@ def moe_mix(p, x, cfg: HybridConfig, live=None):
 
         routed = jax.lax.platform_dependent(
             xt, lid, gate, tpu=touched, default=grouped)
-        sh = _mm(xt, p["shared_up"]["kernel"], _F32)
-        sh = _expert_act(cfg, sh, None if wg is None else _mm(
-            xt, p["shared_gate"]["kernel"], _F32)).astype(cfg.dtype)
-        sh = jnp.matmul(sh, p["shared_down"]["kernel"], preferred_element_type=_F32)
-        if cfg.shared_combine == "average" and cfg.shared_experts > 1:
-            sh = sh * (1.0 / cfg.shared_experts)
+        out = routed
+        if cfg.shared_experts:
+            sh = _mm(xt, p["shared_up"]["kernel"], _F32)
+            sh = _expert_act(cfg, sh, None if wg is None else _mm(
+                xt, p["shared_gate"]["kernel"], _F32)).astype(cfg.dtype)
+            sh = jnp.matmul(sh, p["shared_down"]["kernel"], preferred_element_type=_F32)
+            if cfg.shared_combine == "average" and cfg.shared_experts > 1:
+                sh = sh * (1.0 / cfg.shared_experts)
+            out = routed + sh
         counts = jnp.stack([jnp.sum(local), jnp.sum(sizes > 0), jnp.max(sizes),
                             jnp.int32(1)]).astype(jnp.int32)
-        return (routed + sh).astype(cfg.dtype).reshape(B, T, D), counts
+        return out.astype(cfg.dtype).reshape(B, T, D), counts
 
 
 def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
@@ -687,14 +811,14 @@ def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
     keep their recurrent state and their position.  Returns ``(hidden (B, T,
     D), rows, counts, kv)``: ``counts`` (4,) the expert layers' sums, ``kv``
     (3,) the attention layers' in a decode step (:func:`kv_counts`; None for
-    a prefill chunk, ``active`` None, and for a pattern without a window
-    layer, whose need is its fill)."""
+    a prefill chunk, ``active`` None, and for a config that does not count
+    them, ``kv_counted``)."""
     x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
     T = tokens.shape[1]
     keep = None if active is None else active == 0
     live = None if active is None else active > 0
     counts = jnp.zeros((4,), jnp.int32)
-    counted = active is not None and "W" in cfg.pattern
+    counted = active is not None and cfg.kv_counted
     kv = jnp.zeros((3,), jnp.int32) if counted else None
     layers = {}
     for blk, mixers in zip(params["blocks"], cfg.blocks):
@@ -709,6 +833,11 @@ def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
             elif kind == "E":
                 out, c = moe_mix(blk[name], h, cfg, live)
                 counts = counts + c
+            elif kind == "C":
+                out, conv = conv_mix(blk[name], h, st["conv"], cfg, keep)
+                layers[key] = {"conv": conv}
+            elif kind == "D":
+                out = mlp_mix(blk[name], h, cfg)
             else:
                 if kv is not None:
                     kv = kv + kv_counts(st["k"], rows["pos"], active, kind == "W")
@@ -739,10 +868,11 @@ class HybridSlotModel:
     rows, n_kv_heads x head_dim) in the model dtype (lane-dense), ``rows``
     being ``max_seq`` for a global layer and ``window`` for a window layer
     (written round), per Mamba-2 layer ``conv`` (slots, K-1, C) and ``ssm``
-    (slots, H, P, N) float32, and ``counts``: the counters the prefill
+    (slots, H, P, N) float32, per short-convolution layer ``conv`` (slots,
+    K-1, d_model), and ``counts``: the counters the prefill
     chunks have summed since the last decode dispatch took them."""
 
-    #: a pattern with a window layer adds :data:`KV_COUNTER_NAMES`
+    #: a config that counts them (``kv_counted``) adds :data:`KV_COUNTER_NAMES`
     counter_names = COUNTER_NAMES
     #: neither a recurrent state nor a leaf written round can be cut by position
     supports_prefix = False
@@ -757,7 +887,7 @@ class HybridSlotModel:
         self.cfg = cfg
         self.family = family  # for program names and messages alone
         self.slots = int(slots)
-        if "W" in cfg.pattern:
+        if cfg.kv_counted:
             self.counter_names = COUNTER_NAMES + KV_COUNTER_NAMES
         self.device = device if device is not None else default_device()
         self._pick = _make_pick(temperature, top_k)
@@ -786,6 +916,8 @@ class HybridSlotModel:
                 out[key] = {
                     "conv": ((s, c.conv_kernel - 1, c.d_conv), c.dtype),
                     "ssm": ((s, c.ssm_heads, c.ssm_head_dim, c.ssm_state), _F32)}
+            elif kind == "C":
+                out[key] = {"conv": ((s, c.conv_kernel - 1, c.d_model), c.dtype)}
             elif kind in "*W":
                 kv = (s, c.kv_rows(kind), c.n_kv_heads * c.head_dim)
                 out[key] = {"k": (kv, c.dtype), "v": (kv, c.dtype)}
@@ -857,7 +989,7 @@ class HybridSlotModel:
     def prefill_counts(self, pos: int, n: int) -> Dict[str, int]:
         """What a chunk of ``n`` tokens at position ``pos`` adds to the
         counters that follow from position alone."""
-        if "W" not in self.cfg.pattern:
+        if not self.cfg.kv_counted:
             return {}
         return {KV_COUNTER_NAMES[3]: sum(
             keys_seen(pos, n, self.cfg.kv_rows(kind))

@@ -22,6 +22,7 @@ and are encouraged to express compute as jit-able functions so chains fuse.
 
 from __future__ import annotations
 
+import queue
 import threading
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple, Union
@@ -39,6 +40,12 @@ from ..core.types import ANY, StreamSpec
 # PROPERTIES (a class declaring its own wins) — ≙ the reference's
 # near-universal GObject props (silent on ~every element)
 COMMON_PROPERTIES: Dict[str, "Property"] = {}  # filled after Property def
+
+
+#: out-of-band mailbox item (``(0, WAKE)``): a thread of the element's own
+#: has output ready for ``handle_idle``; the dispatch loop runs the hook at
+#: once (:meth:`Element.wake_dispatch`)
+WAKE = object()
 
 
 @dataclass
@@ -391,6 +398,19 @@ class Element:
         if self.NUM_SINK_PADS is not None:
             return self.NUM_SINK_PADS
         return max(self._next_sink, 1)
+
+    def wake_dispatch(self) -> None:
+        """Any thread: have this element's dispatch thread run its
+        ``handle_idle`` hook NOW instead of at its next mailbox poll (a
+        thread of the element's own, such as the slot pump, has output
+        ready).  A full mailbox holds frames whose handling drains the
+        same output, and the poll stays as the fallback."""
+        box = self._mailbox
+        if box is not None:
+            try:
+                box.put_nowait((0, WAKE))
+            except queue.Full:
+                pass
 
     # -- delivery (called from upstream worker threads) ---------------------
     def deliver(self, pad: int, item: Union[TensorFrame, Event]) -> None:

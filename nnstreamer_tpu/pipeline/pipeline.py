@@ -47,7 +47,7 @@ from ..core.tracer import (
     META_SRC_TS, PipelineTracer, armed, frame_nbytes, name_os_thread,
     record,
 )
-from .element import Element, ElementError, SinkElement, SourceElement
+from .element import WAKE, Element, ElementError, SinkElement, SourceElement
 
 _STOP = object()  # out-of-band worker shutdown sentinel
 
@@ -2173,6 +2173,24 @@ class Pipeline:
         # on the segment so halt-time accounting can count it
         stash = seg.stash
         stash.clear()
+
+        def flush_idle() -> bool:
+            """Run the idle hooks and route what they release; False when
+            the worker must return."""
+            if idle is not None:
+                outs = idle() or []
+                if outs and not self._route_outs(seg, st, outs):
+                    return False
+            for t_st, t_idle in tail_idles:
+                try:
+                    t_outs = t_idle() or []
+                    if t_outs and not self._route_outs(seg, t_st, t_outs):
+                        return False
+                except BaseException as e:  # noqa: BLE001
+                    self._fail(t_st.el, e)
+                    return False
+            return True
+
         while not stop_flag.is_set():
             if stash:
                 pad, item = stash.popleft()
@@ -2198,22 +2216,19 @@ class Pipeline:
                     # filter's dispatch window) release it when the
                     # input goes quiet — a live stream's tail must not
                     # wait for the next frame or EOS
-                    if idle is not None:
-                        outs = idle() or []
-                        if outs and not self._route_outs(seg, st, outs):
-                            return
-                    for t_st, t_idle in tail_idles:
-                        try:
-                            t_outs = t_idle() or []
-                            if t_outs and not self._route_outs(
-                                    seg, t_st, t_outs):
-                                return
-                        except BaseException as e:  # noqa: BLE001
-                            self._fail(t_st.el, e)
-                            return
+                    if not flush_idle():
+                        return
                     continue
             if item is _STOP:
                 return
+            if item is WAKE:
+                # a thread of the element's own (the slot pump) has output
+                # ready: release it now, not at the next poll (a poll's
+                # phase against the pump's turn was 0-20 ms of jitter in
+                # every token frame's delivery: PERF.md section 5)
+                if not flush_idle():
+                    return
+                continue
             tracer = self.tracer
             if tracer is not None:
                 if has_qsize:

@@ -939,6 +939,70 @@ def test_slot_engine_dispatch_ledger(slots, streams, steps):
     assert snap["gen_tokens"] == steps * 8 * min(slots, streams)
 
 
+def test_the_engine_wakes_its_consumer_once_per_batch_of_ready_frames():
+    """``on_ready`` fires once for a batch of ready frames: a batch is due
+    when a frame lands in an EMPTY ready list and is announced after the
+    pump's next device dispatch or as it goes idle; with nothing popped, a
+    whole stream's frames (8 of them) are ONE wake; after a pop the next
+    frame is a wake again.  It is what lets the generator's dispatch thread
+    release a turn's frames when they exist instead of at its next 20 ms
+    poll (``Element.wake_dispatch``)."""
+    from nnstreamer_tpu.core.slots import SimSlotModel, SlotEngine
+
+    wakes = []
+    p = np.arange(8, dtype=np.int32)[None]
+    eng = SlotEngine(SimSlotModel(2, vocab=997, sleep=lambda s: None), None,
+                     max_seq=1 << 20, chunk=8, name="wake",
+                     on_ready=lambda: wakes.append(len(eng._ready)))
+    eng.start()
+    try:
+        eng.submit(TensorFrame([p]), p, max_new=64, chunk=8)
+        assert _until(lambda: eng.snapshot()["gen_completed"] == 1, timeout=60)
+        assert _until(lambda: len(wakes) == 1, timeout=10)    # the list grew unpopped: one batch
+        assert len(eng.pop_ready()) == 8
+        eng.submit(TensorFrame([p]), p, max_new=8, chunk=8)
+        assert _until(lambda: eng.snapshot()["gen_completed"] == 2, timeout=60)
+        assert _until(lambda: len(wakes) == 2, timeout=10) and len(eng.pop_ready()) == 1
+        # every wake found its batch in the list
+        assert all(n >= 1 for n in wakes)
+    finally:
+        eng.stop()
+
+
+def test_a_wake_in_the_mailbox_runs_the_idle_hook_at_once():
+    """The generator's frames reach the sink through ``WAKE`` items the pump
+    posts into the element's own mailbox: every ready batch is one
+    ``wake_dispatch`` call, and the dispatch loop answers each with a
+    ``handle_idle`` (counted, not timed: the poll stays as the fallback)."""
+    from nnstreamer_tpu.pipeline import parse_pipeline
+
+    pipe = parse_pipeline(
+        "appsrc name=src ! tensor_generator name=gen slots=2 custom=sim:1,vocab:101 "
+        "max-new=32 chunk=4 ! tensor_sink name=out max-stored=64")
+    gen, frames, calls = pipe["gen"], [], {"wake": 0, "idle": 0}
+    wake, idle = gen.wake_dispatch, gen.handle_idle
+
+    def counted_wake():
+        calls["wake"] += 1
+        wake()
+
+    def counted_idle():
+        calls["idle"] += 1
+        return idle()
+
+    gen.wake_dispatch, gen.handle_idle = counted_wake, counted_idle
+    pipe["out"].connect_new_data(frames.append)
+    pipe.start()
+    try:
+        pipe["src"].push(np.arange(5, dtype=np.int32)[None])
+        assert _until(lambda: any(f.meta.get("final") for f in frames), timeout=60)
+    finally:
+        pipe.stop()
+    assert sum(len(np.asarray(f.tensors[0]).reshape(-1)) for f in frames if f.tensors) == 32
+    # at least one batch, never more wakes than frames, and an idle flush for each
+    assert 1 <= calls["wake"] <= len(frames) and calls["idle"] >= calls["wake"]
+
+
 # ---------------------------------------------------------------------------
 # The per-token read of the KV cache, bounded by fill
 # ---------------------------------------------------------------------------

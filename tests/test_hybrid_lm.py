@@ -524,6 +524,9 @@ def test_the_window_cells_decode_program_reads_both_kinds_of_leaf_through_the_ke
     # be 4.3 GB with the context reserved for all four layers)
     assert 11.3e9 < mem.argument_size_in_bytes < 11.4e9
     assert mem.temp_size_in_bytes < 64 << 20
+    # the program PR 33 left, operation for operation (PR 36 grew the family a
+    # conv state, a dense block, QK norm and rotary on a global layer around it)
+    assert _instructions(text) == 3019 and mem.temp_size_in_bytes == 5560832
 
 
 def test_the_window_cells_prefill_chunk_is_bounded_by_fill_and_window(cmdaplus_programs):
@@ -539,6 +542,49 @@ def test_the_window_cells_prefill_chunk_is_bounded_by_fill_and_window(cmdaplus_p
     assert len(set(re.findall(r"%nns_chunk_attention[.\d]* =", text))) == 4
     assert "f32[1,8,16,1024,128]" not in text       # the jnp loop's accumulator
     assert mem.temp_size_in_bytes < 1 << 30
+    assert _instructions(text) == 3770 and mem.temp_size_in_bytes == 612244480
+
+
+@pytest.fixture(scope="module")
+def lfm2_programs(one_chip):
+    """``lfm2moe_pp2_rag_closed32``: the first 12 layers of LFM2-8B-A1B at the
+    published widths (the family's default pattern), all 32 experts held, 32
+    slots, 8192 positions, chunks of 1024."""
+    cfg = H.HybridConfig(**{**H.FAMILIES["lfm2_moe"]["fields"], **dict(
+        vocab=65536, d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64, d_ff=7168,
+        experts=32, experts_held=32, top_k=4, d_expert=1792, max_seq=8192)})
+    return _hybrid_programs(cfg, 32, 1024, one_chip)
+
+
+def test_the_conv_cells_decode_program_holds_a_window_and_reads_its_rows_through_the_kernel(
+        lfm2_programs):
+    compiled = lfm2_programs[0].compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    # ten expert layers with every expert held, three attention layers of 64-wide heads
+    assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 10
+    assert len(set(re.findall(r"%nns_decode_attention[.\d]* =", text))) == 3
+    assert "ragged" not in text
+    # nothing but the in-place row writes makes a K/V leaf, and a conv layer's
+    # whole slot state is two rows of the width
+    assert _leaf_makers(text, "bf16[32,8192,512]") == {"scatter": 6}
+    assert "bf16[32,2,2048]" in text
+    # 7.86 GB of parameters, 1.61 GB of K/V and 2.4 MB of windows
+    assert 9.46e9 < mem.argument_size_in_bytes < 9.48e9
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_the_conv_cells_prefill_chunk_streams_its_experts_and_blocks_its_attention(
+        lfm2_programs):
+    """A 1024-token chunk: ten expert layers in four blocks of MAX_TOKENS rows
+    each; heads of 64 are half a lane tile, so the chunk's attention is the
+    blocked jnp form (no ``nns_chunk_attention``), bounded by fill: no (32,
+    1024, 8192) scores (1.07 GB a layer), temporaries under 256 MB."""
+    compiled = lfm2_programs[1].compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 40
+    assert "ragged" not in text and "nns_chunk_attention" not in text
+    assert "f32[1,8,4,1024,8192]" not in text and "f32[1,32,1024,8192]" not in text
+    assert mem.temp_size_in_bytes < 256 << 20
 
 
 @pytest.mark.parametrize("ring,rows", [(False, 16384), (True, 4096)], ids=["global", "window"])
