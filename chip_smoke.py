@@ -657,7 +657,8 @@ def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
                   decode_shapes=((16, 1024, 20, 20, 64), (8, 4096, 32, 2, 128)),
                   ring_shapes=((16, 4096, 128, 8, 128),),
                   chunk_shapes=((False, 1024, 16384, 128, 8, 128), (True, 1024, 4096, 128, 8, 128)),
-                  expert_shapes=((16, 4096, 4096, 16), (1024, 4096, 4096, 16))):
+                  expert_shapes=((16, 4096, 4096, 16, 2), (1024, 4096, 4096, 16, 2),
+                                 (1024, 2048, 1792, 32, 4))):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -665,7 +666,7 @@ def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
     from nnstreamer_tpu.models.transformer import _attend_blocked, kv_attend_write
     from nnstreamer_tpu.ops.chunk_attention import chunk_attention
     from nnstreamer_tpu.ops.decode_attention import decode_attention, ring_skip
-    from nnstreamer_tpu.ops.expert_ffn import touched_experts_ffn
+    from nnstreamer_tpu.ops.expert_ffn import MAX_TOKENS, held_experts_ffn
     from nnstreamer_tpu.ops.flash_attention import (
         flash_attention, flash_attention_grad)
     from nnstreamer_tpu.ops.labeling import top1
@@ -764,34 +765,38 @@ def phase_kernels(*, platform, interpret=False, top1_batches=(1, 2, 8, 128),
             raise AssertionError(f"{name}: max err {err}")
         checked.append(f"{name} err={err:.1e}")
 
-    # the small-batch expert kernel in its gated form (three matrices an
-    # expert): a decode step's rows and a prefill chunk past one call's
-    # rows, top-2 of the held experts, against a loop over the experts
-    for M, D, F, held in expert_shapes:
+    # the held experts' part in its gated form (three matrices an expert),
+    # by the op's one entry: a decode step's rows through the small-batch
+    # kernel, a 1024-row prefill chunk at both long-chunk cells' widths
+    # through the grouped one, top-k of the held experts, against a loop
+    # over the experts
+    for M, D, F, held, k in expert_shapes:
         x = mk(M, D)
         wg, wu, wd = (mk(held, *shape) * (shape[0] ** -0.5)
                       for shape in ((D, F), (D, F), (F, D)))
-        chosen = jnp.asarray(np.stack([rng.permutation(held)[:2] for _ in range(M)]))
-        gates = jnp.sum(jnp.where(
-            chosen[:, :, None] == jnp.arange(held)[None, None, :], 0.5, 0.0), axis=1)
+        chosen = jnp.asarray(np.stack(
+            [rng.permutation(held)[:k] for _ in range(M)]), jnp.int32)
+        weights = jnp.full((M, k), 1.0 / k, jnp.float32)
         out = _run_kernel(
-            lambda x, g, up, down, gate_w, interpret: touched_experts_ffn(
-                x, g, up, down, gate_w, interpret=interpret),
-            (x, gates, wu, wd, wg), interpret)
+            lambda x, lid, w, up, down, gate_w, interpret: held_experts_ffn(
+                x, lid, w, up, down, gate_w, interpret=interpret),
+            (x, chosen, weights, wu, wd, wg), interpret)
 
-        def loop(x, gates, wu, wd, wg):
+        def loop(x, chosen, wu, wd, wg):
             def one(acc, e):
                 mm = functools.partial(jnp.matmul, preferred_element_type=jnp.float32)
                 hid = (jax.nn.silu(mm(x, wg[e])) * mm(x, wu[e])).astype(x.dtype)
-                return acc + gates[:, e, None] * mm(hid, wd[e]), None
+                gate = jnp.sum(jnp.where(chosen == e, 1.0 / k, 0.0), axis=1, keepdims=True)
+                return acc + gate * mm(hid, wd[e]), None
 
             return jax.lax.scan(one, jnp.zeros((M, D), jnp.float32), jnp.arange(held))[0]
 
-        ref = jax.jit(loop)(x, gates, wu, wd, wg)
+        ref = jax.jit(loop)(x, chosen, wu, wd, wg)
         err = float(jnp.max(jnp.abs(out - ref)))
+        name = ("grouped" if M > MAX_TOKENS else "touched") + "_experts_ffn gated"
         if not err < tol:
-            raise AssertionError(f"touched_experts_ffn gated {(M, D, F, held)}: max err {err}")
-        checked.append(f"touched_experts_ffn gated{(M, D, F, held)} err={err:.1e}")
+            raise AssertionError(f"{name} {(M, D, F, held)}: max err {err}")
+        checked.append(f"{name}{(M, D, F, held)} err={err:.1e}")
 
     def loss_kernel(q, k, v, interpret):
         o = flash_attention_grad(q, k, v, True, 128, 128, interpret)

@@ -254,6 +254,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "nns.gen.moe_layer_steps": ("counter", "(expert layer, step) pairs counted: decode steps and prefill chunks"),
     "nns.gen.moe_prefill_local": ("counter", "the prefill chunks' part of moe_local"),
     "nns.gen.moe_prefill_reads": ("counter", "the prefill chunks' part of moe_expert_reads"),
+    "nns.gen.moe_grouped_rows": ("counter", "rows that carried a pick in the grouped expert kernel's tiles (prefill chunks past the small-batch kernel's rows)"),
+    "nns.gen.moe_grouped_rows_run": ("counter", "rows those tiles ran, every expert's group padded to whole row tiles"),
     "nns.gen.kv_rows_need": ("counter", "K/V cache rows the decode steps needed by position and window, over attention layers and live slots"),
     "nns.gen.kv_rows_read": ("counter", "K/V cache rows the decode steps' reads covered"),
     "nns.gen.kv_rows_held": ("counter", "K/V cache rows the leaves held, summed over the same steps"),
@@ -497,6 +499,8 @@ HEALTH_KEY_METRICS: Dict[str, str] = {
     "gen_moe_layer_steps": "nns.gen.moe_layer_steps",
     "gen_moe_prefill_local": "nns.gen.moe_prefill_local",
     "gen_moe_prefill_reads": "nns.gen.moe_prefill_reads",
+    "gen_moe_grouped_rows": "nns.gen.moe_grouped_rows",
+    "gen_moe_grouped_rows_run": "nns.gen.moe_grouped_rows_run",
     # K/V cache rows, handed over the same way: rows a decode step needs by
     # position and window, rows its reads covered, rows held, keys the
     # prefill chunks' queries saw (models/hybrid_lm.py KV_COUNTER_NAMES; the

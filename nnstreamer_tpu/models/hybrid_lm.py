@@ -81,7 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import decode_attention
-from ..ops.expert_ffn import touched_experts_ffn
+from ..ops import expert_ffn
 from .transformer import (
     _make_pick, config_resume_fields, kv_attend_write, pick_slots,
 )
@@ -108,11 +108,14 @@ FAMILIES = {
 #: always-on counters the decode scan and the prefill chunks sum over their
 #: steps and ``E`` layers (the engine adds them to ``snapshot()``): choices
 #: that fell on a held expert, distinct held experts with a token, tokens on
-#: the busiest held expert, (layer, step) pairs counted; and the prefill
-#: chunks' part of the first two
+#: the busiest held expert, (layer, step) pairs counted; the prefill
+#: chunks' part of the first two; and, of the chunks long enough to go through
+#: the grouped expert kernel alone, the rows that carry a pick and the rows
+#: its tiles ran, padding in
 COUNTER_NAMES = ("gen_moe_local", "gen_moe_expert_reads", "gen_moe_max_load",
                  "gen_moe_layer_steps", "gen_moe_prefill_local",
-                 "gen_moe_prefill_reads")
+                 "gen_moe_prefill_reads", "gen_moe_grouped_rows",
+                 "gen_moe_grouped_rows_run")
 #: and, for a pattern with a window layer or a family with ``kv_counters``,
 #: summed the same way over attention
 #: layers and live slots: cache rows a decode step NEEDS by position and
@@ -751,7 +754,8 @@ def moe_mix(p, x, cfg: HybridConfig, live=None):
     """Returns ``(out, counts (4,) int32)``: the held experts' part for the
     tokens routed to them plus the shared experts, where it has any.  ``live`` (B,) bool: rows
     that carry a token (an idle slot's row routes nowhere and counts
-    nothing).  ``counts``: the first four of :data:`COUNTER_NAMES`."""
+    nothing).  ``counts``: the first four of :data:`COUNTER_NAMES`, and for a
+    batch that goes through the grouped kernel the last two after them (6,)."""
     B, T, D = x.shape
     M, k, held = B * T, cfg.top_k, cfg.experts_held
     with jax.named_scope("nns.moe"):
@@ -782,15 +786,14 @@ def moe_mix(p, x, cfg: HybridConfig, live=None):
             part = jnp.where((g > 0)[:, None], part, 0.0) * g[:, None]
             return jnp.zeros((M, D), _F32).at[tok].add(part)
 
-        def touched(xt, lid, gate):
-            """On a TPU: the touched experts' weights streamed once, every
-            token through each (ops/expert_ffn.py)."""
-            one_hot = lid[:, :, None] == jnp.arange(held)[None, None, :]
-            gates = jnp.sum(jnp.where(one_hot, gate[:, :, None], 0.0), axis=1)
-            return touched_experts_ffn(xt, gates, up, down, wg)
+        def kernel(xt, lid, gate):
+            """On a TPU (ops/expert_ffn.py): a small batch streams the
+            touched experts' weights once, every token through each; a
+            large one goes expert by expert through the expert's own rows."""
+            return expert_ffn.held_experts_ffn(xt, lid, gate, up, down, wg)
 
         routed = jax.lax.platform_dependent(
-            xt, lid, gate, tpu=touched, default=grouped)
+            xt, lid, gate, tpu=kernel, default=grouped)
         out = routed
         if cfg.shared_experts:
             sh = _mm(xt, p["shared_up"]["kernel"], _F32)
@@ -800,16 +803,18 @@ def moe_mix(p, x, cfg: HybridConfig, live=None):
             if cfg.shared_combine == "average" and cfg.shared_experts > 1:
                 sh = sh * (1.0 / cfg.shared_experts)
             out = routed + sh
-        counts = jnp.stack([jnp.sum(local), jnp.sum(sizes > 0), jnp.max(sizes),
-                            jnp.int32(1)]).astype(jnp.int32)
-        return out.astype(cfg.dtype).reshape(B, T, D), counts
+        counts = [jnp.sum(local), jnp.sum(sizes > 0), jnp.max(sizes), jnp.int32(1)]
+        run = expert_ffn.rows_run(lid, held, D)
+        if run is not None:  # the batch goes by expert: how full its tiles are
+            counts += [counts[0], run]
+        return out.astype(cfg.dtype).reshape(B, T, D), jnp.stack(counts).astype(jnp.int32)
 
 
 def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
     """Run ``tokens`` (B, T) through every block against the state ``rows``
     (the cache's leaves for these B rows).  ``active`` (B,) int: rows with 0
     keep their recurrent state and their position.  Returns ``(hidden (B, T,
-    D), rows, counts, kv)``: ``counts`` (4,) the expert layers' sums, ``kv``
+    D), rows, counts, kv)``: ``counts`` the expert layers' sums (:func:`moe_mix`), ``kv``
     (3,) the attention layers' in a decode step (:func:`kv_counts`; None for
     a prefill chunk, ``active`` None, and for a config that does not count
     them, ``kv_counted``)."""
@@ -817,7 +822,7 @@ def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
     T = tokens.shape[1]
     keep = None if active is None else active == 0
     live = None if active is None else active > 0
-    counts = jnp.zeros((4,), jnp.int32)
+    counts = None
     counted = active is not None and cfg.kv_counted
     kv = jnp.zeros((3,), jnp.int32) if counted else None
     layers = {}
@@ -832,7 +837,7 @@ def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
                 layers[key] = {"conv": conv, "ssm": ssm}
             elif kind == "E":
                 out, c = moe_mix(blk[name], h, cfg, live)
-                counts = counts + c
+                counts = c if counts is None else counts + c
             elif kind == "C":
                 out, conv = conv_mix(blk[name], h, st["conv"], cfg, keep)
                 layers[key] = {"conv": conv}
@@ -847,6 +852,8 @@ def forward_rows(params, rows, tokens, cfg: HybridConfig, active=None):
             outs.append(out)
         x = x + functools.reduce(jnp.add, outs)
     adv = T if active is None else T * active.astype(jnp.int32)
+    if counts is None:  # a pattern without an expert layer
+        counts = jnp.zeros((4,), jnp.int32)
     return x, {"pos": rows["pos"] + adv, "layers": layers}, counts, kv
 
 
@@ -983,7 +990,8 @@ class HybridSlotModel:
             params, self._rows(cache, slot), toks, self.cfg)
         cache = self._put_rows(
             cache, rows, slot,
-            cache["counts"] + self._tally(jnp.concatenate([counts, counts[:2]])))
+            cache["counts"] + self._tally(
+                jnp.concatenate([counts[:4], counts[:2], counts[4:]])))
         return cache, head(params, x[:, -1], self.cfg)
 
     def prefill_counts(self, pos: int, n: int) -> Dict[str, int]:
@@ -1017,7 +1025,8 @@ class HybridSlotModel:
         step's expert counters are added to the cache's."""
         x, rows, counts, kv = forward_rows(
             params, self._slotted(cache), tok[:, None], self.cfg, active)
-        rows["counts"] = cache["counts"] + self._tally(jnp.pad(counts, (0, 2)), kv)
+        rows["counts"] = cache["counts"] + self._tally(
+            jnp.pad(counts, (0, len(COUNTER_NAMES) - counts.shape[0])), kv)
         return rows, head(params, x[:, 0], self.cfg)
 
     def _decode_scan(self, k, params, cache, tok, gen, active):

@@ -90,13 +90,14 @@ def test_kernels_phase_tiny_runs_every_pallas_kernel_interpreted():
         attn_stream_shape=(1, 37, 4, 8),
         decode_shapes=((3, 256, 4, 4, 32), (3, 128, 4, 2, 64)),
         ring_shapes=((3, 128, 4, 2, 64),),
-        chunk_shapes=((False, 128, 256, 2, 1, 128), (True, 128, 128, 2, 1, 128)), expert_shapes=((5, 32, 128, 4), (40, 32, 128, 4)))
+        chunk_shapes=((False, 128, 256, 2, 1, 128), (True, 128, 128, 2, 1, 128)), expert_shapes=((5, 32, 128, 4, 2), (40, 32, 128, 4, 2), (300, 32, 128, 4, 2)))
     names = " ".join(r["kernels"])
     for kernel in ("top1", "normalize_u8", "flash(", "flash_grad",
                    "decode_attention(3, 256", "decode_attention(3, 128",
                    "decode_attention(3, 128, 4, 2, 64) ring",
                    "chunk_attention(128, 256, 2, 1, 128)", "chunk_attention(128, 128, 2, 1, 128) ring",
-                   "touched_experts_ffn gated(5,", "touched_experts_ffn gated(40,"):
+                   "touched_experts_ffn gated(5,", "touched_experts_ffn gated(40,",
+                   "grouped_experts_ffn gated(300,"):
         assert kernel in names
 
 

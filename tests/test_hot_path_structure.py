@@ -1076,6 +1076,46 @@ def test_kv_rows_read_counts_whole_blocks_of_live_slots_only(monkeypatch):
     assert bounded["gen_kv_rows_read"] == 8 * 128
 
 
+@pytest.mark.parametrize("prefill_chunk,chunks", [(512, 1), (128, 3)],
+                         ids=["one-300-row-chunk", "three-short-chunks"])
+def test_only_a_chunk_past_the_small_batch_kernel_counts_grouped_rows(prefill_chunk, chunks):
+    """One 300-token prompt and 5 new tokens through the hybrid family.  As
+    ONE chunk it is past ``ops/expert_ffn.py``'s small-batch rows: every
+    expert layer goes through the grouped kernel, and the prefill program
+    counts the rows that carry a pick (every local pick of the chunk) and
+    the rows the tiles ran (on this CPU the picks themselves; on a TPU the
+    groups padded to whole tiles).  In chunks of 128 it stays under, as a
+    decode step always does: neither counter moves."""
+    from nnstreamer_tpu.core.slots import SlotEngine
+    from nnstreamer_tpu.models import hybrid_lm
+    from nnstreamer_tpu.ops import expert_ffn
+
+    props = dict(kv.split(":") for kv in HYBRID.replace("seq:128", "seq:384").split(","))
+    model, params, max_seq = hybrid_lm.build_slot_stream(props, 2)
+    eng = SlotEngine(model, params, max_seq=max_seq, chunk=4,
+                     prefill_chunk=prefill_chunk, name="grouped-rows")
+    prompt = (np.arange(300, dtype=np.int32)[None] * 7) % 97
+    assert (300 > expert_ffn.MAX_TOKENS) and (128 <= expert_ffn.MAX_TOKENS)
+    eng.submit(TensorFrame([prompt]), prompt, max_new=5, chunk=4)
+    eng.start()
+    outs = []
+    try:
+        def final():
+            outs.extend(f for _pad, f in eng.pop_ready())
+            return any(f.meta["final"] for f in outs)
+
+        assert _until(final, timeout=120)
+        snap = eng.snapshot()
+    finally:
+        eng.stop()
+    assert snap["gen_prefill_chunks"] == chunks and snap["gen_moe_local"] > 0
+    rows, run = snap["gen_moe_grouped_rows"], snap["gen_moe_grouped_rows_run"]
+    if chunks == 1:
+        assert run >= rows > 0 and rows == snap["gen_moe_prefill_local"]
+    else:
+        assert rows == run == 0 and snap["gen_moe_prefill_local"] > 0
+
+
 # ---------------------------------------------------------------------------
 # The shared-prefix cache's ledger
 # ---------------------------------------------------------------------------

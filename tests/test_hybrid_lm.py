@@ -346,24 +346,27 @@ def test_every_token_on_one_expert_and_nothing_is_dropped(rng):
 @pytest.mark.parametrize("case", ["spread", "one_expert", "no_held_expert", "two_blocks"])
 def test_the_small_batch_kernel_matches_the_reference_loop_over_held_experts(rng, case):
     """ops/expert_ffn.py in the Pallas interpreter (a TPU lowers it in
-    ``moe_mix``'s place for a small batch): the touched experts only, every
-    token through each, weighed by its gate; nothing dropped under skew, and
-    zeros where no token chose a held expert."""
-    from nnstreamer_tpu.ops.expert_ffn import touched_experts_ffn
+    ``moe_mix``'s place), by its one entry: a small batch streams the touched
+    experts only, every token through each, weighed by its gate; nothing
+    dropped under skew, and zeros where no token chose a held expert.
+    ``two_blocks`` (300 rows, two of the blocks such a batch used to be cut
+    into) is past the small-batch kernel's rows and meets the grouped call."""
+    from nnstreamer_tpu.ops.expert_ffn import MAX_TOKENS, held_experts_ffn
 
     cfg = H.cfg_from_props(props(experts_held=4, expert_offset=4))
     p = H.init_params(cfg, SEED)["blocks"][1]["mixer"]
-    rows = 300 if case == "two_blocks" else 21  # past MAX_TOKENS: blocks of rows
+    rows = 300 if case == "two_blocks" else 21
+    assert (rows > MAX_TOKENS) == (case == "two_blocks")
     bias = {"spread": p["router"]["bias"], "two_blocks": p["router"]["bias"],
             "one_expert": jnp.zeros((8,)).at[5].set(50.0).at[1].set(40.0),
             "no_held_expert": jnp.zeros((8,)).at[0].set(50.0).at[1].set(40.0)}[case]
     p = {**p, "router": {**p["router"], "bias": bias}}
     x = jnp.asarray(rng.standard_normal((rows, 64)).astype(np.float32))
     ids, w = H.route(p, x, cfg)
-    held = (ids[:, :, None] - cfg.expert_offset) == jnp.arange(4)[None, None, :]
-    gates = jnp.sum(jnp.where(held, w[:, :, None], 0.0), axis=1)
-    got = touched_experts_ffn(x, gates, p["experts"]["up"], p["experts"]["down"],
-                              interpret=True)
+    local = (ids >= cfg.expert_offset) & (ids < cfg.expert_offset + 4)
+    lid = jnp.where(local, ids - cfg.expert_offset, 4)
+    got = held_experts_ffn(x, lid, jnp.where(local, w, 0.0), p["experts"]["up"],
+                           p["experts"]["down"], interpret=True)
     ref_cfg = {**REF, "n_routed_experts": 4, "expert_offset": 4}
     p_ref = jax.tree.map(jnp.asarray, ref.part(ref_cfg, SEED, 1)["mixer"])
     p_ref["router"]["bias"] = bias
@@ -371,9 +374,99 @@ def test_the_small_batch_kernel_matches_the_reference_loop_over_held_experts(rng
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4)
     touched = {"one_expert": 1, "no_held_expert": 0}.get(case)
     if touched is not None:
-        assert int(jnp.sum(jnp.any(gates != 0, axis=0))) == touched
+        assert len(set(np.asarray(lid).reshape(-1).tolist()) - {4}) == touched
     if case == "no_held_expert":
         assert not np.asarray(got).any()
+
+
+def _expert_loop(x, lid, w, up, down, gate_w):
+    """The held experts one after another over every row, float32: the
+    reference the grouped kernel is held to."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(up.shape[0]):
+        hid = jnp.matmul(x, up[e], precision="highest")
+        hid = jnp.square(jax.nn.relu(hid)) if gate_w is None else jax.nn.silu(
+            jnp.matmul(x, gate_w[e], precision="highest")) * hid
+        gate = jnp.sum(jnp.where(lid == e, w, 0.0), axis=1, keepdims=True)
+        out = out + gate * jnp.matmul(hid, down[e], precision="highest")
+    return out
+
+
+def _picks_of(rng, score, held, k):
+    """Top-``k`` of ``score`` (rows, experts) as the op takes them: each
+    pick's held expert (``held``: none) and its gate (0 there)."""
+    ids = np.argsort(-score, axis=1)[:, :k]
+    return (jnp.asarray(np.where(ids < held, ids, held), jnp.int32),
+            jnp.asarray(np.where(ids < held, rng.random(ids.shape), 0.0), jnp.float32))
+
+
+def _experts_of(rng, held, gated=False):
+    """``(x -> rows, up, down, gate_w)`` of ``held`` experts of 64 x 128."""
+    up, gate_w = (jnp.asarray(rng.standard_normal((held, 64, 128)) / 8, jnp.float32)
+                  for _ in range(2))
+    down = jnp.asarray(rng.standard_normal((held, 128, 64)) / 11, jnp.float32)
+    return up, down, gate_w if gated else None
+
+
+#: case: rows, held experts, the router's experts, picks a token
+GROUPED_CASES = {
+    "spread": (300, 4, 8, 2), "one_expert": (300, 4, 8, 2), "no_held_expert": (300, 4, 8, 2),
+    "all_local": (260, 32, 32, 4),   # every pick held, top-4 of 32
+    "ragged_rows": (333, 4, 8, 2),   # no multiple of a row tile, nor of a sublane tile
+}
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["relu2", "gated"])
+@pytest.mark.parametrize("case", list(GROUPED_CASES))
+def test_the_grouped_kernel_matches_the_reference_loop_over_held_experts(rng, case, gated):
+    """``nns_grouped_experts_ffn`` in the Pallas interpreter: each held
+    expert over its own rows only, both activations, float32 back to the
+    tokens; no pick dropped when every token chooses one expert, zeros where
+    none is held, and the tiles it walks are the ones ``tiled_rows`` counts."""
+    from nnstreamer_tpu.ops import expert_ffn
+
+    rows, held, total, k = GROUPED_CASES[case]
+    score = rng.standard_normal((rows, total))
+    if case == "one_expert":
+        score[:, 2] += 50.0
+    elif case == "no_held_expert":
+        score[:, held:held + k] += 50.0
+    lid, w = _picks_of(rng, score, held, k)
+    x = jnp.asarray(rng.standard_normal((rows, 64)), jnp.float32)
+    up, down, gate_w = _experts_of(rng, held, gated)
+    got = expert_ffn.held_experts_ffn(x, lid, w, up, down, gate_w, interpret=True)
+    want = _expert_loop(x, lid, w, up, down, gate_w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    sizes = np.bincount(np.asarray(lid).reshape(-1), minlength=held + 1)[:held]
+    tm = expert_ffn.TILE_ROWS
+    assert int(expert_ffn.tiled_rows(lid, held, 64)) == int((-(-sizes // tm) * tm).sum())
+    if case == "one_expert":  # every token's first pick: a group of several tiles
+        assert sizes[2] == rows > tm
+    if case == "no_held_expert":
+        assert not sizes.any() and not np.asarray(got).any()
+
+
+def test_a_batch_past_what_vmem_holds_goes_in_blocks_with_layouts_of_their_own(
+        rng, monkeypatch):
+    """The batch and its float32 result stay in VMEM: a batch past that room
+    is cut into equal blocks inside the ONE call, each with its own layout,
+    and ``tiled_rows`` counts every block's tiles."""
+    from nnstreamer_tpu.ops import expert_ffn
+
+    monkeypatch.setattr(expert_ffn, "_RESIDENT_BYTES", 6 * 64 * 300)
+    rows, held, k = 601, 4, 2
+    assert expert_ffn._blocks(rows, 64) == (3, 208)
+    lid, w = _picks_of(rng, rng.standard_normal((rows, 8)), held, k)
+    x = jnp.asarray(rng.standard_normal((rows, 64)), jnp.float32)
+    up, down, _ = _experts_of(rng, held)
+    got = expert_ffn.held_experts_ffn(x, lid, w, up, down, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_expert_loop(x, lid, w, up, down, None)), atol=2e-5)
+    tm, blocks = expert_ffn.TILE_ROWS, np.asarray(jnp.pad(
+        lid, ((0, 3 * 208 - rows), (0, 0)), constant_values=held)).reshape(3, -1)
+    by_hand = sum(int((-(-np.bincount(b, minlength=held + 1)[:held] // tm) * tm).sum())
+                  for b in blocks)
+    assert int(expert_ffn.tiled_rows(lid, held, 64)) == by_hand
 
 
 @pytest.fixture(scope="module")
@@ -397,22 +490,31 @@ def one_chip(v5e):
     return SingleDeviceSharding(v5e.devices[0])
 
 
+def _picks(one_chip, tokens, k):
+    """Shapes of a batch's picks and their gates on the described chip."""
+    return (jax.ShapeDtypeStruct((tokens, k), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((tokens, k), jnp.float32, sharding=one_chip))
+
+
 @pytest.mark.parametrize("tokens", [32, 128, 512])
 def test_the_small_batch_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, tokens):
-    """Interpret mode cannot see tiling or VMEM limits: compile the kernel for
+    """Interpret mode cannot see tiling or VMEM limits: compile the op for
     the chip at the benchmark cell's shapes (32 slots a decode step, a
     128-token prefill chunk; 64 held experts of 2688 x 1920 in bf16) and for
-    a chunk past one call's rows."""
-    from nnstreamer_tpu.ops.expert_ffn import touched_experts_ffn
+    a chunk past the small-batch kernel's rows, which is the grouped call
+    with relu2 experts."""
+    from nnstreamer_tpu.ops.expert_ffn import held_experts_ffn
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = touched_experts_ffn.lower(
-        arg((tokens, 2688), jnp.bfloat16), arg((tokens, 64), jnp.float32),
+    compiled = jax.jit(held_experts_ffn).lower(
+        arg((tokens, 2688), jnp.bfloat16), *_picks(one_chip, tokens, 6),
         arg((64, 2688, 1920), jnp.bfloat16), arg((64, 1920, 2688), jnp.bfloat16),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert ("nns_grouped_experts_ffn" if tokens > 256 else "nns_touched_experts_ffn") in text
+    assert "tpu_custom_call" in text
     # the weights are streamed through VMEM, never copied whole in HBM
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
@@ -493,7 +595,11 @@ def test_the_cells_prefill_program_is_the_one_it_was(nemotron_programs):
     compiled = nemotron_programs[1].compile()
     text = compiled.as_text()
     assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 7
-    assert _instructions(text) == 6333
+    # the program PR 33 left but for the cache's counts vector, two entries
+    # longer since PR 37 (the grouped kernel's rows, which a chunk of 128 rows
+    # never counts): the pad that gives them their zeros is the 4 instructions
+    # over PR 36's 6333; the temporaries are the same bytes
+    assert _instructions(text) == 6337
     assert compiled.memory_analysis().temp_size_in_bytes == 36683264
 
 
@@ -533,16 +639,22 @@ def test_the_window_cells_prefill_chunk_is_bounded_by_fill_and_window(cmdaplus_p
     """A 1024-token chunk at 16384 positions: every layer's attention is one
     ``nns_chunk_attention`` call bounded by fill and window, its scores in
     VMEM (no (128, 1024, 16384) scores: 8.6 GB; nor the blocked jnp loop's
-    (128, 1024, 256) a block), the experts go through the kernel in blocks
-    of MAX_TOKENS rows, and the chunk's temporaries stay under 1 GB."""
+    (128, 1024, 256) a block), each layer's experts are ONE grouped call over
+    the rows routed to them (PR 37: they were sixteen small-batch calls, four
+    blocks of MAX_TOKENS rows a layer), and the chunk's temporaries stay
+    under 1 GB."""
     compiled = cmdaplus_programs[1].compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 16
+    assert len(set(re.findall(r"%nns_grouped_experts_ffn[.\d]* =", text))) == 4
+    assert "nns_touched_experts_ffn" not in text
     assert "ragged" not in text and "f32[1,8,16,1024,16384]" not in text
     assert len(set(re.findall(r"%nns_chunk_attention[.\d]* =", text))) == 4
     assert "f32[1,8,16,1024,128]" not in text       # the jnp loop's accumulator
     assert mem.temp_size_in_bytes < 1 << 30
-    assert _instructions(text) == 3770 and mem.temp_size_in_bytes == 612244480
+    # PR 36 pinned 3770 instructions and 612 244 480 B with the sixteen
+    # small-batch calls; four grouped calls, each with its layout (a running
+    # count of the picks by expert, the tiles' experts), are 3907 and 11.6 MB more
+    assert _instructions(text) == 3907 and mem.temp_size_in_bytes == 623821824
 
 
 @pytest.fixture(scope="module")
@@ -575,13 +687,16 @@ def test_the_conv_cells_decode_program_holds_a_window_and_reads_its_rows_through
 
 def test_the_conv_cells_prefill_chunk_streams_its_experts_and_blocks_its_attention(
         lfm2_programs):
-    """A 1024-token chunk: ten expert layers in four blocks of MAX_TOKENS rows
-    each; heads of 64 are half a lane tile, so the chunk's attention is the
+    """A 1024-token chunk: ten expert layers, each ONE grouped call over the
+    rows routed to its experts (PR 37: forty small-batch calls before, four
+    blocks of MAX_TOKENS rows a layer, each streaming up to all 32 experts);
+    heads of 64 are half a lane tile, so the chunk's attention is the
     blocked jnp form (no ``nns_chunk_attention``), bounded by fill: no (32,
     1024, 8192) scores (1.07 GB a layer), temporaries under 256 MB."""
     compiled = lfm2_programs[1].compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert len(set(re.findall(r"%nns_touched_experts_ffn[.\d]* =", text))) == 40
+    assert len(set(re.findall(r"%nns_grouped_experts_ffn[.\d]* =", text))) == 10
+    assert "nns_touched_experts_ffn" not in text
     assert "ragged" not in text and "nns_chunk_attention" not in text
     assert "f32[1,8,4,1024,8192]" not in text and "f32[1,32,1024,8192]" not in text
     assert mem.temp_size_in_bytes < 256 << 20
@@ -609,18 +724,42 @@ def test_the_chunk_attention_kernel_compiles_for_a_v5e_at_the_window_cells_leave
 @pytest.mark.parametrize("tokens", [16, 1024])
 def test_the_gated_kernel_compiles_for_a_v5e_at_the_window_cells_widths(one_chip, tokens):
     """Three matrices an expert (16 held experts of 4096 x 4096 in bf16), a
-    decode step's rows and a 1024-token chunk; the relu2 call above lowers
-    as it did (two weight operands)."""
-    from nnstreamer_tpu.ops.expert_ffn import touched_experts_ffn
+    decode step's rows and a 1024-token chunk (the grouped call); the relu2
+    call above lowers as it did (two weight operands)."""
+    from nnstreamer_tpu.ops.expert_ffn import held_experts_ffn
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     w = arg((16, 4096, 4096))
-    compiled = touched_experts_ffn.lower(
-        arg((tokens, 4096)), arg((tokens, 16), jnp.float32), w, w, w).compile()
+    compiled = jax.jit(held_experts_ffn).lower(
+        arg((tokens, 4096)), *_picks(one_chip, tokens, 8), w, w, w).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows,d,f,held,k", [
+    (1024, 4096, 4096, 16, 8), (1024, 2048, 1792, 32, 4)],
+    ids=["cmdaplus_ep8_docs_closed16", "lfm2moe_pp2_rag_closed32"])
+def test_the_grouped_kernel_compiles_for_a_v5e_at_both_long_chunk_cells_shapes(
+        one_chip, rows, d, f, held, k):
+    """A 1024-row chunk at both cells' widths: Mosaic takes the call with
+    the batch and its float32 result in VMEM beside the weight tiles (under
+    its own limit), and nothing beside the call but the layout's tables: no
+    expert weight copied or staged whole, no gathered copy of the batch."""
+    from nnstreamer_tpu.ops import expert_ffn
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up, down = arg((held, d, f)), arg((held, f, d))
+    compiled = expert_ffn.grouped_experts_ffn.lower(
+        arg((rows, d)), *_picks(one_chip, rows, k), up, down, up).compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%nns_grouped_experts_ffn[.\d]* =", text))) == 1
+    assert "nns_touched_experts_ffn" not in text and "ragged" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+    assert expert_ffn._blocks(rows, d) == (1, rows)
 
 
 # ---------------------------------------------------------------------------
@@ -873,7 +1012,7 @@ def test_the_generator_serves_the_family_by_custom_alone(rng):
     logits = np.asarray(ref.forward(ref.make_params(REF, SEED), seq, REF))[12:-1]
     assert len(got) == 6 and np.all(logits.max(-1) - logits[np.arange(6), got] <= 1e-4)
     for name in H.COUNTER_NAMES:   # always on: tracing is off here
-        assert health[name] > 0 or name.startswith("gen_moe_prefill"), name
+        assert health[name] > 0 or name.startswith(("gen_moe_prefill", "gen_moe_grouped")), name
 
 
 @pytest.mark.parametrize("line,why", [

@@ -454,7 +454,8 @@ def test_the_generator_serves_the_family_by_custom_alone(rng):
     logits = np.asarray(ref.forward(ref.make_params(REF, SEED), seq, REF))[12:-1]
     assert len(got) == 6 and np.all(logits.max(-1) - logits[np.arange(6), got] <= 1e-4)
     for name in H.COUNTER_NAMES + H.KV_COUNTER_NAMES:   # always on: tracing is off here
-        assert health[name] > 0, name
+        # (a 13-token prompt's chunks are far under the grouped kernel's rows)
+        assert health[name] > 0 or name.startswith("gen_moe_grouped"), name
     assert health["gen_prefill_tokens"] == 13
     # one attention layer, chunks of 8 and 5: keys 1..13, own rows counted
     assert health["gen_kv_prefill_rows_need"] == 13 * 14 // 2
