@@ -52,12 +52,21 @@ from .continuity import (
 from .liveness import ThreadBeat
 from .log import get_logger
 from .resilience import DeviceLostError, DeviceOomError, device_call
+from .telemetry import TL_ANNOUNCE_META, TL_EMIT_META, TL_POP_META
 from .tracer import armed, name_os_thread, record, span
 
 log = get_logger("slots")
 
 #: terminal stream states
 DONE_STATES = ("done", "evicted", "cancelled", "failed")
+
+
+def _block(x) -> None:
+    """Wait for a device array to be computed, without copying it to the
+    host (a host array is ready as it is)."""
+    wait = getattr(x, "block_until_ready", None)
+    if wait is not None:
+        wait()
 
 
 def lru_bucket(lru: "OrderedDict", key, build, cap: int):
@@ -662,6 +671,10 @@ class SlotEngine:
         # not with the dispatch the device waits for
         self.on_ready = on_ready
         self._wake_due = False
+        # how many outputs at the head of the ready list a wake has
+        # announced (while a profiler session is live, their meta holds
+        # the announce instant: the hop the dispatch thread waits for)
+        self._announced = 0
         # per-stream SLO accounting (telemetry.SloTracker, engine side):
         # one TTFT stamp at the first-token pick, one record_n per
         # decode scan, one counter per terminal outcome — all on the
@@ -904,7 +917,12 @@ class SlotEngine:
             if self._error is not None and not self._ready:
                 raise self._error
             out, self._ready = self._ready, []
-            return out
+            self._announced = 0
+        if out and armed():
+            t = time.perf_counter()
+            for _, f in out:
+                f.meta[TL_POP_META] = t
+        return out
 
     def pending(self) -> int:
         """Logical frames parked in the engine (``pending_frames`` hook:
@@ -1012,6 +1030,11 @@ class SlotEngine:
             out.meta[RESUME_META] = s.resume_info
         if extra_meta:
             out.meta.update(extra_meta)
+        if armed():
+            # the frame's first hop on its way to the client: emission
+            out.meta[TL_EMIT_META] = (
+                time.perf_counter(), s.sid,
+                0 if toks is None else int(toks.shape[1]))
         s.chunk_index += 1
         self._ready.append((0, out))
         self._progress.notify_all()
@@ -1149,11 +1172,26 @@ class SlotEngine:
 
     def _announce_ready(self) -> None:
         """Tell the consumer that a batch of outputs is ready, if one is
-        due (``on_ready``)."""
+        due (``on_ready``; the caller does not hold the lock)."""
+        if self._wake_due and armed():
+            with self._lock:
+                self._stamp_announced()
+        self._wake()
+
+    def _wake(self) -> None:
         if self._wake_due:
             self._wake_due = False
             if self.on_ready is not None:
                 self.on_ready()
+
+    def _stamp_announced(self) -> None:
+        """Stamp the announce instant on the ready outputs no wake has
+        announced yet (lock held; only while a profiler session is
+        live)."""
+        t = time.perf_counter()
+        for _, f in self._ready[self._announced:]:
+            f.meta[TL_ANNOUNCE_META] = t
+        self._announced = len(self._ready)
 
     @contextmanager
     def _on_device(self):
@@ -1373,7 +1411,10 @@ class SlotEngine:
                 # the one sleep of the thread that feeds the device:
                 # named as a wait, so an idle chip reads "no request"
                 t0 = self.clock()
-                self._announce_ready()  # no dispatch is coming to do it
+                # no dispatch is coming to announce the ready outputs
+                if self._wake_due and armed():
+                    self._stamp_announced()
+                self._wake()
                 with span("nns.slots.wait_request"):
                     self._work.wait(0.05)
                 self._idle_s += self.clock() - t0
@@ -1447,16 +1488,24 @@ class SlotEngine:
             except DeviceLostError as e:
                 self._handle_device_lost(e)
                 return
-            with span("nns.slots.decode.sync"), self._on_device():
+            with span("nns.slots.decode.sync") as sp, self._on_device():
+                if sp.live:
+                    # traced, the wait for the scan alone; the copies
+                    # below are then the read-back (untraced, the first
+                    # np.asarray waits as it always did)
+                    _block(toks)
                 # materialize BEFORE emission: a yielded token must
                 # EXIST, not merely be dispatched (generator element
                 # contract)
-                toks_host = np.asarray(toks)  # (slots, k)
-                # np.array (not asarray): a jax result view is read-only
-                # and prefill writes per-slot entries in place
-                self._tok_vec = np.array(tok, dtype=np.int32)
-                self._gen_vec = np.array(gen, dtype=np.int32)
-                counts = np.asarray(counts[0]).tolist() if counts else ()
+                with span("nns.slots.readback", what="scan", arrays=4):
+                    toks_host = np.asarray(toks)  # (slots, k)
+                    # np.array (not asarray): a jax result view is
+                    # read-only and prefill writes per-slot entries in
+                    # place
+                    self._tok_vec = np.array(tok, dtype=np.int32)
+                    self._gen_vec = np.array(gen, dtype=np.int32)
+                    counts = (np.asarray(counts[0]).tolist() if counts
+                              else ())
             now = self.clock()
         with span("nns.slots.emit"), self._lock:
             self.decode_steps += 1
@@ -1588,7 +1637,7 @@ class SlotEngine:
         tp = s.prefill_src.shape[1]
         n = min(self.prefill_chunk, tp - s.prefill_pos)
         with span("nns.slots.prefill", request=s.sid, pos=s.prefill_pos,
-                  n=n):
+                  n=n) as sp:
             toks = s.prefill_src[:, s.prefill_pos:s.prefill_pos + n].astype(
                 np.int32)
             self._cache, logits = self._device_step(
@@ -1625,7 +1674,11 @@ class SlotEngine:
             # exact pick the unslotted prefill applies)
             with self._on_device():
                 t1 = self.model.pick_first(logits)
-                t1_host = int(np.asarray(t1)[0])
+                if sp.live:
+                    _block(t1)
+                with span("nns.slots.readback", what="first_token",
+                          arrays=1):
+                    t1_host = int(np.asarray(t1)[0])
             self._tok_vec[s.slot] = t1_host
             self._gen_vec[s.slot] = 1
             now = self.clock()

@@ -72,6 +72,15 @@ TL_ENQ_META = "_nns_tl_enq"
 #: dequeue into the consuming element's queue-wait histogram) — only
 #: written while a tracer is armed, host-local like every TL_ key
 TL_QPUT_META = "_nns_tl_qput"
+#: a token frame's hops from the chip to the transport, stamped only while
+#: a profiler session is live (core/tracer.py ``armed``): the slot
+#: engine's emission ``(perf_counter, sid, tokens)``, the pump's announce
+#: to the dispatch thread and the dispatch thread's pop (perf_counter
+#: each); ``QueryServerCore.process_stream`` folds them into one
+#: ``nns.gen.deliver`` ring record when the transport has sent the frame
+TL_EMIT_META = "_nns_tl_emit"
+TL_ANNOUNCE_META = "_nns_tl_announce"
+TL_POP_META = "_nns_tl_pop"
 #: the client-local end-to-end decomposition attached to answer frames:
 #: {"client_queue","wire","server_queue","device_dispatch",
 #:  "device_compute","total"} — seconds, summing exactly to "total"

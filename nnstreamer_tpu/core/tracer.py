@@ -38,6 +38,7 @@ are the exception and are named as waits.
 
 from __future__ import annotations
 
+import bisect
 import threading
 import time
 from collections import deque
@@ -559,3 +560,69 @@ def spans_between(t0: float, t1: float) -> List[SpanRecord]:
     """A copy of the ring's records that END in ``[t0, t1]``.  The ring
     is process-global and outlives any pipeline."""
     return [r for r in PipelineTracer._snap(_ring) if t0 <= r.t1 <= t1]
+
+
+#: trace_offset_ns: how many of a trace line's first span events are
+#: matched, and how near (ns) an event must lie to where an offset puts a
+#: ring span of its name for the two to be one span
+_OFFSET_EVENTS = 64
+_OFFSET_TOL_NS = 50_000.0
+
+
+def trace_offset_ns(host_lines) -> Optional[float]:
+    """What to add to a ring instant (``perf_counter`` seconds x 1e9) to
+    place it on a profiler trace's clock, so that a ring-only record (a
+    :func:`record`, which has no annotation) can be laid beside the
+    device's events.  ``host_lines``: a trace's host threads as
+    ``{line name: [(event name, start_ns, end_ns), ...]}`` (what
+    ``benchmark/xplane.py::read`` gives under ``"host"``).
+
+    A span is in both places.  A line's first span event is tried
+    against every ring span of its name; each try is an offset, and it
+    is kept where the most of the line's first ``_OFFSET_EVENTS`` span
+    events find a span of their name on the SAME ring thread within
+    ``_OFFSET_TOL_NS`` of where the offset puts them (name and order on
+    one thread's line).
+    The result is the median, over all lines' matched pairs, of the
+    event's start minus the span's ``t0``; None where nothing
+    matches."""
+    starts: Dict[Any, List[float]] = {}
+    for r in PipelineTracer._snap(_ring):
+        if r.thread is not None:  # a _Span: it opened an annotation
+            starts.setdefault((r.thread, r.name), []).append(r.t0 * 1e9)
+    for ts in starts.values():
+        ts.sort()
+
+    def near(thread, name, t):
+        ts = starts.get((thread, name))
+        if not ts:
+            return None
+        i = bisect.bisect_left(ts, t)
+        best = min(ts[max(0, i - 1):i + 1], key=lambda u: abs(u - t))
+        return best if abs(best - t) <= _OFFSET_TOL_NS else None
+
+    names = {n for _, n in starts}
+    diffs: List[float] = []
+    for events in host_lines.values():
+        evs = sorted((s, n) for n, s, _ in events
+                     if n in names)[:_OFFSET_EVENTS]
+        if not evs:
+            continue
+        s0, n0 = evs[0]
+        best: List[float] = []
+        for (thread, name), ts in starts.items():
+            if name != n0:
+                continue
+            for t in ts:
+                off = s0 - t
+                if len(evs) > 1 and near(thread, evs[1][1],
+                                         evs[1][0] - off) is None:
+                    continue
+                got = [s - m for s, n in evs
+                       for m in (near(thread, n, s - off),) if m is not None]
+                if len(got) > len(best):
+                    best = got
+        diffs.extend(best)
+    if not diffs:
+        return None
+    return float(np.median(diffs))

@@ -46,7 +46,16 @@ from ..core.liveness import (
     stamp_deadline,
 )
 from ..core.log import get_logger
-from ..core.telemetry import SRV_SPAN_META, TL_INVOKE_META, TL_RX_META
+from ..core.telemetry import (
+    SRV_SPAN_META,
+    TL_ANNOUNCE_META,
+    TL_EMIT_META,
+    TL_INVOKE_META,
+    TL_POP_META,
+    TL_RX_META,
+    TRACE_ID_META,
+)
+from ..core.tracer import record, span
 from ..core.types import StreamSpec
 from .wire import (
     WireCorruptionError,
@@ -56,6 +65,7 @@ from .wire import (
     encode_frame,
     encode_frames,
     is_batch_payload,
+    note_frame,
 )
 
 log = get_logger("distributed")
@@ -70,6 +80,37 @@ GRPC_OPTS = [
     ("grpc.max_receive_message_length", 512 * 1024 * 1024),
     ("grpc.max_send_message_length", 512 * 1024 * 1024),
 ]
+
+
+def _frame_hops(meta) -> Optional[Tuple[Any, ...]]:
+    """The hop instants a token frame carries once the transport has taken
+    it off the client's answer queue: ``(emit, announce, pop, taken,
+    trace, chunk)``, or None for a frame emitted with no profiler session
+    live.  A frame the dispatch thread popped with no announce of its own
+    (it rode a wake already sent) takes the pop as its announce."""
+    emit = meta.get(TL_EMIT_META)
+    if emit is None:
+        return None
+    taken = time.perf_counter()
+    pop = meta.get(TL_POP_META, taken)
+    return (emit, meta.get(TL_ANNOUNCE_META, pop), pop, taken,
+            meta.get(TRACE_ID_META), meta.get("chunk_index"))
+
+
+def _deliver_record(hops, sent: float) -> None:
+    """One ``nns.gen.deliver`` ring record: a token frame from its
+    emission by the slot engine to the transport's return from its send.
+    Its four hops partition the record exactly: emission to the pump's
+    announce (the wait for the pump's next dispatch), announce to the
+    dispatch thread's pop, pop to the transport taking it off the answer
+    queue (serversink and queue hand-offs), and the send (``send_s``):
+    raw TCP's encode and socket write, or gRPC's encode, write and the
+    server thread's wait for the interpreter after it, which can end
+    after the client already holds the frame."""
+    (t_emit, sid, tokens), announce, pop, taken, trace, chunk = hops
+    record("nns.gen.deliver", t_emit, sent, request=sid, trace=trace,
+           chunk=chunk, tokens=tokens, announce_s=announce - t_emit,
+           pop_s=pop - announce, queue_s=taken - pop, send_s=sent - taken)
 
 
 # ---------------------------------------------------------------------------
@@ -328,11 +369,18 @@ class QueryServerCore:
                 deadline = time.monotonic() + budget
                 while True:
                     ans = self._await_answer(answer_q, deadline)
+                    # a token frame emitted while a profiler session was
+                    # live carries its hops' instants (core/slots.py)
+                    hops = _frame_hops(ans.meta)
                     # per-chunk span decomposition (each chunk's meta is
                     # a fresh copy of the request's, so "total" reads as
                     # time-since-request at that chunk)
                     self._stamp_server_spans([ans])
                     yield ans
+                    if hops is not None:
+                        # both transports resume this generator only
+                        # once their send of the chunk has returned
+                        _deliver_record(hops, time.perf_counter())
                     if ans.meta.get("final", True):
                         if "final" not in ans.meta:
                             cid = ans.meta.get("client_id")
@@ -724,7 +772,11 @@ class QueryConnection:
             for data in self._invoke_stream_rpc(
                 encode_frame(frame), timeout=timeout or self.timeout
             ):
-                yield decode_frame(data, verify=self._verify)
+                with span("nns.query.recv") as sp:
+                    ans = decode_frame(data, verify=self._verify)
+                    if sp.live:
+                        note_frame(sp, ans)
+                    yield ans
         except grpc.RpcError as e:
             self._map_busy(e)
             raise

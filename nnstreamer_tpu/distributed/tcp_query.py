@@ -62,6 +62,7 @@ from ..core.lifecycle import ServerGoawayError
 from ..core.liveness import ServerBusyError
 from ..core.log import get_logger
 from ..core.resilience import FAULTS, RemoteApplicationError
+from ..core.tracer import span
 from .wire import (
     V1,
     V2,
@@ -73,6 +74,7 @@ from .wire import (
     encode_frame_parts,
     encode_frames_parts,
     is_batch_payload,
+    note_frame,
     parts_nbytes,
 )
 
@@ -606,12 +608,17 @@ class TcpQueryConnection:
                         self._check_reply(rtype, body)
                         raise RemoteApplicationError(
                             f"unexpected stream reply type {rtype}")
-                    ans = decode_frame(body, verify=self._verify)
-                    if ans.meta.get("final", True):
-                        broken = False  # clean completion
+                    # the chunk off the connection (the socket's sleep is
+                    # behind us): its decode and its hand-off downstream
+                    with span("nns.query.recv") as sp:
+                        ans = decode_frame(body, verify=self._verify)
+                        if sp.live:
+                            note_frame(sp, ans)
+                        if ans.meta.get("final", True):
+                            broken = False  # clean completion
+                            yield ans
+                            return
                         yield ans
-                        return
-                    yield ans
             except (ConnectionError, OSError) as e:
                 if (attempt == 0 and reused and not sent
                         and not isinstance(e, TimeoutError)):

@@ -60,6 +60,7 @@ import numpy as np
 from ..core.buffer import TensorFrame
 from ..core.liveness import DEADLINE_META
 from ..core.telemetry import TL_PREFIX as _TL_PREFIX
+from ..core.telemetry import TRACE_ID_META
 from ..core.tracer import META_SRC_TS as _SRC_TS_META
 from ..core.types import (
     TENSOR_COUNT_LIMIT,
@@ -197,6 +198,17 @@ def encode_frame_parts(frame: TensorFrame, version: int = None) -> list:
             crc = zlib.crc32(p, crc)
         parts[0] = _HEAD2.pack(_MAGIC, V2, frame.seq, pts, len(meta), crc)
     return parts
+
+
+def note_frame(sp, frame: TensorFrame) -> None:
+    """Name a streaming client's ``nns.query.recv`` span by the frame it
+    received: the request's trace id and the chunk, so that the frame's
+    server record (``nns.gen.deliver``) and its receipt join on (trace,
+    chunk), and its tokens (what a client counts of a chunk: its first
+    tensor's elements)."""
+    sp.set(request=frame.meta.get(TRACE_ID_META),
+           chunk=frame.meta.get("chunk_index"),
+           tokens=int(np.size(frame.tensors[0])) if frame.tensors else 0)
 
 
 def parts_nbytes(parts) -> int:

@@ -969,6 +969,66 @@ def test_the_engine_wakes_its_consumer_once_per_batch_of_ready_frames():
         eng.stop()
 
 
+def test_an_untraced_turn_makes_the_copies_it_always_made(monkeypatch):
+    """With no profiler session live the pump's read-back is what it was
+    before it had a span: no annotation is opened, nothing waits for the
+    device apart from the copies themselves, and the host copies are
+    exactly one ``asarray`` and two ``array`` a decode scan (tokens; the
+    next inputs) and one of each a first token (the pick, its pending
+    row), in that order, for a model without counters."""
+    import numpy
+    from nnstreamer_tpu.core import slots, tracer
+    from nnstreamer_tpu.core.slots import SimSlotModel, SlotEngine
+
+    calls = []
+
+    class Counted:
+        def __getattr__(self, name):
+            return getattr(numpy, name)
+
+        def asarray(self, *a, **k):
+            calls.append("asarray")
+            return numpy.asarray(*a, **k)
+
+        def array(self, *a, **k):
+            calls.append("array")
+            return numpy.array(*a, **k)
+
+    def no_annotation(name):
+        raise AssertionError(f"annotation {name} opened with no session")
+
+    assert tracer.armed() is False
+    monkeypatch.setattr(tracer, "_annotation", no_annotation)
+    monkeypatch.setattr(slots, "_block", lambda x: calls.append("wait"))
+    eng = SlotEngine(SimSlotModel(2, vocab=997, sleep=lambda s: None), None,
+                     max_seq=1 << 20, chunk=8, name="copies")
+    eng._np = Counted()
+    p = np.arange(8, dtype=np.int32)[None]
+    outs = []
+    try:
+        for _ in range(2):
+            eng.submit(TensorFrame([p]), p, max_new=24, chunk=8)
+        eng.start()
+
+        def finals():
+            outs.extend(f for _pad, f in eng.pop_ready())
+            return sum(1 for f in outs if f.meta["final"]) == 2
+
+        assert _until(finals, timeout=60)
+        snap = eng.snapshot()
+    finally:
+        eng.stop()
+    scans, firsts = snap["gen_decode_steps"], snap["gen_first_tokens"]
+    assert scans >= 3 and firsts == 2
+    assert "wait" not in calls
+    assert calls.count("asarray") == scans + firsts
+    assert calls.count("array") == 2 * scans + firsts
+    # a scan's copies run together: tokens, then the next inputs
+    text = " ".join(calls)
+    assert text.count("asarray array array") == scans
+    assert not any(k.startswith("_nns_tl_") for f in outs for k in f.meta)
+
+
 def test_a_wake_in_the_mailbox_runs_the_idle_hook_at_once():
     """The generator's frames reach the sink through ``WAKE`` items the pump
     posts into the element's own mailbox: every ready batch is one

@@ -419,3 +419,181 @@ def test_a_traced_engine_names_every_phase_of_the_pump(session, rng):
     assert sorted(c["n"] for c in chunks) == [5, 8, 32]
     assert len([r for r in recs if r.name == "nns.slots.decode"]) == (
         snap["gen_decode_steps"])
+    # the read-back has a span of its own: the scan's four copies inside
+    # the sync (after the wait for the scan), the first token's copy
+    # inside the prompt's last prefill chunk
+    backs = [r for r in recs if r.name == "nns.slots.readback"]
+    scans = [r for r in backs if r.attrs["what"] == "scan"]
+    firsts = [r for r in backs if r.attrs["what"] == "first_token"]
+    assert len(scans) + len(firsts) == len(backs)
+    assert {r.parent for r in scans} == {"nns.slots.decode.sync"}
+    assert {r.attrs["arrays"] for r in scans} == {4}
+    assert len(scans) == snap["gen_decode_steps"]
+    assert {r.parent for r in firsts} == {"nns.slots.prefill"}
+    assert {r.attrs["arrays"] for r in firsts} == {1}
+    assert len(firsts) == snap["gen_first_tokens"]
+    assert {r.request for r in firsts} == sids
+
+
+# -- a token frame's path from the pump to the client ---------------------------
+def _round_trip(ct, sid, callers=2, max_new=10, chunk=4):
+    """``callers`` streaming clients of one slotted sim generator served
+    over transport ``ct``, concurrently; their sinks' frames by caller."""
+    server = parse_pipeline(
+        f"tensor_query_serversrc name=ssrc id={sid} port=0 connect-type={ct} "
+        "! tensor_generator name=gen slots=2 custom=sim:1,vocab:101 "
+        f"max-new={max_new} chunk={chunk} ! tensor_query_serversink id={sid}")
+    server.start()
+    port = server["ssrc"].props["port"]
+    got = {}
+
+    def caller(i):
+        pipe = parse_pipeline(
+            f"appsrc name=src ! tensor_query_client port={port} "
+            f"connect-type={ct} stream=true timeout=60 ! "
+            "tensor_sink name=out", name=f"hops-{ct}-{i}")
+        pipe.start()
+        pipe["src"].push(np.arange(3 + 4 * i, dtype=np.int32)[None] % 101)
+        pipe["src"].end_of_stream()
+        try:
+            pipe.wait(timeout=90)
+            got[i] = list(pipe["out"].frames)
+        finally:
+            pipe.stop()
+
+    threads = [threading.Thread(target=caller, args=(i,), name=f"hops{i}")
+               for i in range(callers)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        server.stop()
+    assert sorted(got) == list(range(callers))
+    return got
+
+
+def _wire_metas(monkeypatch):
+    """Every meta that crossed the wire, as the receiving side decoded it
+    (both transports decode through ``wire.decode_frame``)."""
+    from nnstreamer_tpu.distributed import service, tcp_query, wire
+
+    seen = []
+
+    def decode(buf, verify=True):
+        f = wire.decode_frame(buf, verify=verify)
+        seen.append(dict(f.meta))
+        return f
+
+    monkeypatch.setattr(tcp_query, "decode_frame", decode)
+    monkeypatch.setattr(service, "decode_frame", decode)
+    return seen
+
+
+@pytest.mark.parametrize("ct", ["tcp", "grpc"])
+def test_a_traced_frame_is_delivered_and_received_once_on_each_hop(
+        session, monkeypatch, ct):
+    from nnstreamer_tpu.core.telemetry import TL_PREFIX, TRACE_ID_META
+
+    crossed = _wire_metas(monkeypatch)
+    got = _round_trip(ct, 781 if ct == "tcp" else 782)
+    chunks = {(f.meta[TRACE_ID_META], f.meta["chunk_index"]): f
+              for frames in got.values() for f in frames}
+    carrying = {k for k, f in chunks.items() if f.tensors}
+    assert len(carrying) == 2 * 3            # 10 tokens in chunks of 4
+
+    def ring():
+        return [r for r in tracer.spans_between(session["t0"],
+                                                time.perf_counter())
+                if r.name in ("nns.gen.deliver", "nns.query.recv")]
+
+    # the server writes a frame's record once the transport has sent it,
+    # which may be after the client has it
+    assert _until(lambda: sum(r.name == "nns.gen.deliver" for r in ring())
+                  == len(chunks))
+    session["stop"]()
+    recs = ring()
+    deliver = {(r.attrs["trace"], r.attrs["chunk"]): r
+               for r in recs if r.name == "nns.gen.deliver"}
+    recv = {(r.request, r.attrs["chunk"]): r
+            for r in recs if r.name == "nns.query.recv"}
+    # one record and one receipt per chunk, joined on (trace, chunk); a
+    # tensor-carrying chunk has tokens, the closing zero-tensor one (if
+    # any) none
+    assert len(deliver) == len(recv) == len(chunks)
+    assert set(deliver) == set(recv) == set(chunks)
+    assert {k for k, r in deliver.items() if r.attrs["tokens"]} == carrying
+    assert all(r.attrs["tokens"] == recv[k].attrs["tokens"]
+               for k, r in deliver.items())
+    assert {r.thread for r in recv.values()} and None not in {
+        r.thread for r in recv.values()}
+    for k, r in deliver.items():
+        hops = [r.attrs[h] for h in ("announce_s", "pop_s", "queue_s",
+                                     "send_s")]
+        assert min(hops) >= 0.0
+        assert sum(hops) == pytest.approx(r.t1 - r.t0, abs=1e-6)
+        # emitted, then taken off the answer queue, before the client had
+        # it; the record's end is the server thread's return from the
+        # write, which in one process may come after the client's receipt
+        # (the reader took the interpreter first), so it is held to
+        # follow the take and no more
+        taken = r.t1 - r.attrs["send_s"]
+        assert r.t0 <= taken <= recv[k].t0 <= recv[k].t1
+    # the server's records are per stream: one sid a caller
+    assert len({r.request for r in deliver.values()}) == 2
+    # instants stayed home: nothing host-local crossed in either direction
+    assert crossed and not any(k.startswith(TL_PREFIX)
+                               for m in crossed for k in m)
+
+
+@pytest.mark.parametrize("ct", ["tcp", "grpc"])
+def test_an_untraced_round_trip_records_and_stamps_nothing(monkeypatch, ct):
+    from nnstreamer_tpu.core.telemetry import TL_PREFIX
+
+    crossed = _wire_metas(monkeypatch)
+    t0 = time.perf_counter()
+    got = _round_trip(ct, 783 if ct == "tcp" else 784)
+    assert sum(len(frames) for frames in got.values()) >= 6
+    assert tracer.spans_between(t0, time.perf_counter()) == []
+    assert crossed and not any(k.startswith(TL_PREFIX)
+                               for m in crossed for k in m)
+
+
+def test_a_ring_record_lands_beside_its_span_on_the_trace(session):
+    """``trace_offset_ns`` puts a ring instant on the trace's clock: a
+    ``record()`` taken inside a span, on two threads, is placed within a
+    millisecond of that span's event in the trace."""
+    def work(tag, n):
+        for i in range(n):
+            with tracer.span(f"nns.test.place.{tag}"):
+                a = time.perf_counter()
+                time.sleep(0.0005)
+                tracer.record(f"nns.test.placed.{tag}", a,
+                              time.perf_counter(), i=i)
+
+    t = threading.Thread(target=work, args=("w", 6), name="place-worker")
+    t.start()
+    work("m", 4)
+    t.join(timeout=10)
+    session["stop"]()
+    path = glob.glob(session["dir"] + "/**/*.xplane.pb", recursive=True)[0]
+    host = xplane.read(path)["host"]
+    off = tracer.trace_offset_ns(host)
+    assert off is not None
+    for tag, n in (("m", 4), ("w", 6)):
+        events = sorted(s for evs in host.values() for name, s, _ in evs
+                        if name == f"nns.test.place.{tag}")
+        placed = sorted(r.t0 * 1e9 + off for r in tracer._ring
+                        if r.name == f"nns.test.placed.{tag}")[-n:]
+        assert len(events) == len(placed) == n
+        assert all(abs(p - e) < 1e6 for p, e in zip(placed, events))
+
+
+def _until(cond, timeout=30.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        if time.monotonic() > deadline:
+            return False
+        time.sleep(0.005)
+    return True
