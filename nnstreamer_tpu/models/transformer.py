@@ -689,6 +689,45 @@ def build_stream(props: Dict[str, str], device=None):
     return prefill, decode_chunk, params, cfg.max_seq
 
 
+def _converted_at_use(cfg: TransformerConfig, path) -> bool:
+    """Whether the module that reads the parameter at ``path`` converts it
+    to ``cfg.dtype`` before every use: the ``embedding`` of ``embed`` and
+    ``pos_embed`` (``nn.Embed`` converts the whole table before its
+    lookup) and, unquantized, the ``kernel`` of a block's ``nn.Dense``.
+    A LayerNorm applies its scale and bias in float32, ``lm_head`` is a
+    float32 product, and ``QuantDense`` derives its int8 scales from the
+    float kernel: their leaves stay as drawn."""
+    names = [k.key for k in path]
+    return names[-1] == "embedding" or (
+        names[-1] == "kernel" and names[-3].startswith("block")
+        and not cfg.quant)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast_leaves(leaves, dtype):
+    return [x.astype(dtype) for x in leaves]
+
+
+def served_params(cfg: TransformerConfig, params):
+    """``params`` with every leaf that its module converts to ``cfg.dtype``
+    before use (:func:`_converted_at_use`) stored in ``cfg.dtype``, in one
+    program; every other leaf is the same array it was.  What the module
+    computes from such a leaf is bit-identical either way (its conversion
+    becomes the identity), while a program given the float32 leaf
+    converts all of it at every entry: the whole vocabulary table to look
+    up a few rows.  With nothing to convert the tree is returned as is."""
+    dtype = jnp.dtype(cfg.dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    picked = [i for i, (path, leaf) in enumerate(flat)
+              if leaf.dtype != dtype and _converted_at_use(cfg, path)]
+    if not picked:
+        return params
+    leaves = [leaf for _, leaf in flat]
+    for i, leaf in zip(picked, _cast_leaves([leaves[i] for i in picked], dtype)):
+        leaves[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 class SlotModel:
     """The jittable halves of the SLOTTED decode path (continuous
     batching, ``core/slots.py``): a fixed-width slot batch whose cache
@@ -807,7 +846,10 @@ class SlotModel:
     def place_params(self, params):
         """Place a param pytree where this model runs, fully staged
         before return: tp-sharded over the mesh, else committed to the
-        model's device."""
+        model's device.  The leaves every program would convert to the
+        model dtype at its entry are stored in it first
+        (:func:`served_params`)."""
+        params = served_params(self.cfg, params)
         if self.mesh is None:
             params = jax.device_put(params, self.device)
         else:

@@ -1064,6 +1064,49 @@ def test_a_wake_in_the_mailbox_runs_the_idle_hook_at_once():
 
 
 # ---------------------------------------------------------------------------
+# The dense programs convert no stored weight
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("layers", [2, 3])
+def test_dense_programs_convert_no_weight_they_are_given(layers, program):
+    """A bf16 dense model's ``decode_fn(4)`` scan and ``prefill_fn(8)``
+    chunk, lowered: a ``convert`` from float32 to bf16 whose operand is an
+    argument shaped like a block's projection kernel or an embedding table
+    is a copy of a stored weight made at every dispatch.  From the placed
+    tree there is none; from the tree as drawn (float32) each program
+    converts four kernels a layer and both tables."""
+    import re
+
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.models.transformer import build_slot_stream
+    from test_dense_param_dtype import (
+        PROPS, _by_path, _drawn, _served_in_model_dtype)
+
+    props = {**PROPS, "layers": str(layers)}
+    model, placed, _ = build_slot_stream(props, 3)
+    _, drawn = _drawn(props)
+    weights = {"x".join(map(str, leaf.shape)) for path, leaf in
+               _by_path(drawn).items() if _served_in_model_dtype(path, quant=False)}
+    cache, vec = model.init_cache(), jnp.zeros((3,), jnp.int32)
+
+    def converts(params):
+        if program == "decode":
+            lowered = model.decode_fn(4).lower(params, cache, vec, vec, vec)
+        else:
+            lowered = model.prefill_fn(8).lower(
+                params, cache, jnp.zeros((1, 8), jnp.int32), np.int32(1))
+        found = re.findall(
+            r"stablehlo\.convert %arg\d+ : \(tensor<([\dx]+)xf32>\) -> "
+            r"tensor<([\dx]+)xbf16>", lowered.as_text())
+        return sum(a == b and a in weights for a, b in found)
+
+    assert len(weights) == 6
+    assert converts(placed) == 0
+    assert converts(drawn) == 4 * layers + 2
+
+
+# ---------------------------------------------------------------------------
 # The per-token read of the KV cache, bounded by fill
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("layers", [2, 3])
