@@ -27,7 +27,14 @@ Mechanics (model halves: ``models/transformer.SlotModel``):
   answered with a typed-expiry final chunk (partial tokens preserved,
   ``evicted="deadline"`` meta) instead of rotting in the batch;
 * **priority joins** — free slots go to the highest PR-8 priority class
-  first (FIFO within a class), so tenant QoS extends to slot admission.
+  first (FIFO within a class), so tenant QoS extends to slot admission;
+* **one scan in flight ahead of its read-back** — the token and count
+  vectors a scan takes stay where the model's programs leave them (on the
+  device), a prompt's first token is seated into them there, and what the
+  next scan runs is known on the host when it is dispatched: so the pump
+  dispatches scan n+1 before it reads scan n back, and the copies,
+  emission and admission run while the chip works.  Whenever nothing is
+  left to dispatch behind the scan in flight it is read back at once.
 
 Threading: the engine runs its own decode pump thread (the PR-6
 CompletionWindow reaper discipline) so decode never waits on the
@@ -38,6 +45,7 @@ on the pipeline thread), and engine errors re-raise there too.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import OrderedDict
@@ -67,6 +75,38 @@ def _block(x) -> None:
     wait = getattr(x, "block_until_ready", None)
     if wait is not None:
         wait()
+
+
+@functools.cache
+def _seat_program():
+    """The one device program that writes a slot's next decode input:
+    ``(tok, gen, slot, first (1,), n) -> (tok, gen)`` with ``tok[slot] =
+    first[0]`` and ``gen[slot] = n``, both vectors donated."""
+    import jax
+
+    def seat(tok, gen, slot, first, n):
+        return tok.at[slot].set(first[0]), gen.at[slot].set(n)
+
+    return jax.jit(seat, donate_argnums=(0, 1))
+
+
+class _Scan:
+    """What the pump dispatched and has not read back yet: one decode
+    scan of ``k`` steps over ``streams`` (``toks`` and the model's
+    ``counts`` as the program returned them), and the streams whose first
+    token was picked before it (``firsts``, seated into ``tok_in``, the
+    token vector the scan took).  ``toks`` is None for first tokens that
+    no scan followed."""
+
+    __slots__ = ("k", "streams", "toks", "counts", "tok_in", "firsts")
+
+    def __init__(self, k, streams, toks, counts, tok_in, firsts):
+        self.k = k
+        self.streams = streams
+        self.toks = toks
+        self.counts = counts
+        self.tok_in = tok_in
+        self.firsts = firsts
 
 
 def lru_bucket(lru: "OrderedDict", key, build, cap: int):
@@ -299,7 +339,8 @@ class GenStream:
     __slots__ = (
         "sid", "frame", "prompt", "max_new", "chunk", "tenant", "priority",
         "deadline_ts", "token_budget_s", "state", "slot", "prefill_pos",
-        "gen", "tok", "pending", "pending_n", "chunk_index", "tokens_out",
+        "gen", "dispatched", "tok", "pending", "pending_n", "chunk_index",
+        "tokens_out",
         "evict_reason", "submitted_ts", "last_token_ts", "joined_ts",
         # seconds the pump spent in THIS stream's own reset and prefill
         # steps (what lane wait leaves out)
@@ -333,6 +374,7 @@ class GenStream:
         self.slot: Optional[int] = None
         self.prefill_pos = 0
         self.gen = 0                      # tokens generated so far
+        self.dispatched = 0               # tokens whose step is dispatched
         self.tok = 0                      # last token (host int)
         self.pending: List[Any] = []      # np arrays (1, k) awaiting a chunk
         self.pending_n = 0
@@ -376,7 +418,9 @@ class SlotModelProtocol(Protocol):
     * ``decode_fn(k)(params, cache, tok, gen, active) -> (cache, tok,
       gen, toks (slots, k)[, counts])`` — ``k`` tokens for every active
       slot; a row with ``active == 0`` keeps its token, its count and its
-      state.  ``counts``, where ``counter_names`` is not empty, is one
+      state.  The engine hands the ``tok`` and ``gen`` a dispatch returned
+      to the next one as they are, and reads ``toks`` back a turn later.
+      ``counts``, where ``counter_names`` is not empty, is one
       integer per name, summed since the last dispatch took them: the
       engine adds them to always-on counters of those names in
       :meth:`SlotEngine.snapshot`, on the read-back it makes anyway;
@@ -699,10 +743,15 @@ class SlotEngine:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
-        # device state (pump-thread-private after start)
+        # device state (pump-thread-private after start): the cache, the
+        # token and count vectors the next scan takes, the scan dispatched
+        # and not yet read back, and the streams whose first token has
+        # been seated since the last scan was dispatched
         self._cache = None
         self._tok_vec = None
         self._gen_vec = None
+        self._inflight: Optional[_Scan] = None
+        self._picked: List[GenStream] = []
         # chunk-length jit buckets, LRU-bounded (filter _stack_jit_cache
         # discipline): one per distinct prefill piece / decode scan length
         self._prefill_lru: "OrderedDict[int, Any]" = OrderedDict()
@@ -714,6 +763,7 @@ class SlotEngine:
         self.evictions = 0
         self.cancellations = 0
         self.decode_steps = 0
+        self.decode_ahead = 0       # scans dispatched before the last read back
         self.prefill_chunks = 0
         self.prefill_tokens = 0     # prompt tokens those chunks held
         self.tokens_total = 0
@@ -729,8 +779,8 @@ class SlotEngine:
         self.remeshes = 0           # models rebuilt on surviving devices
         # where requests and the pump wait (seconds on ``clock``; the
         # pump thread is the only writer): submit -> join summed over
-        # joins; join -> first token minus the stream's own reset and
-        # prefill steps, summed over first tokens; pump seconds inside a
+        # joins; join -> the first token's pick minus the stream's own
+        # reset and prefill steps, summed over picks; pump seconds inside a
         # turn that are neither a device step or read-back nor the idle
         # wait for a request
         self.admit_wait_s = 0.0
@@ -752,11 +802,12 @@ class SlotEngine:
         self._error = None
         self._goaway = False
         self._cache = self.model.init_cache()
-        # engine-owned state vectors are HOST numpy (model-agnostic: the
-        # jax halves convert at the jit boundary — (S,) ints, negligible
-        # — and sim models consume them directly)
+        # the state vectors start as HOST numpy (model-agnostic: a jax
+        # model converts them at the jit boundary and hands back its own,
+        # which the next dispatch takes as they are; sim models stay numpy)
         self._tok_vec = np.zeros((self.slots,), np.int32)
         self._gen_vec = np.zeros((self.slots,), np.int32)
+        self._inflight, self._picked = None, []
         self._thread = threading.Thread(
             target=self._pump, name=f"{self.name}-slots", daemon=True)
         self.heartbeat.bind(self._thread)
@@ -871,7 +922,8 @@ class SlotEngine:
     #: never moving backwards
     _LEDGER_ATTRS = (
         "joins", "completions", "evictions", "cancellations",
-        "decode_steps", "prefill_chunks", "prefill_tokens", "tokens_total",
+        "decode_steps", "decode_ahead", "prefill_chunks", "prefill_tokens",
+        "tokens_total",
         "resumes",
         "goaway_evicted", "oom_retries", "oom_sheds", "device_lost",
         "device_lost_evicted", "remeshes", "admit_wait_s", "first_tokens",
@@ -957,6 +1009,7 @@ class SlotEngine:
                 "gen_cancelled": self.cancellations,
                 "gen_tokens": self.tokens_total,
                 "gen_decode_steps": self.decode_steps,
+                "gen_decode_ahead": self.decode_ahead,
                 "gen_prefill_chunks": self.prefill_chunks,
                 "gen_prefill_tokens": self.prefill_tokens,
                 "gen_tokens_per_step": round(self.tokens_per_step, 3),
@@ -1276,6 +1329,7 @@ class SlotEngine:
         self._cache = self.model.init_cache()
         self._tok_vec = np.zeros((self.slots,), np.int32)
         self._gen_vec = np.zeros((self.slots,), np.int32)
+        self._inflight, self._picked = None, []
         if clear_jit_lrus:
             self._prefill_lru.clear()
             self._decode_lru.clear()
@@ -1389,25 +1443,48 @@ class SlotEngine:
                 self._turn()
             self.pump_host_s += max(
                 0.0, self.clock() - t0 - self._dev_s - self._idle_s)
+        # stopping: what the chip was still computing is read back
+        self._drain()
+
+    def _decodes(self, s: Optional[GenStream]) -> bool:
+        """Whether a scan dispatched now would run ``s`` (lock held): it
+        decodes and some of its tokens are not dispatched yet."""
+        return (s is not None and s.state == "decoding" and not s.finished
+                and s.dispatched < s.max_new)
+
+    def _left_to_dispatch(self) -> bool:
+        """Whether a later turn has anything to dispatch (lock held): a
+        request waiting, a prompt prefilling, a stream with tokens to go."""
+        return bool(self._waiting) or any(
+            s is not None and not s.finished
+            and (s.state == "prefill" or self._decodes(s))
+            for s in self._occupants)
 
     def _turn(self) -> None:
-        """One iteration of the pump: admit, at most the lane's share of
-        prefill chunks, one decode scan, emission."""
+        """One iteration of the pump: admit; dispatch at most the lane's
+        share of prefill chunks and the next decode scan; then wait for
+        the scan dispatched a turn earlier, read it back and emit it.  The
+        chip has the new scan to run while the host copies, emits and
+        admits."""
         np = self._np
 
+        if self._goaway:
+            # a handoff carries every token the chip has made
+            self._drain()
         with span("nns.slots.admit"), self._work:
             self._reap_cancelled()
             if self._goaway:
                 self._sweep_goaway()
             self._sweep_deadlines(self.clock())
-            joined = self._join_waiting(self.clock())
-            have_prefill = any(
-                s is not None and s.state == "prefill"
-                for s in self._occupants)
-            have_decode = any(
-                s is not None and s.state == "decoding"
-                for s in self._occupants)
-            if not (joined or have_prefill or have_decode):
+            self._join_waiting(self.clock())
+            prefilling = [
+                s for s in self._occupants
+                if s is not None and s.state == "prefill"
+                and not s.finished
+            ]
+            have_decode = any(self._decodes(s) for s in self._occupants)
+            idle = not (prefilling or have_decode)
+            if idle and self._inflight is None and not self._picked:
                 # the one sleep of the thread that feeds the device:
                 # named as a wait, so an idle chip reads "no request"
                 t0 = self.clock()
@@ -1419,17 +1496,17 @@ class SlotEngine:
                     self._work.wait(0.05)
                 self._idle_s += self.clock() - t0
                 return
+        if idle:
+            # nothing to dispatch behind what is in flight: read it back
+            # now, so that a stream's last frame never waits for a request
+            self._drain()
+            return
 
         # ---- prefill phase: while decoding, up to prefill_priority
         # chunks interleave per scan (a long prompt never stalls
         # live streams for more than that); with the decode batch
         # EMPTY there is nothing to protect — run every pending
         # joiner's next chunk so the batch fills immediately
-        prefilling = [
-            s for s in self._occupants
-            if s is not None and s.state == "prefill"
-            and not s.finished
-        ]
         budget = (self.prefill_priority if have_decode
                   else max(1, len(prefilling)))
         try:
@@ -1440,112 +1517,221 @@ class SlotEngine:
                 self._prefill_one(s)
         except DeviceOomError:
             # prefill state is re-entrant (prefill_pos advanced only
-            # on success): shed a slot and re-run next iteration
+            # on success): read back what is in flight, shed a slot and
+            # re-run next iteration
+            self._drain()
             self._handle_oom()
             self._recover_donated_cache()
             return
         except DeviceLostError as e:
+            self._drain(lost=True)
             self._handle_device_lost(e)
             return
 
         # ---- decode phase: k tokens for every active slot in ONE
         # lax.scan dispatch (k = min(chunk, min remaining), so every
         # stream completes exactly at a scan boundary and joins/
-        # leaves happen at token boundaries)
+        # leaves happen at token boundaries); what a stream has left is
+        # counted from its dispatched tokens, so the scan is composed
+        # before the one in flight is read back
         with self._lock:
-            decoding = [
-                s for s in self._occupants
-                if s is not None and s.state == "decoding"
-                and not s.finished
-            ]
-        if not decoding:
+            decoding = [s for s in self._occupants if self._decodes(s)]
+            left = self._left_to_dispatch()
+        if not decoding and self._inflight is None:
+            if self._picked and not left:
+                self._drain()  # first tokens no scan will follow
             return
-        k = min(
-            self.chunk,
-            min(s.max_new - s.gen for s in decoding),
-        )
-        k = max(1, k)
-        active = np.zeros((self.slots,), np.int32)
-        for s in decoding:
-            active[s.slot] = 1
-        with span("nns.slots.decode", k=k, active=len(decoding)):
-            try:
-                with span("nns.slots.decode.dispatch"):
-                    # a model with counters of its own hands them over
-                    # as a fifth result
-                    self._cache, tok, gen, toks, *counts = self._device_step(
-                        self._decode_fn(k),
-                        self.params, self._cache, self._tok_vec,
-                        self._gen_vec, active,
-                    )
-            except DeviceOomError:
-                # the step raised before any state assignment: shed the
-                # lowest-priority slot (its tokens survive as a
-                # resumable chunk) and retry on the smaller batch
-                self._handle_oom()
-                self._recover_donated_cache()
-                return
-            except DeviceLostError as e:
-                self._handle_device_lost(e)
-                return
-            with span("nns.slots.decode.sync") as sp, self._on_device():
-                if sp.live:
-                    # traced, the wait for the scan alone; the copies
-                    # below are then the read-back (untraced, the first
-                    # np.asarray waits as it always did)
-                    _block(toks)
-                # materialize BEFORE emission: a yielded token must
-                # EXIST, not merely be dispatched (generator element
-                # contract)
-                with span("nns.slots.readback", what="scan", arrays=4):
-                    toks_host = np.asarray(toks)  # (slots, k)
-                    # np.array (not asarray): a jax result view is
-                    # read-only and prefill writes per-slot entries in
-                    # place
-                    self._tok_vec = np.array(tok, dtype=np.int32)
-                    self._gen_vec = np.array(gen, dtype=np.int32)
-                    counts = (np.asarray(counts[0]).tolist() if counts
-                              else ())
-            now = self.clock()
+        k = max(1, min([self.chunk]
+                       + [s.max_new - s.dispatched for s in decoding]))
+        with span("nns.slots.decode", k=k if decoding else 0,
+                  active=len(decoding)):
+            scan = None
+            if decoding:
+                active = np.zeros((self.slots,), np.int32)
+                for s in decoding:
+                    active[s.slot] = 1
+                try:
+                    with span("nns.slots.decode.dispatch"):
+                        # a model with counters of its own hands them over
+                        # as a fifth result
+                        cache, tok, gen, toks, *counts = self._device_step(
+                            self._decode_fn(k),
+                            self.params, self._cache, self._tok_vec,
+                            self._gen_vec, active,
+                        )
+                except DeviceOomError:
+                    # the step raised before any state assignment: read
+                    # back what is in flight, shed the lowest-priority slot
+                    # (its tokens survive as a resumable chunk) and retry
+                    # on the smaller batch
+                    self._drain()
+                    self._handle_oom()
+                    self._recover_donated_cache()
+                    return
+                except DeviceLostError as e:
+                    self._drain(lost=True)
+                    self._handle_device_lost(e)
+                    return
+                scan = _Scan(k, decoding, toks, counts, self._tok_vec,
+                             self._picked)
+                self._cache, self._tok_vec, self._gen_vec = cache, tok, gen
+                self._picked = []
+                for s in decoding:
+                    s.dispatched += k
+                if self._inflight is not None:
+                    self.decode_ahead += 1
+            done = [] if self._inflight is None else [self._inflight]
+            self._inflight = scan
+            with self._lock:
+                left = self._left_to_dispatch()
+            if not left:
+                # nothing to dispatch behind this scan: read it back too
+                done += self._take_outstanding()
+            got = [self._read(rec) for rec in done]
         with span("nns.slots.emit"), self._lock:
-            self.decode_steps += 1
-            self.tokens_total += k * len(decoding)
-            for name, n in zip(self.model.counter_names, counts):
-                self.model_counts[name] += n
-            a = 0.2  # EWMA horizon ~ last 5 scans
-            self.tokens_per_step = (
-                len(decoding) if self.decode_steps == 1
-                else (1 - a) * self.tokens_per_step + a * len(decoding)
+            for rec, host in zip(done, got):
+                self._apply(rec, *host)
+
+    def _take_outstanding(self) -> List[_Scan]:
+        """Everything dispatched and not read back, in dispatch order: the
+        scan in flight and first tokens no scan has followed yet."""
+        out = [] if self._inflight is None else [self._inflight]
+        if self._picked:
+            out.append(_Scan(0, [], None, (), self._tok_vec, self._picked))
+        self._inflight, self._picked = None, []
+        return out
+
+    def _drain(self, lost: bool = False) -> None:
+        """Read back and emit everything dispatched and not yet read, so
+        that an idle wait, a GOAWAY sweep, a recovery and a stop each find
+        every token the chip has made on the host.  ``lost``: the device
+        may have taken what was in flight with it; what cannot be read is
+        dropped (the streams are handed off with what they hold)."""
+        recs = self._take_outstanding()
+        if not recs:
+            return
+        with span("nns.slots.decode", drain=True):
+            try:
+                got = [self._read(rec) for rec in recs]
+            except Exception:  # noqa: BLE001 — the lost device's results
+                if not lost:
+                    raise
+                log.warning("%s: the work in flight was lost with the "
+                            "device; handing off from what was read back",
+                            self.name, exc_info=True)
+                return
+        with span("nns.slots.emit"), self._lock:
+            for rec, host in zip(recs, got):
+                self._apply(rec, *host)
+
+    def _read(self, rec: _Scan):
+        """Wait for one dispatched record and copy what it made to the
+        host: the scan's tokens and the model's counters, and the token
+        vector its first tokens were seated in.  Returns ``(toks, counts,
+        firsts, now)``."""
+        np = self._np
+        scan = rec.toks is not None
+        with span("nns.slots.decode.sync") as sp, self._on_device():
+            if sp.live:
+                # traced, the wait for the scan alone; the copies below
+                # are then the read-back (untraced, the first np.asarray
+                # waits as it always did)
+                _block(rec.toks if scan else rec.tok_in)
+            # materialize BEFORE emission: a yielded token must EXIST, not
+            # merely be dispatched (generator element contract)
+            with span("nns.slots.readback",
+                      what="scan" if scan else "first_token",
+                      arrays=scan + bool(rec.counts) + bool(rec.firsts)):
+                toks = np.asarray(rec.toks) if scan else None  # (slots, k)
+                counts = (np.asarray(rec.counts[0]).tolist() if rec.counts
+                          else ())
+                firsts = np.asarray(rec.tok_in) if rec.firsts else None
+        return toks, counts, firsts, self.clock()
+
+    def _apply(self, rec: _Scan, toks_host, counts, firsts,
+               now: float) -> None:
+        """Hand one read-back to its streams (lock held), in dispatch
+        order: first tokens, then the scan's ``k`` tokens a stream.  A
+        stream that finished while they were computed (cancelled,
+        evicted, handed off) has them discarded."""
+        np = self._np
+        for s in rec.firsts:
+            if not s.finished:
+                self._first_token(s, int(firsts[s.slot]), now)
+        if toks_host is None:
+            return
+        k, decoding = rec.k, rec.streams
+        self.decode_steps += 1
+        self.tokens_total += k * len(decoding)
+        for name, n in zip(self.model.counter_names, counts):
+            self.model_counts[name] += n
+        a = 0.2  # EWMA horizon ~ last 5 scans
+        self.tokens_per_step = (
+            len(decoding) if self.decode_steps == 1
+            else (1 - a) * self.tokens_per_step + a * len(decoding)
+        )
+        for s in decoding:
+            if s.finished:  # cancelled mid-scan: tokens discarded
+                continue
+            row = toks_host[s.slot:s.slot + 1, :]  # (1, k)
+            s.tok = int(row[0, -1])
+            s.gen += k
+            # per-token pace QoS: the scan's OWN per-token rate
+            # against the stream's budget — a stream decoding
+            # slower than its pace is evicted (tokens from this
+            # scan are preserved in the typed-expiry flush)
+            pace_blown = (
+                s.token_budget_s > 0.0
+                and (now - s.last_token_ts) / k > s.token_budget_s
             )
-            for s in decoding:
-                if s.finished:  # cancelled mid-scan: tokens discarded
-                    continue
-                row = toks_host[s.slot:s.slot + 1, :]  # (1, k)
-                s.tok = int(row[0, -1])
-                s.gen += k
-                # per-token pace QoS: the scan's OWN per-token rate
-                # against the stream's budget — a stream decoding
-                # slower than its pace is evicted (tokens from this
-                # scan are preserved in the typed-expiry flush)
-                pace_blown = (
-                    s.token_budget_s > 0.0
-                    and (now - s.last_token_ts) / k > s.token_budget_s
-                )
-                # SLO per-token inter-arrival: the scan's k tokens
-                # as k observations of the same pace — one bucket
-                # increment, reusing the pace sweep's clock reads
-                if self.slo is not None:
-                    self.slo.note_tokens(
-                        s.tenant, max(0.0, now - s.last_token_ts), k)
-                s.last_token_ts = now
-                s.pending.append(row.astype(np.int32))
-                s.pending_n += k
-                if s.gen >= s.max_new:
-                    self._finish(s, "done")
-                elif pace_blown:
-                    self._evict(s, "token_budget")
-                else:
-                    self._emit_boundary(s)
+            # SLO per-token inter-arrival: the scan's k tokens
+            # as k observations of the same pace — one bucket
+            # increment, reusing the pace sweep's clock reads
+            if self.slo is not None:
+                self.slo.note_tokens(
+                    s.tenant, max(0.0, now - s.last_token_ts), k)
+            s.last_token_ts = now
+            s.pending.append(row.astype(np.int32))
+            s.pending_n += k
+            if s.gen >= s.max_new:
+                self._finish(s, "done")
+            elif pace_blown:
+                self._evict(s, "token_budget")
+            else:
+                self._emit_boundary(s)
+
+    def _first_token(self, s: GenStream, t: int, now: float) -> None:
+        """Token 1 of a stream, read back (lock held)."""
+        np = self._np
+        s.tok = t
+        s.gen = 1
+        self.tokens_total += 1  # token 1 comes from the prefill pick
+        # SLO TTFT: the promised one-stamp-per-first-token — resumed
+        # streams have none (their first token predates this server)
+        if self.slo is not None:
+            self.slo.note_ttft(s.tenant, max(0.0, now - s.submitted_ts))
+        s.pending.append(np.array([[t]], np.int32))
+        s.pending_n += 1
+        if s.gen >= s.max_new:
+            self._finish(s, "done")
+        else:
+            self._emit_boundary(s)
+
+    def _seat(self, slot: int, first, n: int) -> None:
+        """Write a slot's next decode input, token ``first`` (1,) at count
+        ``n``, into the vectors the next scan takes, where they are: into
+        copies on the host where the vectors and the token are host arrays,
+        by one small donated program on the device otherwise."""
+        np = self._np
+        if isinstance(first, np.ndarray) and isinstance(
+                self._tok_vec, np.ndarray):
+            tok, gen = self._tok_vec.copy(), self._gen_vec.copy()
+            tok[slot], gen[slot] = first[0], n
+        else:
+            tok, gen = _seat_program()(
+                self._tok_vec, self._gen_vec, np.int32(slot), first,
+                np.int32(n))
+        self._tok_vec, self._gen_vec = tok, gen
 
     # -- shared-prefix cache (attach on join, publish at boundaries) --------
     def _attach_prefix(self, s: GenStream, slot: int) -> None:
@@ -1637,7 +1823,7 @@ class SlotEngine:
         tp = s.prefill_src.shape[1]
         n = min(self.prefill_chunk, tp - s.prefill_pos)
         with span("nns.slots.prefill", request=s.sid, pos=s.prefill_pos,
-                  n=n) as sp:
+                  n=n):
             toks = s.prefill_src[:, s.prefill_pos:s.prefill_pos + n].astype(
                 np.int32)
             self._cache, logits = self._device_step(
@@ -1656,14 +1842,15 @@ class SlotEngine:
             if s.resume_gen:
                 # checkpointed restart: no pick, no token-1 emission — the
                 # client already holds tokens 1..resume_gen
-                self._tok_vec[s.slot] = s.resume_tok
-                self._gen_vec[s.slot] = s.resume_gen
+                with self._on_device():
+                    self._seat(s.slot, np.array([s.resume_tok], np.int32),
+                               s.resume_gen)
                 now = self.clock()
                 with self._lock:
                     if s.finished:  # cancelled/handed off during prefill
                         return
                     s.tok = s.resume_tok
-                    s.gen = s.resume_gen
+                    s.gen = s.dispatched = s.resume_gen
                     s.last_token_ts = now
                     if s.resume_gen >= s.max_new:
                         self._finish(s, "done")  # defensive: nothing left
@@ -1671,28 +1858,16 @@ class SlotEngine:
                         s.state = "decoding"
                 return
             # prompt fully prefilled: pick token 1 (raw gen_seed key — the
-            # exact pick the unslotted prefill applies)
+            # exact pick the unslotted prefill applies) and seat it where
+            # the next scan takes it; its host value is read back with
+            # that scan
             with self._on_device():
-                t1 = self.model.pick_first(logits)
-                if sp.live:
-                    _block(t1)
-                with span("nns.slots.readback", what="first_token",
-                          arrays=1):
-                    t1_host = int(np.asarray(t1)[0])
-            self._tok_vec[s.slot] = t1_host
-            self._gen_vec[s.slot] = 1
+                self._seat(s.slot, self.model.pick_first(logits), 1)
             now = self.clock()
-            # SLO TTFT: the promised one-stamp-per-first-token — resumed
-            # streams skip it above (their first token predates this server)
-            if self.slo is not None:
-                self.slo.note_ttft(s.tenant, max(0.0, now - s.submitted_ts))
             with self._lock:
                 if s.finished:  # cancelled during prefill
                     return
-                s.tok = t1_host
-                s.gen = 1
-                self.tokens_total += 1  # token 1 comes from the prefill pick
-                # lane wait: join to first token, less the seconds the pump
+                # lane wait: join to this pick, less the seconds the pump
                 # spent in this stream's own reset and prefill steps — the
                 # rest is the stream waiting for its turn in the lane
                 own = s.own_s + (self._dev_s - dev0)
@@ -1703,11 +1878,8 @@ class SlotEngine:
                     t_end = time.perf_counter()
                     record("nns.gen.lane_wait", t_end - lane - own, t_end,
                            request=s.sid, own_s=own)
+                # the pace of the stream's first scan runs from its pick
                 s.last_token_ts = now
-                s.pending.append(np.array([[t1_host]], np.int32))
-                s.pending_n = 1
-                if s.max_new <= 1:
-                    self._finish(s, "done")
-                else:
-                    s.state = "decoding"
-                    self._emit_boundary(s)
+                s.dispatched = 1
+                s.state = "decoding"
+                self._picked.append(s)

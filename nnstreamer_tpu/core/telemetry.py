@@ -211,6 +211,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "nns.gen.cancelled": ("counter", "streams cancelled (consumer gone)"),
     "nns.gen.tokens": ("counter", "tokens decoded across all slots"),
     "nns.gen.decode_steps": ("counter", "slot-batch decode steps"),
+    "nns.gen.decode_ahead": ("counter", "decode steps dispatched before the previous one was read back"),
     "nns.gen.prefill_chunks": ("counter", "chunked-prefill pieces interleaved"),
     "nns.gen.prefill_tokens": ("counter", "prompt tokens those pieces held"),
     "nns.gen.tokens_per_step": ("gauge", "EWMA active slots per decode step"),
@@ -254,8 +255,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "nns.gen.device_lost_evicted": ("counter", "live streams handed off on device loss"),
     "nns.gen.remeshes": ("counter", "slot models rebuilt on surviving devices"),
     "nns.gen.admit_wait_seconds": ("counter", "seconds requests waited from submit to a slot, summed over joins"),
-    "nns.gen.first_tokens": ("counter", "streams that reached their first token (resumed streams excluded)"),
-    "nns.gen.lane_wait_seconds": ("counter", "seconds from join to first token less the stream's own reset and prefill steps, summed"),
+    "nns.gen.first_tokens": ("counter", "streams whose first token was picked (resumed streams excluded)"),
+    "nns.gen.lane_wait_seconds": ("counter", "seconds from join to the first token's pick less the stream's own reset and prefill steps, summed"),
     "nns.gen.pump_host_seconds": ("counter", "pump seconds inside a turn outside device steps, read-backs and the idle wait"),
     "nns.gen.moe_local": ("counter", "token-expert choices that fell on an expert this server holds"),
     "nns.gen.moe_expert_reads": ("counter", "distinct held experts with at least one token, summed over (layer, step) pairs"),
@@ -460,6 +461,7 @@ HEALTH_KEY_METRICS: Dict[str, str] = {
     "gen_cancelled": "nns.gen.cancelled",
     "gen_tokens": "nns.gen.tokens",
     "gen_decode_steps": "nns.gen.decode_steps",
+    "gen_decode_ahead": "nns.gen.decode_ahead",
     "gen_prefill_chunks": "nns.gen.prefill_chunks",
     "gen_prefill_tokens": "nns.gen.prefill_tokens",
     "gen_tokens_per_step": "nns.gen.tokens_per_step",

@@ -355,6 +355,177 @@ class TestSlotEngine:
 
 
 # ---------------------------------------------------------------------------
+# One scan in flight ahead of its read-back
+# ---------------------------------------------------------------------------
+HYBRID = (
+    "arch:nemotron_h,layers:MEM*EME,vocab:97,d_model:64,ssm_heads:4,"
+    "ssm_head_dim:16,ssm_groups:2,ssm_state:16,conv:4,scan_chunk:8,heads:4,"
+    "kv_heads:2,head_dim:16,experts:8,experts_held:4,expert_offset:0,"
+    "experts_per_tok:2,d_expert:32,d_shared:64,routed_scale:2.5,eps:1e-5,"
+    "seq:128,dtype:float32,seed:1")
+
+
+class _Pump(SlotEngine):
+    """The engine with two hooks for a test.  ``synchronous`` reads every
+    scan back in the turn that dispatched it, the pump's order before a
+    scan could run ahead of its read-back; the consumer of ``victim`` goes
+    away as that stream's second frame is emitted (what ``cancel`` does,
+    under the lock the emission holds), so the cancel lands at the same
+    token in either order."""
+
+    synchronous = False
+    victim = None
+
+    def _left_to_dispatch(self):
+        return not self.synchronous and super()._left_to_dispatch()
+
+    def _emit_frame(self, s, toks, final, extra_meta=None):
+        super()._emit_frame(s, toks, final, extra_meta)
+        if s.sid == self.victim and s.chunk_index == 2 and not final:
+            s.state = "cancelled"
+            self.cancellations += 1
+
+
+def _slot_model(kind, slots):
+    if kind == "sim":
+        return SimSlotModel(slots, vocab=97, sleep=lambda s: None), None, 1 << 20
+    if kind == "dense":
+        from nnstreamer_tpu.models.transformer import build_slot_stream
+
+        return build_slot_stream({k: str(v) for k, v in PROPS.items()}, slots)
+    from nnstreamer_tpu.models.hybrid_lm import build_slot_stream
+
+    return build_slot_stream(dict(kv.split(":") for kv in HYBRID.split(",")), slots)
+
+
+def _until(pred, timeout=120.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if pred():
+            return True
+        time.sleep(0.005)
+    return False
+
+
+def _serve_mix(kind, synchronous):
+    """Six requests through three slots, all queued before the pump
+    starts: one-chunk and three-chunk prompts, lengths that end scans at
+    odd ``k``, a consumer that leaves after two frames, a pace eviction at
+    the first scan, a resume, and joins into freed slots.  Returns each
+    request's frames as (chunk, tokens, final, evicted, tokens done), the
+    snapshot and the oracle's stream for the sim."""
+    model, params, max_seq = _slot_model(kind, 3)
+    eng = _Pump(model, params, max_seq=max_seq, chunk=4, prefill_chunk=8,
+                name=f"ahead-{kind}")
+    eng.synchronous = synchronous
+    rng = np.random.default_rng(5)
+
+    def prompt(n):
+        return rng.integers(0, 61, (1, n)).astype(np.int32)
+
+    asks = [(prompt(5), 13), (prompt(6), 20), (prompt(20), 10),
+            (prompt(5), 30), (prompt(9), 9)]
+    streams = [eng.submit(_frame(p), p, max_new=m, chunk=4) for p, m in asks]
+    p = prompt(12)
+    streams.append(eng.submit(_frame(p), p, max_new=7, chunk=4, resume={
+        "prefix": prompt(3), "tokens_done": 3}))
+    # the pace eviction falls on the first scan, which both orders compose
+    # alike (the cut of a later scan follows the joins' turns)
+    streams[1].token_budget_s = 1e-9
+    eng.victim = streams[3].sid
+    eng.start()
+    try:
+        outs = _drain(eng, lambda o: sum(
+            1 for _p, f in o if f.meta["final"]) >= 5, timeout=120)
+        assert _until(lambda: eng.snapshot()["gen_occupied"] == 0)
+        outs += eng.pop_ready()
+        snap = eng.snapshot()
+    finally:
+        eng.stop()
+    frames = {s.sid: [] for s in streams}
+    for _pad, f in outs:
+        sid = next(s.sid for s in streams if s.frame.seq == f.meta["stream_seq"])
+        frames[sid].append((
+            f.meta["chunk_index"],
+            np.asarray(f.tensors[0]).tolist() if f.tensors else [],
+            f.meta["final"], f.meta.get("evicted"), f.meta["tokens_done"]))
+    oracle = sim_oracle(model, asks[0][0], 13) if kind == "sim" else None
+    return [frames[s.sid] for s in streams], snap, oracle
+
+
+class TestScanAhead:
+    @pytest.mark.parametrize("kind", ["sim", "dense", "hybrid"])
+    def test_frames_are_those_of_the_synchronous_order(self, kind):
+        """The pump that dispatches scan n+1 before it reads scan n back
+        emits, request for request, the frames the synchronous order
+        emits: the same tokens in the same chunks, the same finals, the
+        cancelled consumer's two frames and nothing after them, the pace
+        eviction's typed final at its first scan, the resume's tail."""
+        ahead, snap, oracle = _serve_mix(kind, synchronous=False)
+        sync, sync_snap, _ = _serve_mix(kind, synchronous=True)
+        assert ahead == sync
+        assert [len(f) for f in ahead] == [4, 2, 3, 2, 3, 1]
+        assert [f[-1][2:] for f in ahead] == [
+            (True, None, 13), (True, "token_budget", 5), (True, None, 10),
+            (False, None, 8), (True, None, 9), (True, None, 7)]
+        if oracle is not None:
+            assert sum((f[1][0] for f in ahead[0]), []) == oracle[0].tolist()
+        assert snap["gen_cancelled"] == sync_snap["gen_cancelled"] == 1
+        assert snap["gen_decode_ahead"] > 0 == sync_snap["gen_decode_ahead"]
+
+    def test_a_stream_s_last_frame_does_not_wait_for_a_request(self):
+        """One request and nothing after it: its last scan is read back
+        as soon as nothing is left to dispatch behind it, so its final
+        frame comes with no further request arriving."""
+        eng = SlotEngine(SimSlotModel(2, vocab=97, sleep=lambda s: None), None,
+                         max_seq=1 << 20, chunk=8, name="last-frame")
+        eng.start()
+        try:
+            p = np.arange(5, dtype=np.int32)[None]
+            eng.submit(_frame(p), p, max_new=25, chunk=8)
+            outs = _drain(eng, lambda o: any(f.meta["final"] for _p, f in o))
+            snap = eng.snapshot()
+            assert eng.idle()
+        finally:
+            eng.stop()
+        assert sum(len(f.tensors[0][0]) for _p, f in outs) == 25
+        assert snap["gen_completed"] == 1 and snap["gen_occupied"] == 0
+        # three scans of 8, each after the first dispatched ahead
+        assert snap["gen_decode_steps"] == 3 and snap["gen_decode_ahead"] == 2
+
+    @pytest.mark.parametrize("synchronous,ahead", [(False, 2), (True, 0)],
+                             ids=["ahead", "synchronous"])
+    def test_decode_ahead_counts_the_scans_dispatched_before_a_read_back(
+            self, synchronous, ahead):
+        """Three requests through two slots, queued before the pump
+        starts (k = 8): scan 1 runs A and B with nothing in flight; scan 2
+        runs A while scan 1 is in flight, and its read-back frees B's
+        slot; C joins and scan 3 runs A and C while scan 2 is in flight;
+        nothing is left behind scan 3, so it is read back in the same
+        turn.  Two scans ran ahead; read back in turn, none does."""
+        model = SimSlotModel(2, vocab=97, sleep=lambda s: None)
+        eng = _Pump(model, None, max_seq=1 << 20, chunk=8, name="ahead-count")
+        eng.synchronous = synchronous
+        prompts = [np.full((1, 4), i + 1, np.int32) for i in range(3)]
+        for p, m in zip(prompts, (25, 9, 9)):
+            eng.submit(_frame(p), p, max_new=m, chunk=8)
+        eng.start()
+        try:
+            outs = _drain(eng, lambda o: sum(
+                1 for _p, f in o if f.meta["final"]) >= 3)
+            snap = eng.snapshot()
+        finally:
+            eng.stop()
+        assert snap["gen_decode_steps"] == 3
+        assert snap["gen_decode_ahead"] == ahead
+        assert snap["gen_tokens"] == 25 + 9 + 9
+        by_seq = _group_by_stream([f for _p, f in outs])
+        got = sorted(_stream_tokens(f).tolist() for f in by_seq.values())
+        assert got == sorted(sim_oracle(model, p, m).tolist()
+                             for p, m in zip(prompts, (25, 9, 9)))
+
+
+# ---------------------------------------------------------------------------
 # Element-level: single-occupant parity through the pipeline (satellite)
 # ---------------------------------------------------------------------------
 def _run_pipeline_stream(prompts, n, chunk, slots, fuse=True,

@@ -614,6 +614,51 @@ class TestSlotEngineDeviceLost:
             eng.stop()
 
 
+class TestSlotEngineFaultWithAScanInFlight:
+    @pytest.mark.parametrize("site", ["device.oom", "device.lost"])
+    def test_a_fault_with_a_scan_in_flight_recovers_as_before(self, site):
+        """The fault site fires at the pump's fourth device call: the two
+        prompts' chunks and scan 1 are the first three, so it is scan 2's
+        dispatch, made while scan 1 is in flight.  That scan is read back
+        first, so the recovery sees what it saw when every scan was read
+        back in its own turn: each stream holds its first token and scan
+        1's four.  An OOM sheds the lower-priority stream with those five
+        as a resumable chunk and the other finishes exactly; a lost device
+        hands both off with them."""
+        model = SimSlotModel(2, step_base_ms=0.5)
+        eng = SlotEngine(model, None, max_seq=1 << 20, chunk=4,
+                         prefill_chunk=32, resume_sig="testsig",
+                         on_device_lost=lambda err: None)
+        lo, p_lo = _submit(eng, base=1, priority=0)
+        hi, p_hi = _submit(eng, base=9, priority=3)
+        oom = site == "device.oom"
+        FAULTS.arm(site, exc=DeviceOomError if oom else DeviceLostError,
+                   times=1, after=3)
+        eng.start()
+        reason = "oom" if oom else "device_lost"
+        try:
+            out = _drain_until(eng, lambda o: sum(
+                1 for _, f in o if f.meta.get("final")) >= 2)
+            snap = eng.snapshot()
+        finally:
+            eng.stop()
+        handed = {f.meta["stream_seq"]: f for _, f in out
+                  if f.meta.get("evicted") == reason}
+        assert sorted(handed) == sorted(
+            [lo.frame.seq] if oom else [lo.frame.seq, hi.frame.seq])
+        for seq, f in handed.items():
+            assert f.meta[GOAWAY_META] is True and RESUME_META in f.meta
+            assert f.meta["tokens_done"] == 5
+            p = p_lo if seq == lo.frame.seq else p_hi
+            assert _stream_tokens(out, seq) == _oracle(model, p, 5)
+        if oom:
+            assert _stream_tokens(out, hi.frame.seq) == _oracle(model, p_hi, 24)
+            assert (snap["gen_oom_retries"], snap["gen_oom_sheds"]) == (1, 1)
+        else:
+            assert (snap["gen_device_lost"], snap["gen_device_lost_evicted"],
+                    snap["gen_remeshes"]) == (1, 2, 1)
+
+
 class TestGeneratorDeviceLost:
     """The slotted generator's ``on_device_lost`` hook for REAL
     (non-sim) models: an unsharded model escalates to supervision

@@ -971,11 +971,13 @@ def test_the_engine_wakes_its_consumer_once_per_batch_of_ready_frames():
 
 def test_an_untraced_turn_makes_the_copies_it_always_made(monkeypatch):
     """With no profiler session live the pump's read-back is what it was
-    before it had a span: no annotation is opened, nothing waits for the
-    device apart from the copies themselves, and the host copies are
-    exactly one ``asarray`` and two ``array`` a decode scan (tokens; the
-    next inputs) and one of each a first token (the pick, its pending
-    row), in that order, for a model without counters."""
+    before it had a span: no annotation is opened and nothing waits for the
+    device apart from the copies themselves.  The next inputs stay where
+    the model left them, so the host copies are exactly one ``asarray`` a
+    decode scan (its tokens), one more for the scan the prompts' first
+    tokens rode (the vector they were seated in) and one ``array`` a first
+    token (its pending row), in that order, for a model without
+    counters."""
     import numpy
     from nnstreamer_tpu.core import slots, tracer
     from nnstreamer_tpu.core.slots import SimSlotModel, SlotEngine
@@ -1019,13 +1021,12 @@ def test_an_untraced_turn_makes_the_copies_it_always_made(monkeypatch):
     finally:
         eng.stop()
     scans, firsts = snap["gen_decode_steps"], snap["gen_first_tokens"]
-    assert scans >= 3 and firsts == 2
+    assert scans == 3 and firsts == 2
     assert "wait" not in calls
-    assert calls.count("asarray") == scans + firsts
-    assert calls.count("array") == 2 * scans + firsts
-    # a scan's copies run together: tokens, then the next inputs
-    text = " ".join(calls)
-    assert text.count("asarray array array") == scans
+    # both first tokens ride the first scan: its tokens and the seated
+    # vector, then the two pending rows; one copy for each later scan
+    assert calls == ["asarray", "asarray", "array", "array"] + (
+        ["asarray"] * (scans - 1))
     assert not any(k.startswith("_nns_tl_") for f in outs for k in f.meta)
 
 
